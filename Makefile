@@ -18,7 +18,7 @@ NATIVE_BASE = -std=gnu++17 -pthread -shared -fPIC
 # slowdown usable; separate .so names so they never clobber the prod build
 NATIVE_SAN_CFLAGS ?= -O1 -g -march=x86-64-v2 -ffp-contract=off
 
-.PHONY: test test-fast native native-tsan native-asan native-avx2 native-avx512 sanitize devnet devnet-persistent bench bench-scaling clean lint
+.PHONY: test test-fast native native-tsan native-asan native-avx2 native-avx512 sanitize devnet devnet-persistent bench bench-scaling chip-smoke clean lint
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -68,14 +68,20 @@ devnet-persistent:
 	$(PY) -m protocol_tpu.devnet --workers 2 --cpu --runtime docker \
 	  --scheduler-backend remote --state-dir /var/tmp/protocol_tpu_devnet
 
-# the scheduler-kernel benchmark (real accelerator; prints one JSON line)
+# the scheduler-kernel benchmark: measures the TPU and FAILS without one
+# (a CPU measurement is asked for by name: bench.py engine=native-mt)
 bench:
 	$(PY) bench.py
 
 # ladder-#4 scaling measurement (per-shard rates + HBM envelopes; see
-# SCALING.md). Runs on the chip when healthy, CPU mesh otherwise.
+# SCALING.md). Fails without a TPU; add --cpu for the virtual CPU mesh.
 bench-scaling:
 	$(PY) bench_scaling.py --full
+
+# the quickest proof the served path starts on the chip (one process,
+# one session end to end at 32k x 32k; fails without a TPU)
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # full-scale matcher tests (100k nodes x 10k slots; ~4 min on CPU)
 scale-tests:

@@ -54,6 +54,24 @@ def _free_port() -> int:
     return port
 
 
+def _server_device(address: str) -> dict:
+    """``platform`` / ``device_kind`` / ``device_count`` as the SERVER
+    reports them (Health RPC): every report names the device its walls
+    were taken on, so a run that came up on the CPU says so."""
+    from protocol_tpu.services.scheduler_grpc import SchedulerBackendClient
+
+    client = SchedulerBackendClient(address)
+    try:
+        h = client.health()
+    finally:
+        client.close()
+    return {
+        "platform": h.platform,
+        "device_kind": h.device_kind,
+        "device_count": int(h.device_count),
+    }
+
+
 class _SessionStats:
     __slots__ = (
         "sid", "tenant", "cold_ms", "warm", "assigned_frac_min",
@@ -106,9 +124,14 @@ def _request_v2(snap, p_cols, r_cols, kernel: str):
     )
 
 
-def _open(client, snap, p_cols, r_cols, sid: str, kernel: str):
+def _open(
+    client, snap, p_cols, r_cols, sid: str, kernel: str,
+    reconcile_every: Optional[int] = None, timeout: float = 600,
+):
     """OpenSession from the current cumulative columns; returns the
-    server-acknowledged fingerprint (None = refused)."""
+    server-acknowledged fingerprint (None = refused).
+    ``reconcile_every`` opens a STREAM-mode session with that
+    full-solve cadence (event-typed deltas are refused otherwise)."""
     from protocol_tpu.proto import wire
     from protocol_tpu.trace import format as tfmt
 
@@ -120,12 +143,46 @@ def _open(client, snap, p_cols, r_cols, sid: str, kernel: str):
         snap.eps, snap.max_iters,
     )
     req = _request_v2(snap, p_cols, r_cols, kernel)
+    if reconcile_every is not None:
+        req.stream_mode = True
+        req.reconcile_every = int(reconcile_every)
     chunks = list(wire.chunk_snapshot(sid, fp, req))
-    resp = client.open_session(iter(chunks), timeout=600)
+    resp = client.open_session(iter(chunks), timeout=timeout)
     if not resp.ok:
         return None, resp.error, None
     p4t = wire.unblob(resp.result.provider_for_task, np.int32)
     return fp, "", p4t
+
+
+def _delta_request(
+    sid: str, fp: str, tick: int,
+    provider_rows, p_cols, task_rows, r_cols, event=None,
+):
+    """One ``AssignDelta`` wire message carrying the churned rows of a
+    batch tick, or of ``event`` (a stream event, whose source/seq/kind
+    ride along so the server routes it through the stream engine)."""
+    from protocol_tpu.proto import scheduler_pb2 as pb
+    from protocol_tpu.proto import wire
+    from protocol_tpu.trace import format as tfmt
+
+    req = pb.AssignDeltaRequest(
+        session_id=sid, epoch_fingerprint=fp, tick=tick,
+    )
+    if event is not None:
+        req.event_source = event.source
+        req.event_seq = int(event.seq)
+        req.event_kind = event.kind
+    if provider_rows.size:
+        req.provider_rows.CopyFrom(wire.blob(provider_rows, np.int32))
+        req.providers.CopyFrom(
+            wire.encode_providers_v2(tfmt._as_ns(p_cols))
+        )
+    if task_rows.size:
+        req.task_rows.CopyFrom(wire.blob(task_rows, np.int32))
+        req.requirements.CopyFrom(
+            wire.encode_requirements_v2(tfmt._as_ns(r_cols))
+        )
+    return req
 
 
 def _drive_session(
@@ -163,10 +220,8 @@ def _drive_session(
     re-ground legitimately re-derives duals)."""
     import grpc
 
-    from protocol_tpu.proto import scheduler_pb2 as pb
     from protocol_tpu.proto import wire
     from protocol_tpu.services.scheduler_grpc import SchedulerBackendClient
-    from protocol_tpu.trace import format as tfmt
     from protocol_tpu.trace.replay import iter_input_ticks
 
     endpoints = (
@@ -224,26 +279,11 @@ def _drive_session(
                 server_tick = 0
                 stats.cold_ms.append((time.perf_counter() - t0) * 1e3)
             else:
-                req = pb.AssignDeltaRequest(
-                    session_id=sid, epoch_fingerprint=fp,
-                    tick=server_tick + 1,
+                req = _delta_request(
+                    sid, fp, server_tick + 1,
+                    delta.provider_rows, delta.p_cols,
+                    delta.task_rows, delta.r_cols,
                 )
-                if delta.provider_rows.size:
-                    req.provider_rows.CopyFrom(
-                        wire.blob(delta.provider_rows, np.int32)
-                    )
-                    req.providers.CopyFrom(
-                        wire.encode_providers_v2(tfmt._as_ns(delta.p_cols))
-                    )
-                if delta.task_rows.size:
-                    req.task_rows.CopyFrom(
-                        wire.blob(delta.task_rows, np.int32)
-                    )
-                    req.requirements.CopyFrom(
-                        wire.encode_requirements_v2(
-                            tfmt._as_ns(delta.r_cols)
-                        )
-                    )
                 p4t = None
                 reopened = False
                 evict_retried = False
@@ -563,8 +603,9 @@ def run_load(
             )
         )
 
-    t_wall = time.perf_counter()
     try:
+        device = _server_device(address)
+        t_wall = time.perf_counter()
         threads = [
             threading.Thread(
                 target=_drive_session,
@@ -745,6 +786,7 @@ def run_load(
             "traces": [str(p) for p in traces] if tmpdir is None else
                       "synth (ephemeral)",
         },
+        **device,
         "wall_s": round(wall_s, 3),
         "total_warm_ticks": total_warm_ticks,
         "aggregate_warm_ticks_per_s": round(agg_warm_per_s, 2),
@@ -1359,13 +1401,11 @@ def _drive_event_session(
     import grpc as _grpc
 
     from protocol_tpu.dstream import fanout as _fan
-    from protocol_tpu.proto import scheduler_pb2 as pb
     from protocol_tpu.proto import wire
     from protocol_tpu.services.scheduler_grpc import (
         SchedulerBackendClient,
     )
     from protocol_tpu.stream.events import event_from_delta
-    from protocol_tpu.trace import format as tfmt
 
     endpoints = (
         [str(a) for a in address]
@@ -1414,25 +1454,13 @@ def _drive_event_session(
     # leave events (snapshot values, valid=False)
     p_cum = {k: np.array(v, copy=True) for k, v in snap.p_cols.items()}
     r_cum = {k: np.array(v, copy=True) for k, v in snap.r_cols.items()}
-    w = tfmt._as_ns(dict(zip(
-        ("price", "load", "proximity", "priority"), snap.weights
-    )))
 
     def _open_stream(p_cols, r_cols):
-        req = _request_v2(snap, p_cols, r_cols, kernel)
-        req.stream_mode = True
-        req.reconcile_every = int(reconcile_every)
-        new_fp = wire.epoch_fingerprint(
-            p_cols, r_cols, w, kernel,
-            max(int(snap.top_k) or 64, 1), snap.eps, snap.max_iters,
-        )
-        chunks = list(wire.chunk_snapshot(sid, new_fp, req))
-        resp = send(lambda c: c.open_session(
-            iter(chunks), timeout=rpc_timeout_s
+        new_fp, err, _p4t = send(lambda c: _open(
+            c, snap, p_cols, r_cols, sid, kernel,
+            reconcile_every=reconcile_every, timeout=rpc_timeout_s,
         ))
-        if not resp.ok:
-            return None, resp.error
-        return new_fp, ""
+        return new_fp, err
 
     # client-side chaos'd delivery order: drops become retransmits,
     # dups second copies, reorders late arrivals — every index is
@@ -1491,28 +1519,11 @@ def _drive_event_session(
             nonlocal window_max, window_last, last_recon_p4t
             evict_retried = False
             for retry in range(max_retries):
-                dreq = pb.AssignDeltaRequest(
-                    session_id=sid, epoch_fingerprint=fp,
-                    tick=server_tick + 1,
-                    event_source=ev.source, event_seq=int(ev.seq),
-                    event_kind=ev.kind,
+                dreq = _delta_request(
+                    sid, fp, server_tick + 1,
+                    ev.provider_rows, ev.p_cols,
+                    ev.task_rows, ev.r_cols, event=ev,
                 )
-                if ev.provider_rows.size:
-                    dreq.provider_rows.CopyFrom(
-                        wire.blob(ev.provider_rows, np.int32)
-                    )
-                    dreq.providers.CopyFrom(
-                        wire.encode_providers_v2(tfmt._as_ns(ev.p_cols))
-                    )
-                if ev.task_rows.size:
-                    dreq.task_rows.CopyFrom(
-                        wire.blob(ev.task_rows, np.int32)
-                    )
-                    dreq.requirements.CopyFrom(
-                        wire.encode_requirements_v2(
-                            tfmt._as_ns(ev.r_cols)
-                        )
-                    )
                 r = send(lambda c: c.assign_delta(
                     dreq, timeout=rpc_timeout_s
                 ))
@@ -2273,8 +2284,9 @@ def run_events(
                 refusals_armed=blackout_refusals,
             )
 
-        t_wall = time.perf_counter()
         try:
+            device = _server_device(address)
+            t_wall = time.perf_counter()
             threads = [
                 threading.Thread(
                     target=_drive_event_session,
@@ -2326,6 +2338,7 @@ def run_events(
         "rate_hz": rate_hz,
         "reconcile_every": reconcile_every,
         "kernel": kernel,
+        **device,
         "wall_s": round(wall_s, 3),
         "events_total": total_events,
         "events_per_s": round(total_events / max(wall_s, 1e-9), 1),
@@ -2354,6 +2367,11 @@ def _print_report(rep: dict) -> None:
         f"{cfg['ticks']} ticks, kernel {cfg['kernel']}, "
         f"{cfg['shards']} shards"
     )
+    if "platform" in rep:
+        print(
+            f"  server platform {rep['platform']} "
+            f"({rep['device_kind']} x{rep['device_count']})"
+        )
     print(
         f"  wall {rep['wall_s']}s, {rep['total_warm_ticks']} warm ticks "
         f"({rep['aggregate_warm_ticks_per_s']}/s aggregate), "
@@ -2503,7 +2521,11 @@ def main(argv=None) -> int:
                     help="N > 1 runs the DISTRIBUTED fleet: N real "
                          "servicer subprocesses behind the endpoint "
                          "ring over a shared journal root; the restart "
-                         "drill becomes the process kill/migrate drill")
+                         "drill becomes the process kill/migrate drill. "
+                         "A chip belongs to ONE process, so the forked "
+                         "fleet is pinned to the CPU backend; only "
+                         "--processes 1 runs on whatever device jax "
+                         "finds (named in the report)")
     ap.add_argument("--chaos", default=None,
                     help="seeded chaos spec (faults.plan.ChaosConfig): "
                          "rate faults arm every process's interceptor; "
@@ -2554,7 +2576,14 @@ def main(argv=None) -> int:
                          "recovery must be warm)")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from protocol_tpu.utils.platform import place_compile_cache
+
+    place_compile_cache()
+    if args.processes > 1:
+        # a chip belongs to one process: the forked fleet's servicers are
+        # pinned to the CPU (dfleet/manager.py), and this driver replays
+        # their baselines in-process, so it runs the same float pipeline
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.events is not None:
         rep = run_events(
             sessions=args.sessions, tenants=args.tenants,

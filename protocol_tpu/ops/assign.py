@@ -38,8 +38,8 @@ from protocol_tpu.ops.cost import INFEASIBLE
 
 # -inf stand-in that survives arithmetic. A Python float on purpose:
 # a jnp scalar at module level would initialize the JAX backend at
-# import time (fatal for control-plane processes when the remote
-# accelerator is unreachable).
+# import time — a control-plane process importing this module would
+# claim the chip the scheduler pod owns.
 _NEG = -1e18
 
 

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from protocol_tpu.parallel._compat import shard_map
 
 from protocol_tpu.ops.assign import AssignResult, _invert
 from protocol_tpu.ops.cost import INFEASIBLE
@@ -44,7 +43,7 @@ def _build_sharded_dense_auction(
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis, None),),
         out_specs=P(),

@@ -25,7 +25,6 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from protocol_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from protocol_tpu.ops.blocked import (
@@ -58,7 +57,7 @@ def _build_sharded_sinkhorn(
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=(P(axis), P()),

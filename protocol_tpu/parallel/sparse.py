@@ -28,7 +28,6 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from protocol_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from protocol_tpu.ops.assign import AssignResult, _invert
@@ -100,7 +99,7 @@ def _build_sharded_phase(
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P()),
@@ -565,7 +564,7 @@ def _build_sharded_gen(
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), er_specs),
         out_specs=out_specs,
@@ -780,7 +779,7 @@ def _build_repair_enter_sharded(
         return enter.reshape(Tl)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             enter_scan_sharded,
             mesh=mesh,
             in_specs=(P(), P(), P(), er_specs, P(axis)),

@@ -306,11 +306,11 @@ class TpuBatchMatcher:
         self._last_gen_sharded = False
         self._mesh_fallback_logged = False
         if native_fallback:
-            # pin the process to the host platform NOW: the whole point is
-            # an unreachable accelerator, and letting jax initialize the
-            # remote platform on first use would hang the solve path.
-            # MUST precede the mesh probe below — jax.devices() initializes
-            # the default backend, which is exactly the hang being avoided.
+            # pin the process to the host platform NOW: this matcher was
+            # asked for the CPU engine, so its process must not claim the
+            # chip the scheduler pod owns (one process per chip). MUST
+            # precede the mesh probe below — jax.devices() initializes the
+            # default backend, which is exactly the claim being avoided.
             jax.config.update("jax_platforms", "cpu")
         if use_mesh and not native_fallback:
             import jax as _jax

@@ -594,7 +594,16 @@ def serve_scheduler(args) -> None:
         address=args.address, max_workers=args.max_workers,
         metrics_port=args.metrics_port, fleet=fleet,
     )
-    print(f"scheduler backend on {args.address} (version {VERSION})", flush=True)
+    from protocol_tpu.utils.platform import device_summary
+
+    device = device_summary()
+    print(
+        f"scheduler backend on {args.address} (version {VERSION}) "
+        f"platform={device['platform']} "
+        f"device_kind={device['device_kind']!r} "
+        f"device_count={device['device_count']}",
+        flush=True,
+    )
     if server.metrics is not None:
         print(
             f"obs /metrics on 127.0.0.1:{server.metrics.port}", flush=True
@@ -1040,14 +1049,13 @@ def main(argv: Optional[list[str]] = None) -> int:
             "pool": str(getattr(args, "pool_id", "")),
         },
     )
-    # Operational platform pin (e.g. PROTOCOL_TPU_FORCE_PLATFORM=cpu for
-    # control-plane pods with no accelerator): applied via jax.config, which
-    # outranks JAX_PLATFORMS when a site hook has already forced a platform.
-    forced = os.environ.get("PROTOCOL_TPU_FORCE_PLATFORM", "")
-    if forced:
-        import jax
+    if args.service in ("scheduler", "orchestrator"):
+        # the services whose process can hold the chip (the orchestrator
+        # through its in-process matcher): their jit executables go to a
+        # compile cache that does not move between restarts
+        from protocol_tpu.utils.platform import place_compile_cache
 
-        jax.config.update("jax_platforms", forced)
+        place_compile_cache()
     if args.service not in ("scheduler", "dfleet", "ledger-api", "kv-api"):
         if not args.ledger_url:
             parser.error("--ledger-url (or LEDGER_URL env) required")

@@ -1678,19 +1678,14 @@ class SchedulerBackendServicer:
         return flushed
 
     def Health(self, request: pb.HealthRequest, context) -> pb.HealthResponse:
-        import jax
+        from protocol_tpu.utils.platform import device_summary
 
         # deterministic fleet sweep: health probes are the periodic
         # traffic every deployment already has, so idle expired sessions
         # release their arena bytes here instead of waiting for the next
         # data-path touch (the fabric also sweeps under budget pressure)
         self.sessions.sweep()
-        devices = jax.devices()
-        resp = pb.HealthResponse(
-            status="ok",
-            platform=devices[0].platform if devices else "none",
-            device_count=len(devices),
-        )
+        resp = pb.HealthResponse(status="ok", **device_summary())
         seam = dict(self.seam.snapshot())
         seam["sessions_active"] = float(len(self.sessions))
         seam["session_evictions"] = float(self.sessions.evictions)
@@ -2127,10 +2122,10 @@ class RemoteBatchMatcher(TpuBatchMatcher):
 
     def attach_groups(self, plugin) -> None:
         # The group solve is tiny (groups x tasks) and runs in-process even
-        # on the remote matcher — but this control-plane host must never
-        # lazily initialize a remote accelerator platform (a wedged tunnel
-        # would hang the solve path). Pin jax to the host CPU first; every
-        # LARGE solve still rides the gRPC seam.
+        # on the remote matcher — but this control-plane process must not
+        # claim the chip the scheduler pod owns (a chip belongs to one
+        # process at a time). Pin jax to the host CPU first; every LARGE
+        # solve still rides the gRPC seam.
         import jax
 
         jax.config.update("jax_platforms", "cpu")

@@ -130,8 +130,8 @@ def detect_compute_specs(
     detection via the JAX device list when available.
 
     ``probe_accelerator=False`` skips the jax.devices() call — backend
-    initialization can block indefinitely when a remote accelerator plugin
-    is unreachable, and control-plane processes must boot regardless.
+    initialization claims the accelerator for this process, and a
+    control-plane process must not take the chip the scheduler pod owns.
     """
     report = IssueReport()
     cores = os.cpu_count() or 1

@@ -65,7 +65,9 @@ def main(argv=None) -> int:
         return 0
 
     from protocol_tpu.stream.replay import stream_replay
+    from protocol_tpu.utils.platform import place_compile_cache
 
+    place_compile_cache()
     chaos = None
     if args.chaos:
         from protocol_tpu.faults.plan import ChaosConfig

@@ -254,6 +254,10 @@ def _stream_replay(
         "reconcile_wall_ms": [],
         "recon_ticks": [],
     }
+    if eng == "jax":
+        from protocol_tpu.utils.platform import device_summary
+
+        report.update(device_summary())
     recon_p4ts: list = []
     gap_every_event: list = []
     delivered = 0

@@ -364,6 +364,13 @@ def replay(
         "tick_wall_ms": [],
         "assigned": [],
     }
+    if eng == "jax":
+        # the backend the jax engine's walls and plans came from (a
+        # golden recorded under jax:cpu is not expected to verify on
+        # another float pipeline)
+        from protocol_tpu.utils.platform import device_summary
+
+        report.update(device_summary())
     p4ts: list = []
     tick_stats: list = []  # scalar per-tick stats (quality plane)
     try:

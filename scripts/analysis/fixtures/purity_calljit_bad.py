@@ -11,7 +11,7 @@ import jax
 import numpy as np
 from functools import partial
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _sync_body(cost):

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 from functools import partial
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _pure_body(cost):

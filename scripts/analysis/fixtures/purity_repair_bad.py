@@ -13,7 +13,7 @@ import jax
 import numpy as np
 from jax import lax
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @lru_cache(maxsize=32)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @lru_cache(maxsize=32)

@@ -9,7 +9,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 PROVIDER_AXIS = "p"

@@ -130,12 +130,13 @@ def build_file() -> dp.FileDescriptorProto:
         ("price", 5, "float", True),
     ])
     _msg(fd, "HealthRequest", [])
-    # v1 fields 1-3 frozen; 4 is a v2 addition old clients skip as unknown
+    # v1 fields 1-3 frozen; 4-5 are additions old clients skip as unknown
     _msg(fd, "HealthResponse", [
         ("status", 1, "string", False),
         ("platform", 2, "string", False),
         ("device_count", 3, "uint32", False),
         ("seam_metrics", 4, "MetricSample", True),
+        ("device_kind", 5, "string", False),  # jax device_kind
     ])
 
     # ---------------- v2: tensor frames + session epochs ----------------

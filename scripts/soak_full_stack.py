@@ -195,7 +195,7 @@ class Stack:
         self.worker_ports = [free_port() for _ in self.node_keys]
         self.base_env = dict(
             os.environ,
-            PROTOCOL_TPU_FORCE_PLATFORM="cpu",
+            JAX_PLATFORMS="cpu",
             LEDGER_API_KEY="admin",
             KV_API_KEY="admin",
             # pods derive their identity from hex keys under the SAME

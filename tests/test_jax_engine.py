@@ -9,8 +9,8 @@ Contracts under test, at unit grain:
   - the regen-exactness contract (a warm chain's candidate structure is
     bit-identical to a from-scratch rebuild on the current columns);
   - device-count INVARIANCE of sharded generation (D=1 == D=4 == D=8,
-    bit for bit, through the ``parallel/_compat`` shard_map shim on the
-    conftest's virtual 8-device CPU mesh) — the property that makes the
+    bit for bit, through ``jax.shard_map`` on the conftest's virtual
+    8-device CPU mesh) — the property that makes the
     warm carry sound across device-count changes;
   - degradation INSIDE the engine: over-asking for devices clamps with
     a counted, non-fatal provenance flag, never a silent native
@@ -300,7 +300,7 @@ class TestJitCacheWitness:
 
 
 class TestDeviceInvarianceAndDegradation:
-    """Satellite 4: the shard_map shim's D-invariance at arena grain,
+    """Satellite 4: sharded generation's D-invariance at arena grain,
     and the degrade-inside-the-engine contract."""
 
     @pytest.mark.parametrize("D", [2, 4, 8])
@@ -416,13 +416,41 @@ class TestDeviceInvarianceAndDegradation:
         )
         assert ref.device_degraded is False
 
-    def test_compat_shim_exports_shard_map(self):
-        """The parallel/_compat seam every mesh kernel imports through:
-        present and callable on this runtime (promoted or experimental
-        home — the shim hides which)."""
-        from protocol_tpu.parallel import _compat
+    def test_mesh_kernels_build_under_jax_shard_map(self, monkeypatch):
+        """Every mesh kernel family stages through ``jax.shard_map``
+        itself, with nothing version-shaped in between: a spy on the
+        promoted API sees the dense auction, the sharded Sinkhorn and
+        the sparse phase builder each hand it their mesh, and the
+        retired ``parallel/_compat`` seam is gone."""
+        import importlib
 
-        assert callable(_compat.shard_map)
+        from protocol_tpu.parallel import auction, make_mesh, sinkhorn, sparse
+
+        seen = []
+        real = jax.shard_map
+
+        def spy(fun, **kw):
+            seen.append((kw["mesh"], kw["check_vma"]))
+            return real(fun, **kw)
+
+        monkeypatch.setattr(jax, "shard_map", spy)
+        mesh = make_mesh(2)
+        builders = (
+            (auction._build_sharded_dense_auction, (mesh, "p", 0.01, 8)),
+            (sinkhorn._build_sharded_sinkhorn,
+             (mesh, "p", (1.0, 1.0, 0.001, 0.0), 0.05, 2, 8, 16)),
+            (sparse._build_sharded_phase, (mesh, "p", 16, 8, 8, True)),
+        )
+        try:
+            for build, args in builders:
+                build.cache_clear()
+                assert callable(build(*args))
+        finally:
+            for build, _ in builders:
+                build.cache_clear()  # drop the closures built over the spy
+        assert [m for m, _ in seen] == [mesh] * len(builders)
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module("protocol_tpu.parallel._compat")
 
 
 class TestExportRestore:
