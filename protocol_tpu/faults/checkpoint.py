@@ -73,10 +73,12 @@ import hashlib
 import json
 import logging
 import os
+from contextlib import contextmanager
 from typing import Optional
 
 import numpy as np
 
+from protocol_tpu.obs.spans import TRACER as _tracer
 from protocol_tpu.trace import format as tfmt
 
 log = logging.getLogger(__name__)
@@ -149,6 +151,19 @@ def journal_session_id(path: str) -> Optional[str]:
     return None
 
 
+@contextmanager
+def _frame_span(writer, kind: str):
+    """One journal frame (encode + DEFLATE + write) as a ``ckpt.frame``
+    span carrying the time the frame spent inside ``zlib.compress``."""
+    before = writer.deflate_ms
+    with _tracer.span("ckpt.frame", kind=kind) as span:
+        yield
+        if span is not None:
+            span["attrs"]["deflate_ms"] = round(
+                writer.deflate_ms - before, 3
+            )
+
+
 class SessionCheckpointer:
     """Per-session checkpoint writer/loader over ``<root>/<proc_id>/``
     (one namespace per servicer process; see the module docstring)."""
@@ -174,6 +189,10 @@ class SessionCheckpointer:
         self.handoffs = 0
         self.fence_refusals = 0
         self.journals_skipped = 0
+        # what the last successful flush cost (flush_ms, export_ms,
+        # deflate_ms, bytes_raw, bytes_out = the journal's size on
+        # disk); the servicer, which owns the seam, records it
+        self.last_flush: dict = {}
 
     def path_for(self, session_id: str) -> str:
         return os.path.join(self.directory, _fname(session_id))
@@ -229,9 +248,17 @@ class SessionCheckpointer:
         if self.fence_superseded():
             self.fence_refusals += 1
             return False
+        took: dict = {}
         try:
-            self._write_locked(session)
+            with _tracer.stage("ckpt.flush", took, "flush_ms") as span:
+                self._write_locked(session, took)
+                if span is not None:
+                    span["attrs"].update(
+                        bytes_raw=took["bytes_raw"],
+                        bytes_out=took["bytes_out"],
+                    )
             self.flushes += 1
+            self.last_flush = took
             return True
         except Exception:
             self.flush_failures += 1
@@ -241,11 +268,12 @@ class SessionCheckpointer:
             )
             return False
 
-    def _write_locked(self, session) -> None:
+    def _write_locked(self, session, took: dict) -> None:
         from protocol_tpu.proto import scheduler_pb2 as pb
         from protocol_tpu.proto import wire
 
-        state = session.arena.export_state()
+        with _tracer.stage("ckpt.export", took, "export_ms"):
+            state = session.arena.export_state()
         meta = {
             "kind": _META_KIND,
             "session_id": session.session_id,
@@ -285,33 +313,40 @@ class SessionCheckpointer:
                 # re-grounds on a mismatched-ISA load
                 "native_isa": state.pop("native_isa", "scalar"),
             }
-        req = pb.AssignRequestV2(
-            providers=wire.encode_providers_v2(
-                tfmt._as_ns(session.p_cols)
-            ),
-            requirements=wire.encode_requirements_v2(
-                tfmt._as_ns(session.r_cols)
-            ),
-            kernel=session.kernel,
-            top_k=session.top_k,
-        )
         final = self.path_for(session.session_id)
         tmp = final + ".tmp"
         writer = tfmt.TraceWriter(tmp, meta=meta)
         try:
-            writer.write_snapshot(
-                session.session_id, session.fingerprint, req
-            )
-            if state is not None:
-                writer.write_arena(state)
-            if session.last_p4t is not None:
-                writer.write_outcome(
-                    int(session.tick),
-                    np.asarray(session.last_p4t, np.int32),
+            with _frame_span(writer, "snapshot"):
+                writer.write_snapshot(
+                    session.session_id, session.fingerprint,
+                    pb.AssignRequestV2(
+                        providers=wire.encode_providers_v2(
+                            tfmt._as_ns(session.p_cols)
+                        ),
+                        requirements=wire.encode_requirements_v2(
+                            tfmt._as_ns(session.r_cols)
+                        ),
+                        kernel=session.kernel,
+                        top_k=session.top_k,
+                    ),
                 )
+            if state is not None:
+                with _frame_span(writer, "arena"):
+                    writer.write_arena(state)
+            if session.last_p4t is not None:
+                with _frame_span(writer, "outcome"):
+                    writer.write_outcome(
+                        int(session.tick),
+                        np.asarray(session.last_p4t, np.int32),
+                    )
         finally:
             writer.close()
         os.replace(tmp, final)
+        took.update(
+            deflate_ms=round(writer.deflate_ms, 3),
+            bytes_raw=writer.bytes_raw, bytes_out=writer.bytes_out,
+        )
 
     # ---------------- migration handoff ----------------
 

@@ -26,11 +26,26 @@ context, the servicer adopts it as the remote parent of its RPC root
 span, and a client tick stitches into one causal trace across
 processes. Cross-thread handoff inside a process works the same way —
 pass ``header()`` and open the child with ``remote_parent=``.
+
+The profiler's clock: while ``jax`` is loaded, :meth:`SpanTracer.span`
+also holds a ``jax.profiler.TraceAnnotation`` of the same name open, so
+during a profiler capture every program span lands on the trace's host
+plane under the clock of the device's own lines (inert, and a few
+hundred nanoseconds, when no capture runs). ``record_span`` and
+``point`` are stamped after the fact and cannot be mirrored: a region
+that should show beside the device trace is a ``with`` block.
+
+Adding a span: close it where the host ALREADY blocks or returns —
+never add a ``block_until_ready`` or a read of a device value to end
+one; a span over an asynchronous dispatch measures the dispatch and
+says so (``dispatch_only=True``).
 """
 
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -39,6 +54,14 @@ from typing import Iterable, Optional
 
 # gRPC metadata key (must be lowercase per the gRPC metadata contract)
 METADATA_KEY = "x-pt-span"
+
+
+def _annotation(name: str):
+    """An open-able profiler annotation named like the span, or None
+    while jax is not loaded (this module never imports it: the
+    native-only paths trace too)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(name)
 
 
 class SpanTracer:
@@ -52,7 +75,9 @@ class SpanTracer:
         self.capacity = int(capacity)
         self._lock = make_lock("tracer")
         self._ring: deque = deque(maxlen=self.capacity)
-        self._next_id = 1
+        # next() on a count is one C call: atomic under the GIL, so ids
+        # need no lock (a span takes the ring's lock once, to record)
+        self._ids = itertools.count(1)
         self._seq = 0  # completed spans ever (ring-overflow-proof cursor)
         self._tls = threading.local()
 
@@ -65,10 +90,7 @@ class SpanTracer:
         return st
 
     def _alloc_id(self) -> int:
-        with self._lock:
-            sid = self._next_id
-            self._next_id += 1
-            return sid
+        return next(self._ids)
 
     def _record(self, rec: dict) -> None:
         with self._lock:
@@ -107,9 +129,14 @@ class SpanTracer:
             "parent": parent, "t0_ns": t0, "attrs": dict(attrs),
         }
         stack.append(frame)
+        mirror = _annotation(name)
+        if mirror is not None:
+            mirror.__enter__()
         try:
             yield frame
         finally:
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
             t1 = time.perf_counter_ns()
             # pop by identity: a mismatched exit (generator abandoned
             # mid-span) must not corrupt an unrelated frame
@@ -119,6 +146,19 @@ class SpanTracer:
                 stack.remove(frame)
             frame["dur_ns"] = t1 - t0
             self._record(frame)
+
+    @contextmanager
+    def stage(self, name: str, out: dict, key: str, **attrs):
+        """A span whose wall also lands in ``out[key]`` (ms, rounded
+        like the arena's stage walls), tracer on or off: the counter
+        beside the span, for the stats dicts that ride next to
+        results (``last_stats``, a flush's timings)."""
+        t0 = time.perf_counter()
+        try:
+            with self.span(name, **attrs) as frame:
+                yield frame
+        finally:
+            out[key] = round((time.perf_counter() - t0) * 1e3, 3)
 
     def record_span(
         self, name: str, t0_ns: int, dur_ns: int, **attrs

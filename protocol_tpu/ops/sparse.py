@@ -26,12 +26,14 @@ BASELINE.md configs #3-#5.
 
 from __future__ import annotations
 
+import time
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from protocol_tpu.obs.spans import TRACER as _tracer
 from protocol_tpu.ops.assign import AssignResult, _invert
 from protocol_tpu.ops.cost import INFEASIBLE, CostWeights, cost_matrix, tie_jitter
 from protocol_tpu.ops.encoding import EncodedProviders, EncodedRequirements
@@ -233,25 +235,27 @@ def candidates_topk_reverse(
         rev_c0, rev_t0 = carry  # [P, r] running best (smallest) costs/tasks
         # forward: per-task top-k providers (the exact shared step —
         # jitter, offsets, approx_max_k — of candidates_topk)
-        provider, cost_k, cost = _forward_tile_select(
-            ep, er, weights, t0, tile, k,
-            provider_offset, task_offset, approx_recall,
-        )
+        with jax.named_scope("gen.forward"):
+            provider, cost_k, cost = _forward_tile_select(
+                ep, er, weights, t0, tile, k,
+                provider_offset, task_offset, approx_recall,
+            )
         # reverse: this tile's per-provider top-rt, then a tiny merge
-        tid = t0 + jnp.arange(tile, dtype=jnp.int32)
-        if rt == 1:
-            j = jnp.argmin(cost, axis=1)
-            tile_c = jnp.take_along_axis(cost, j[:, None], axis=1)
-            tile_t = tid[j][:, None]
-        else:
-            neg, j = lax.top_k(-cost, rt)
-            tile_c = -neg
-            tile_t = tid[j]
-        merged_c = jnp.concatenate([rev_c0, tile_c], axis=1)  # [P, r+rt]
-        merged_t = jnp.concatenate([rev_t0, tile_t], axis=1)
-        neg_c, m = lax.top_k(-merged_c, r)
-        rev_c1 = -neg_c
-        rev_t1 = jnp.take_along_axis(merged_t, m, axis=1)
+        with jax.named_scope("gen.reverse"):
+            tid = t0 + jnp.arange(tile, dtype=jnp.int32)
+            if rt == 1:
+                j = jnp.argmin(cost, axis=1)
+                tile_c = jnp.take_along_axis(cost, j[:, None], axis=1)
+                tile_t = tid[j][:, None]
+            else:
+                neg, j = lax.top_k(-cost, rt)
+                tile_c = -neg
+                tile_t = tid[j]
+            merged_c = jnp.concatenate([rev_c0, tile_c], axis=1)  # [P, r+rt]
+            merged_t = jnp.concatenate([rev_t0, tile_t], axis=1)
+            neg_c, m = lax.top_k(-merged_c, r)
+            rev_c1 = -neg_c
+            rev_t1 = jnp.take_along_axis(merged_t, m, axis=1)
         ys = (provider, cost_k)
         if with_pools:
             ys = ys + (tile_t, tile_c)
@@ -276,13 +280,14 @@ def candidates_topk_reverse(
     return out
 
 
-@partial(jax.jit, static_argnames=("extra",))
+@partial(jax.jit, static_argnames=("extra", "scope"))
 def merge_reverse_candidates(
     cand_p: jax.Array,
     cand_c: jax.Array,
     rev_t: jax.Array,
     rev_c: jax.Array,
     extra: int = 16,
+    scope: str = "gen.merge",
 ) -> tuple[jax.Array, jax.Array]:
     """Scatter reverse (provider -> task) edges into up to ``extra`` extra
     candidate columns per task: returns ([T, K+extra] provider ids, costs).
@@ -295,40 +300,45 @@ def merge_reverse_candidates(
     runner-up value equal its best (v1 == v2), collapsing every bid on that
     provider to the minimal +eps increment — measured as a slower, slightly
     WORSE matching than forward-only at 4k.
+
+    ``scope`` names the program's ops in a device trace: cold
+    generation and the warm repair both end in this merge, and each
+    says which it is (``gen.merge`` / ``repair.merge``).
     """
-    T = cand_p.shape[0]
-    P, r = rev_t.shape
-    t_flat = jnp.where(rev_t.reshape(-1) >= 0, rev_t.reshape(-1), T)
-    p_flat = jnp.repeat(jnp.arange(P, dtype=jnp.int32), r)
-    c_flat = rev_c.reshape(-1)
-    dup = jnp.any(
-        cand_p[jnp.minimum(t_flat, T - 1)] == p_flat[:, None], axis=1
-    )
-    t_flat = jnp.where(dup, T, t_flat)
-    order = jnp.lexsort((c_flat, t_flat))
-    t_s, p_s, c_s = t_flat[order], p_flat[order], c_flat[order]
-    n = t_s.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    new_seg = jnp.concatenate(
-        [jnp.ones(1, bool), t_s[1:] != t_s[:-1]]
-    )
-    run_start = lax.associative_scan(
-        jnp.maximum, jnp.where(new_seg, pos, -1)
-    )
-    rank = pos - run_start
-    keep = (t_s < T) & (rank < extra)
-    ti = jnp.where(keep, t_s, T)
-    ri = jnp.where(keep, rank, 0)
-    extra_p = jnp.full((T + 1, extra), -1, jnp.int32).at[ti, ri].set(
-        p_s, mode="drop"
-    )[:T]
-    extra_c = jnp.full((T + 1, extra), jnp.float32(INFEASIBLE)).at[ti, ri].set(
-        c_s, mode="drop"
-    )[:T]
-    return (
-        jnp.concatenate([cand_p, extra_p], axis=1),
-        jnp.concatenate([cand_c, extra_c], axis=1),
-    )
+    with jax.named_scope(scope):
+        T = cand_p.shape[0]
+        P, r = rev_t.shape
+        t_flat = jnp.where(rev_t.reshape(-1) >= 0, rev_t.reshape(-1), T)
+        p_flat = jnp.repeat(jnp.arange(P, dtype=jnp.int32), r)
+        c_flat = rev_c.reshape(-1)
+        dup = jnp.any(
+            cand_p[jnp.minimum(t_flat, T - 1)] == p_flat[:, None], axis=1
+        )
+        t_flat = jnp.where(dup, T, t_flat)
+        order = jnp.lexsort((c_flat, t_flat))
+        t_s, p_s, c_s = t_flat[order], p_flat[order], c_flat[order]
+        n = t_s.shape[0]
+        pos = jnp.arange(n, dtype=jnp.int32)
+        new_seg = jnp.concatenate(
+            [jnp.ones(1, bool), t_s[1:] != t_s[:-1]]
+        )
+        run_start = lax.associative_scan(
+            jnp.maximum, jnp.where(new_seg, pos, -1)
+        )
+        rank = pos - run_start
+        keep = (t_s < T) & (rank < extra)
+        ti = jnp.where(keep, t_s, T)
+        ri = jnp.where(keep, rank, 0)
+        extra_p = jnp.full((T + 1, extra), -1, jnp.int32).at[ti, ri].set(
+            p_s, mode="drop"
+        )[:T]
+        extra_c = jnp.full((T + 1, extra), jnp.float32(INFEASIBLE)).at[ti, ri].set(
+            c_s, mode="drop"
+        )[:T]
+        return (
+            jnp.concatenate([cand_p, extra_p], axis=1),
+            jnp.concatenate([cand_c, extra_c], axis=1),
+        )
 
 
 def pick_tile(n_tasks: int, cap: int = 1024) -> int:
@@ -460,38 +470,40 @@ def _sparse_auction_phase(
         open_mask = (p4t < 0) & task_feasible & ~retired  # [T]
 
         # ---- frontier selection: up to B open tasks (fill = T -> dropped)
-        f_idx = jnp.flatnonzero(open_mask, size=B, fill_value=T).astype(jnp.int32)
-        f_ok = f_idx < T
-        p1, v1, v2 = frontier_bids(cand_safe, value_base, price, f_idx, f_ok, K)
+        with jax.named_scope("auction.bid"):
+            f_idx = jnp.flatnonzero(open_mask, size=B, fill_value=T).astype(jnp.int32)
+            f_ok = f_idx < T
+            p1, v1, v2 = frontier_bids(cand_safe, value_base, price, f_idx, f_ok, K)
 
-        newly_retired = f_ok & (v1 < give_up)
-        retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(True, mode="drop")
+            newly_retired = f_ok & (v1 < give_up)
+            bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
+            bid_amt = price[p1] + (v1 - v2) + eps  # [B]
+            tgt = jnp.where(bidding, p1, P)
 
-        bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
-        bid_amt = price[p1] + (v1 - v2) + eps  # [B]
-        tgt = jnp.where(bidding, p1, P)
+        with jax.named_scope("auction.resolve"):
+            win_bid = jnp.full(P, _NEG).at[tgt].max(
+                jnp.where(bidding, bid_amt, _NEG), mode="drop"
+            )
+            # among max bidders per provider, lowest task index wins
+            is_winner_bid = bidding & (bid_amt >= win_bid[p1])
+            win_task = jnp.full(P, T, jnp.int32).at[tgt].min(
+                jnp.where(is_winner_bid, f_idx, T), mode="drop"
+            )
+            got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
 
-        win_bid = jnp.full(P, _NEG).at[tgt].max(
-            jnp.where(bidding, bid_amt, _NEG), mode="drop"
-        )
-        # among max bidders per provider, lowest task index wins
-        is_winner_bid = bidding & (bid_amt >= win_bid[p1])
-        win_task = jnp.full(P, T, jnp.int32).at[tgt].min(
-            jnp.where(is_winner_bid, f_idx, T), mode="drop"
-        )
-        got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
-
-        evict_t = jnp.where(got_bid & (owner >= 0), owner, T)
-        p4t = p4t.at[evict_t].set(-1, mode="drop")
-        p_idx = jnp.arange(P, dtype=jnp.int32)
-        win_t_safe = jnp.where(got_bid, win_task, T)
-        p4t = p4t.at[win_t_safe].set(jnp.where(got_bid, p_idx, -1), mode="drop")
-        owner = jnp.where(got_bid, win_task, owner)
-        price = jnp.where(got_bid, win_bid, price)
-        n_now = jnp.sum(p4t >= 0)
-        improved = n_now > best
-        best = jnp.maximum(best, n_now)
-        stall = jnp.where(improved, 0, stall + 1)
+        with jax.named_scope("auction.commit"):
+            retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(True, mode="drop")
+            evict_t = jnp.where(got_bid & (owner >= 0), owner, T)
+            p4t = p4t.at[evict_t].set(-1, mode="drop")
+            p_idx = jnp.arange(P, dtype=jnp.int32)
+            win_t_safe = jnp.where(got_bid, win_task, T)
+            p4t = p4t.at[win_t_safe].set(jnp.where(got_bid, p_idx, -1), mode="drop")
+            owner = jnp.where(got_bid, win_task, owner)
+            price = jnp.where(got_bid, win_bid, price)
+            n_now = jnp.sum(p4t >= 0)
+            improved = n_now > best
+            best = jnp.maximum(best, n_now)
+            stall = jnp.where(improved, 0, stall + 1)
         return (it + 1, price, owner, p4t, retired), best, stall
 
     if state is None:
@@ -511,6 +523,7 @@ def _sparse_auction_phase(
 
 
 @jax.jit
+@jax.named_scope("auction.unassign_unhappy")
 def _unassign_unhappy(cand_provider, cand_cost, price, owner, p4t, eps_next):
     """eps-CS repair between phases: holders whose assignment violates the
     tighter eps re-enter the auction; happy holders stay seated (avoids both
@@ -542,6 +555,7 @@ def _unassign_unhappy(cand_provider, cand_cost, price, owner, p4t, eps_next):
 
 
 @partial(jax.jit, static_argnames=("budget",))
+@jax.named_scope("auction.greedy_cleanup")
 def _greedy_cleanup_compacted(cand_provider, cand_cost, owner, p4t, budget: int):
     """Forward auctions never lower prices, so an unfillable tail can strand
     providers at pumped prices. Sweep the OPEN tasks greedily (cheapest free
@@ -653,7 +667,10 @@ def assign_auction_sparse_scaled(
     # frontier_ladder: adaptive per-phase frontier shrink (see
     # _phase_adaptive) — disable to pin the exact Jacobi schedule (the
     # sharded-parity tests compare against the fixed-frontier mesh kernel)
-    phase_fn = _phase_adaptive if frontier_ladder else _sparse_auction_phase
+    phase_fn = (
+        partial(_phase_adaptive, stats_out=stats_out)
+        if frontier_ladder else _sparse_auction_phase
+    )
     while True:
         final = eps <= eps_end
         state, stall = phase_fn(
@@ -680,15 +697,18 @@ def assign_auction_sparse_scaled(
             break
         eps = max(eps * scale, eps_end)
         it, price, owner, p4t, retired = state
-        owner, p4t = _unassign_unhappy(
-            cand_provider, cand_cost, price, owner, p4t, eps
-        )
-        # un-retire: coarse-phase retirement was only the circuit breaker
-        retired = jnp.zeros_like(retired)
+        with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
+            owner, p4t = _unassign_unhappy(
+                cand_provider, cand_cost, price, owner, p4t, eps
+            )
+            # un-retire: coarse-phase retirement was only the circuit
+            # breaker
+            retired = jnp.zeros_like(retired)
         state = (it, price, owner, p4t, retired)
 
     _, price, owner, p4t, retired = state
-    p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+    with _tracer.span("auction.cleanup"):
+        p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
         # a retired task the greedy cleanup managed to seat is assigned,
@@ -709,6 +729,7 @@ def _phase_adaptive(
     frontier: int,
     retire: bool,
     stall_limit: int,
+    stats_out: dict | None = None,
 ):
     """One eps phase run in SEGMENTS with a shrinking frontier executable.
 
@@ -731,6 +752,11 @@ def _phase_adaptive(
     size for the same retrace reason; the phase budget is honored at
     segment granularity (up to seg_rounds-1 extra rounds past
     ``max_iters``, a budget-cap semantic, not a correctness one).
+
+    Each segment is one ``auction.segment`` span (the finest grain the
+    solve is traced at: nothing per round). ``stats_out`` gains
+    ``segments`` and ``wait_ms``, the time the host spent inside the
+    segment's blocking scalar reads — its view of the device's time.
     """
     seg_rounds = 256
     T = cand_cost.shape[0]
@@ -739,32 +765,50 @@ def _phase_adaptive(
     total_it = 0
     B = min(frontier, T)
     carried_stall = 0
+    segments = 0
+    wait_s = 0.0
     while iters_left > 0:
-        state, stall = _sparse_auction_phase(
-            cand_provider, cand_cost, num_providers, state,
-            eps=eps, max_iters=seg_rounds, frontier=B, retire=retire,
-            stall_limit=0,
-        )
-        it = int(state[0])
-        total_it += it
-        iters_left -= it
-        s = int(stall)
-        carried_stall = carried_stall + it if s >= it else s
-        if it < seg_rounds:
-            break  # converged or emptied
-        if stall_limit > 0 and carried_stall >= stall_limit:
-            break  # circuit breaker (segment-boundary granularity)
-        # candidate-less tasks stay open forever: they must not pin the
-        # frontier large (the kernel's own open_mask excludes them too)
-        open_count = int(
-            jnp.sum((state[3] < 0) & ~state[4] & task_feasible)
-        )
+        with _tracer.span("auction.segment", frontier=B) as seg:
+            state, stall = _sparse_auction_phase(
+                cand_provider, cand_cost, num_providers, state,
+                eps=eps, max_iters=seg_rounds, frontier=B, retire=retire,
+                stall_limit=0,
+            )
+            t_wait = time.perf_counter()
+            it = int(state[0])
+            s = int(stall)
+            total_it += it
+            iters_left -= it
+            carried_stall = carried_stall + it if s >= it else s
+            # the phase goes on only after a full segment under the
+            # circuit breaker (checked at segment-boundary granularity)
+            # with tasks still open; candidate-less tasks stay open
+            # forever and must not pin the frontier large (the kernel's
+            # own open_mask excludes them too)
+            open_count = 0
+            if it == seg_rounds and not (
+                stall_limit > 0 and carried_stall >= stall_limit
+            ):
+                open_count = int(
+                    jnp.sum((state[3] < 0) & ~state[4] & task_feasible)
+                )
+            waited = time.perf_counter() - t_wait
+            segments += 1
+            wait_s += waited
+            if seg is not None:
+                seg["attrs"].update(
+                    rounds=it, open_count=open_count,
+                    wait_ms=round(waited * 1e3, 3),
+                )
         if open_count == 0:
             break
         fit = 512
         while fit < open_count and fit < B:
             fit *= 2
         B = min(B, fit)
+    if stats_out is not None:
+        stats_out["segments"] = stats_out.get("segments", 0) + segments
+        stats_out["wait_ms"] = stats_out.get("wait_ms", 0.0) + wait_s * 1e3
     # report the PHASE's total rounds in the state's counter slot (each
     # segment resets it; the ladder's rounds_total sums these) and the
     # ACCUMULATED stall so _report_stall sees breaker trips (the last
@@ -859,27 +903,31 @@ def assign_auction_sparse_warm(
     # regression). Negative prices are fine: the auction only ever
     # compares price DIFFERENCES (values -cost - price and bid
     # increments), never absolute levels.
-    finite_max = jnp.max(jnp.where(cand_provider >= 0, cand_cost, 0.0))
-    price0 = jnp.asarray(price0, jnp.float32)
-    shift = jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
-    price0 = price0 - shift
-    owner0 = _invert(p4t0, num_providers)
-    owner0, p4t0 = _unassign_unhappy(
-        cand_provider, cand_cost, price0, owner0, p4t0, eps
+    with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
+        finite_max = jnp.max(jnp.where(cand_provider >= 0, cand_cost, 0.0))
+        price0 = jnp.asarray(price0, jnp.float32)
+        shift = jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
+        price0 = price0 - shift
+        owner0 = _invert(p4t0, num_providers)
+        owner0, p4t0 = _unassign_unhappy(
+            cand_provider, cand_cost, price0, owner0, p4t0, eps
+        )
+        if retired0 is None:
+            retired_seed = jnp.zeros(cand_cost.shape[0], bool)
+        else:
+            # a seeded assignment outranks a stale retirement flag
+            retired_seed = jnp.asarray(retired0, bool) & (p4t0 < 0)
+        state = (
+            jnp.int32(0),
+            jnp.asarray(price0, jnp.float32),
+            owner0,
+            p4t0,
+            retired_seed,
+        )
+    phase_fn = (
+        partial(_phase_adaptive, stats_out=stats_out)
+        if frontier_ladder else _sparse_auction_phase
     )
-    if retired0 is None:
-        retired_seed = jnp.zeros(cand_cost.shape[0], bool)
-    else:
-        # a seeded assignment outranks a stale retirement flag
-        retired_seed = jnp.asarray(retired0, bool) & (p4t0 < 0)
-    state = (
-        jnp.int32(0),
-        jnp.asarray(price0, jnp.float32),
-        owner0,
-        p4t0,
-        retired_seed,
-    )
-    phase_fn = _phase_adaptive if frontier_ladder else _sparse_auction_phase
     state, stall = phase_fn(
         cand_provider, cand_cost, num_providers, state,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
@@ -894,7 +942,8 @@ def assign_auction_sparse_warm(
         # per-round kernel cost (see assign_auction_sparse_scaled)
         stats_out["rounds_total"] = int(state[0])
     _, price, owner, p4t, retired = state
-    p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+    with _tracer.span("auction.cleanup"):
+        p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
         return res, price, retired & (p4t < 0)

@@ -469,10 +469,10 @@ class JaxSolveArena:
         the given dirty global rows and rebuild the merged lists —
         bit-identical to what :meth:`_gen` would produce on the current
         columns (the repaired==regen oracle contract), at O(churn
-        scope) instead of O(P*T). Updates the stored structure in place
-        and returns (changed-row mask vs the PREVIOUS merged lists,
-        repair-scope stats). Caller guarantees parts exist
-        (``approx_recall is None`` and the arena is primed)."""
+        scope) instead of O(P*T). Stores the repaired parts and returns
+        (cand_p, cand_c, repair-scope stats) for :meth:`_adopt`. Caller
+        guarantees parts exist (``approx_recall is None`` and the arena
+        is primed)."""
         from protocol_tpu.parallel.sparse import repair_topk_bidir_sharded
 
         ep = EncodedProviders(**pf)
@@ -491,15 +491,66 @@ class JaxSolveArena:
                 pad_floors=self._repair_pads,
             )
         )
-        self._repair_pads = dict(stats.get("pad_hw") or {})
-        changed = (
-            (cand_p != self._cand_p).any(axis=1)
-            | (cand_c != self._cand_c).any(axis=1)
-        )
-        self._cand_p, self._cand_c = cand_p, cand_c
+        self._repair_pads = dict(stats.pop("pad_hw"))
         self._fwd_p, self._fwd_c = fwd_p, fwd_c
         self._pool_t, self._pool_c = pool_t, pool_c
-        return changed, stats
+        return cand_p, cand_c, stats
+
+    def _maintain(self, pf, rf, weights, dirty_p, dirty_t, **attrs):
+        """Warm candidate maintenance for the dirty global rows, shared
+        by the batch tick and the single event: the churn-masked repair
+        (recompute exactly the flagged forward rows and reverse pools
+        and re-merge — bit-identical to a full regen on the current
+        columns, without the O(P*T) pass), then :meth:`_adopt`.
+        ``approx_recall`` arenas have no parts (no exactness contract
+        under approx_max_k) and keep the honest, counted full regen.
+        Returns (changed mask, stats with the stage walls, sharded,
+        cand_cold_passes)."""
+        with _tracer.span(
+            "arena.candidates", cold=False, dirty_providers=dirty_p.size,
+            dirty_tasks=dirty_t.size, **attrs,
+        ):
+            if self._fwd_p is not None:
+                cand_p, cand_c, rep = self._repair(
+                    pf, rf, weights, dirty_p, dirty_t
+                )
+                sharded = self._gen_plan(rf["cpu_cores"].shape[0])[1]
+                cold_passes = 0
+            else:
+                cand_p, cand_c, sharded = self._gen(pf, rf, weights)
+                rep = {}
+                cold_passes = 1
+            changed = self._adopt(cand_p, cand_c, dirty_t, rep)
+        return changed, rep, sharded, cold_passes
+
+    def _adopt(self, cand_p, cand_c, dirty_t, out: dict) -> np.ndarray:
+        """Take ``cand_p``/``cand_c`` as the merged lists and return the
+        changed-row mask against the PREVIOUS ones (membership moved or
+        any cost moved — a superset of "materially cheaper", so clearing
+        retirement on it is sound, just occasionally generous). Dirty
+        tasks (``dirty_t``, global rows) count as changed and lose
+        their seat: it predates the new requirement. Feasibility guard:
+        a seat whose provider left the row's list is unseated here (only
+        changed rows can have lost one). ``out["diff_ms"]`` is the wall
+        of all of it."""
+        with _tracer.stage("arena.diff", out, "diff_ms"):
+            changed = (
+                (cand_p != self._cand_p).any(axis=1)
+                | (cand_c != self._cand_c).any(axis=1)
+            )
+            self._cand_p, self._cand_c = cand_p, cand_c
+            if dirty_t.size:
+                self._p4t[dirty_t] = -1
+                changed[dirty_t] = True
+            seat_check = np.flatnonzero(changed & (self._p4t >= 0))
+            if seat_check.size:
+                in_list = (
+                    self._cand_p[seat_check] == self._p4t[seat_check, None]
+                ).any(axis=1)
+                lost = seat_check[~in_list]
+                if lost.size:
+                    self._p4t[lost] = -1
+        return changed
 
     def _ladder(self, P: int, eng: Optional[dict]):
         """Cold/refresh solve stage: the eps-annealed auction ladder
@@ -509,14 +560,26 @@ class JaxSolveArena:
             num_providers=P, eps_start=self.eps_start,
             eps_end=self.eps_end, stats_out=eng, with_state=True,
         )
-        # np.array (not asarray): asarray over a device buffer hands back
-        # a READ-ONLY view, and the arena mutates p4t in place on the
-        # seat-guard and dirty-row paths. Owned copies, always.
-        return (
-            np.array(res.provider_for_task, np.int32),
-            np.array(price, np.float32),
-            np.array(retired, bool),
-        )
+        return self._readback(res, price, retired, eng)
+
+    @staticmethod
+    def _readback(res, price, retired, eng: Optional[dict]):
+        """The solve's three results as OWNED host copies. np.array (not
+        asarray): asarray over a device buffer hands back a READ-ONLY
+        view, and the arena mutates p4t in place on the seat-guard and
+        dirty-row paths; the carried structure must stay writable
+        across warm ticks. The copies block until the device is done,
+        so their wall joins the segments' in ``eng["wait_ms"]``."""
+        took: dict = {}
+        with _tracer.stage("arena.readback", took, "ms"):
+            out = (
+                np.array(res.provider_for_task, np.int32),
+                np.array(price, np.float32),
+                np.array(retired, bool),
+            )
+        if eng is not None:
+            eng["wait_ms"] = round(eng.get("wait_ms", 0.0) + took["ms"], 3)
+        return out
 
     def _warm(
         self, P: int, p4t0: np.ndarray, changed: np.ndarray,
@@ -537,27 +600,22 @@ class JaxSolveArena:
             retired0=jnp.asarray(self._retired & ~changed),
             stats_out=eng, with_state=True,
         )
-        # Owned copies for the same reason as _ladder: the carried
-        # structure must stay writable across warm ticks.
-        return (
-            np.array(res.provider_for_task, np.int32),
-            np.array(price, np.float32),
-            np.array(retired, bool),
-        )
+        return self._readback(res, price, retired, eng)
 
     def _quality_pass(
         self, rf: dict, p4t, price, prev_p4t, eng: Optional[dict] = None
     ) -> dict:
-        t0 = time.perf_counter()
-        stats, self._starve_age = _quality.tick_quality(
-            self._cand_p, self._cand_c, p4t, price,
-            valid=rf["valid"].astype(bool),
-            prev_p4t=prev_p4t,
-            starve_age=self._starve_age,
-            outcomes=None,
-            eng=eng,
-        )
-        stats["quality_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+        took: dict = {}
+        with _tracer.stage("arena.quality", took, "quality_ms"):
+            stats, self._starve_age = _quality.tick_quality(
+                self._cand_p, self._cand_c, p4t, price,
+                valid=rf["valid"].astype(bool),
+                prev_p4t=prev_p4t,
+                starve_age=self._starve_age,
+                outcomes=None,
+                eng=eng,
+            )
+        stats.update(took)
         self._last_quality = stats
         return stats
 
@@ -708,36 +766,13 @@ class JaxSolveArena:
             return self._p4t.copy()
 
         eng: Optional[dict] = {} if obs.enabled() else None
-        if self._fwd_p is not None:
-            changed, rep = self._repair(
-                self._p_fields, self._r_fields, weights, dirty_p, dirty_t
-            )
-            sharded = self._gen_plan(T)[1]
-            cold_passes = 0
-        else:
-            cand_p, cand_c, sharded = self._gen(
-                self._p_fields, self._r_fields, weights
-            )
-            changed = (
-                (cand_p != self._cand_p).any(axis=1)
-                | (cand_c != self._cand_c).any(axis=1)
-            )
-            self._cand_p, self._cand_c = cand_p, cand_c
-            rep = {}
-            cold_passes = 1
-        if n_dt:
-            self._p4t[dirty_t] = -1
-            changed[dirty_t] = True
-        seat_check = np.flatnonzero(changed & (self._p4t >= 0))
-        if seat_check.size:
-            in_list = (
-                self._cand_p[seat_check] == self._p4t[seat_check, None]
-            ).any(axis=1)
-            lost = seat_check[~in_list]
-            if lost.size:
-                self._p4t[lost] = -1
+        changed, rep, sharded, cold_passes = self._maintain(
+            self._p_fields, self._r_fields, weights, dirty_p, dirty_t,
+            event=True,
+        )
         t_gen = time.perf_counter()
-        p4t, price, retired = self._warm(P, self._p4t, changed, eng)
+        with _tracer.span("arena.engine", engine="jax", cold=False):
+            p4t, price, retired = self._warm(P, self._p4t, changed, eng)
         t_solve = time.perf_counter()
         self._price, self._retired, self._p4t = price, retired, p4t
         self.last_repair_mask = changed
@@ -813,27 +848,37 @@ class JaxSolveArena:
             return self._solve_impl(ep, er, weights)
 
     def _solve_impl(self, ep, er, weights) -> np.ndarray:
-        pf = _canon(ep, _P_SPEC)
-        rf = _canon(er, _R_SPEC)
-        P = pf["gpu_count"].shape[0]
-        T = rf["cpu_cores"].shape[0]
+        # dirty detection: the O(P+T) canonicalisation and value diff
+        # over every column that every tick pays before any stage wall
+        # starts (a cold tick pays the canonicalisation alone)
+        took: dict = {}
+        with _tracer.stage("arena.dirty", took, "dirty_ms"):
+            pf = _canon(ep, _P_SPEC)
+            rf = _canon(er, _R_SPEC)
+            P = pf["gpu_count"].shape[0]
+            T = rf["cpu_cores"].shape[0]
+            warm = (
+                P > 0 and T > 0
+                and self._shapes_compatible(pf, rf)
+                and self._weights_key == self._wkey(weights)
+                and self._warm_solves < self.cold_every
+            )
+            if warm:
+                dirty_p = np.flatnonzero(
+                    _dirty_rows(pf, self._p_fields, _P_SPEC)
+                )
+                dirty_t = np.flatnonzero(
+                    _dirty_rows(rf, self._r_fields, _R_SPEC)
+                )
         if P == 0 or T == 0:
             self.last_stats = {
                 "native_isa": jax_isa(), "engine": "jax",
                 "cold": True, "assigned": 0,
             }
             return np.full(T, -1, np.int32)
-
-        if (
-            not self._shapes_compatible(pf, rf)
-            or self._weights_key != self._wkey(weights)
-            or self._warm_solves >= self.cold_every
-        ):
+        if not warm:
             return self._cold(weights, pf, rf, P, T)
-
-        dirty_p = _dirty_rows(pf, self._p_fields, _P_SPEC)
-        dirty_t = _dirty_rows(rf, self._r_fields, _R_SPEC)
-        n_dp, n_dt = int(dirty_p.sum()), int(dirty_t.sum())
+        n_dp, n_dt = int(dirty_p.size), int(dirty_t.size)
         if (n_dp + n_dt) / (P + T) > self.max_dirty_frac:
             return self._cold(weights, pf, rf, P, T)
         if n_dp == 0 and n_dt == 0:
@@ -882,72 +927,24 @@ class JaxSolveArena:
         self._p_fields, self._r_fields = pf, rf
         self._owned_cols = set()
 
-        # ---- churn-masked structure repair: recompute exactly the
-        # flagged forward rows and reverse pools and re-merge —
-        # bit-identical to a full regen on the current columns (the
-        # repaired==regen oracle contract), without the O(P*T) pass.
-        # The changed-row diff against the previous merged lists is
-        # still exact (membership moved or any cost moved — a superset
-        # of "materially cheaper", so clearing retirement on it is
-        # sound, just occasionally generous). approx_recall arenas have
-        # no parts (no exactness contract under approx_max_k) and keep
-        # the honest full-regen path.
-        if self._fwd_p is not None:
-            changed, rep = self._repair(
-                pf, rf, weights,
-                np.flatnonzero(dirty_p), np.flatnonzero(dirty_t),
-            )
-            sharded = self._gen_plan(T)[1]
-            cold_passes = 0
-        else:
-            cand_p, cand_c, sharded = self._gen(pf, rf, weights)
-            changed = (
-                (cand_p != self._cand_p).any(axis=1)
-                | (cand_c != self._cand_c).any(axis=1)
-            )
-            self._cand_p, self._cand_c = cand_p, cand_c
-            rep = {}
-            cold_passes = 1
-        if n_dt:
-            # a dirty task's seat predates its new requirement: re-seat
-            # from scratch
-            di = np.flatnonzero(dirty_t)
-            self._p4t[di] = -1
-            changed[di] = True
-
-        # ---- feasibility guard: a seat whose provider left the row's
-        # candidate list must be unseated here (only changed rows can
-        # have lost one — unchanged rows kept identical lists)
-        seat_check = np.flatnonzero(changed & (self._p4t >= 0))
-        if seat_check.size:
-            in_list = (
-                self._cand_p[seat_check] == self._p4t[seat_check, None]
-            ).any(axis=1)
-            lost = seat_check[~in_list]
-            if lost.size:
-                self._p4t[lost] = -1
-
-        t_gen = time.perf_counter()
-        _tracer.record_span(
-            "arena.candidates", int(t_start * 1e9),
-            int((t_gen - t_start) * 1e9), cold=False,
-            dirty_providers=n_dp, dirty_tasks=n_dt,
+        changed, rep, sharded, cold_passes = self._maintain(
+            pf, rf, weights, dirty_p, dirty_t
         )
+        t_gen = time.perf_counter()
         dual_refresh = (
             self.dual_refresh_every > 0
             and self._dual_age >= self.dual_refresh_every
         )
-        if dual_refresh:
-            p4t, price, retired = self._ladder(P, eng)
-            self._dual_age = 0
-        else:
-            p4t, price, retired = self._warm(P, self._p4t, changed, eng)
-            self._dual_age += 1
+        with _tracer.span("arena.engine", engine="jax", cold=False):
+            if dual_refresh:
+                p4t, price, retired = self._ladder(P, eng)
+                self._dual_age = 0
+            else:
+                p4t, price, retired = self._warm(
+                    P, self._p4t, changed, eng
+                )
+                self._dual_age += 1
         t_solve = time.perf_counter()
-        _tracer.record_span(
-            "arena.engine", int(t_gen * 1e9),
-            int((t_solve - t_gen) * 1e9), engine="jax", cold=False,
-        )
         self._price, self._retired, self._p4t = price, retired, p4t
         self._warm_solves += 1
         qual = (
@@ -959,6 +956,7 @@ class JaxSolveArena:
             **qual,
             "cold": False,
             "cand_cold_passes": cold_passes,
+            **took,
             **rep,
             "dual_refresh": dual_refresh,
             "dirty_providers": n_dp,

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from protocol_tpu.obs.spans import TRACER as _tracer
 from protocol_tpu.ops.assign import AssignResult, _invert
 from protocol_tpu.ops.sparse import frontier_bids
 
@@ -579,22 +580,24 @@ def _build_sharded_gen(
             # shared forward step: jitter keyed on the GLOBAL task index
             # via task_offset, so each shard produces exactly the columns
             # the single-device scan would at its global tile
-            provider, cost_k, cost = _forward_tile_select(
-                ep_rep, er_local, weights, t0, tile, k,
-                None, offset, approx_recall,
-            )
-            tid = offset.astype(jnp.int32) + t0 + jnp.arange(tile, dtype=jnp.int32)
-            if rt == 1:
-                j = jnp.argmin(cost, axis=1)
-                tile_c = jnp.take_along_axis(cost, j[:, None], axis=1)
-                tile_t = tid[j][:, None]
-            else:
-                neg, j = lax.top_k(-cost, rt)
-                tile_c = -neg
-                tile_t = tid[j]
-            merged_c = jnp.concatenate([rev_c0, tile_c], axis=1)
-            merged_t = jnp.concatenate([rev_t0, tile_t], axis=1)
-            neg_c, m = lax.top_k(-merged_c, r)
+            with jax.named_scope("gen.forward"):
+                provider, cost_k, cost = _forward_tile_select(
+                    ep_rep, er_local, weights, t0, tile, k,
+                    None, offset, approx_recall,
+                )
+            with jax.named_scope("gen.reverse"):
+                tid = offset.astype(jnp.int32) + t0 + jnp.arange(tile, dtype=jnp.int32)
+                if rt == 1:
+                    j = jnp.argmin(cost, axis=1)
+                    tile_c = jnp.take_along_axis(cost, j[:, None], axis=1)
+                    tile_t = tid[j][:, None]
+                else:
+                    neg, j = lax.top_k(-cost, rt)
+                    tile_c = -neg
+                    tile_t = tid[j]
+                merged_c = jnp.concatenate([rev_c0, tile_c], axis=1)
+                merged_t = jnp.concatenate([rev_t0, tile_t], axis=1)
+                neg_c, m = lax.top_k(-merged_c, r)
             ys = (provider, cost_k)
             if with_pools:
                 ys = ys + (tile_t, tile_c)
@@ -676,6 +679,7 @@ def _build_repair_enter(
 
     weights = CostWeights(*weights_tuple)
 
+    @jax.named_scope("repair.enter_scan")
     def enter_scan(ep_dirty, p_ids, p_valid, er, thresh):
         def step(_, t0):
             r_tile = _slice_requirements(er, t0, tile)
@@ -717,6 +721,7 @@ def _build_repair_forward(
 
     weights = CostWeights(*weights_tuple)
 
+    @jax.named_scope("repair.forward_rows")
     def forward_rows(ep, er_rows, t_ids, col_dirty):
         cost, _m = cost_matrix(ep, er_rows, weights)  # [Pn, c_pad]
         jit_grid = tie_jitter_ids(jnp.arange(Pn, dtype=jnp.uint32), t_ids)
@@ -755,6 +760,7 @@ def _build_repair_enter_sharded(
         er_treedef, [P(axis)] * er_treedef.num_leaves
     )
 
+    @jax.named_scope("repair.enter_scan")
     def enter_scan_sharded(ep_dirty, p_ids, p_valid, er_local, thresh_local):
         shard = lax.axis_index(axis)
         offset = (shard * Tl).astype(jnp.uint32)
@@ -809,6 +815,7 @@ def _build_repair_tile(
 
     weights = CostWeights(*weights_tuple)
 
+    @jax.named_scope("repair.tile_contrib")
     def tile_contrib(ep_rows, p_ids, er_tile, t0):
         cost, _m = cost_matrix(ep_rows, er_tile, weights)  # [s_pad, tile]
         jit_grid = tie_jitter_ids(
@@ -848,6 +855,7 @@ def _build_repair_refold(
 
     ntl = n_tiles // d_fold
 
+    @jax.named_scope("repair.refold")
     def refold(pool_t, pool_c):
         # [P, n_tiles*rt] tile order -> [ntl, D, P, rt] scan layout
         pt = jnp.moveaxis(
@@ -994,137 +1002,147 @@ def repair_topk_bidir_sharded(
         and (T // mesh.shape[axis]) % tile == 0
     )
 
+    # four host-sequenced stages: each a span closed at the read-back
+    # that already ends it, its wall beside it in the stats
+    took: dict = {}
+
     # ---- forward scope
-    rows = np.zeros(T, bool)
-    rows[dirty_t] = True
-    enter_count = 0
-    if dirty_p.size:
-        rows |= np.isin(fwd_p, dirty_p).any(axis=1)
-        dp_pad = _padq("enter", dirty_p.size)
-        ep_dirty = _gather_rows(ep, dirty_p, dp_pad)
-        p_ids = np.zeros(dp_pad, np.uint32)
-        p_ids[: dirty_p.size] = dirty_p
-        p_valid = np.zeros(dp_pad, bool)
-        p_valid[: dirty_p.size] = True
-        if use_mesh:
-            D = mesh.shape[axis]
-            run = _build_repair_enter_sharded(
-                mesh, axis, wtuple, T // D, tile, dp_pad,
-                ep_treedef, er_treedef,
+    with _tracer.stage("repair.enter_scan", took, "rep_enter_ms"):
+        rows = np.zeros(T, bool)
+        rows[dirty_t] = True
+        enter_count = 0
+        if dirty_p.size:
+            rows |= np.isin(fwd_p, dirty_p).any(axis=1)
+            dp_pad = _padq("enter", dirty_p.size)
+            ep_dirty = _gather_rows(ep, dirty_p, dp_pad)
+            p_ids = np.zeros(dp_pad, np.uint32)
+            p_ids[: dirty_p.size] = dirty_p
+            p_valid = np.zeros(dp_pad, bool)
+            p_valid[: dirty_p.size] = True
+            if use_mesh:
+                D = mesh.shape[axis]
+                run = _build_repair_enter_sharded(
+                    mesh, axis, wtuple, T // D, tile, dp_pad,
+                    ep_treedef, er_treedef,
+                )
+                er_dev = jax.tree.map(
+                    lambda a: jax.device_put(
+                        a, NamedSharding(mesh, P(axis))
+                    ), er,
+                )
+                thresh = jax.device_put(
+                    jnp.asarray(fwd_c[:, -1]), NamedSharding(mesh, P(axis))
+                )
+            else:
+                run = _build_repair_enter(
+                    wtuple, tile, n_tiles, dp_pad, ep_treedef, er_treedef,
+                )
+                er_dev = jax.tree.map(jnp.asarray, er)
+                thresh = jnp.asarray(fwd_c[:, -1])
+            enter = np.asarray(
+                run(
+                    ep_dirty, jnp.asarray(p_ids), jnp.asarray(p_valid),
+                    er_dev, thresh,
+                )
             )
-            er_dev = jax.tree.map(
-                lambda a: jax.device_put(
-                    a, NamedSharding(mesh, P(axis))
-                ), er,
-            )
-            thresh = jax.device_put(
-                jnp.asarray(fwd_c[:, -1]), NamedSharding(mesh, P(axis))
-            )
-        else:
-            run = _build_repair_enter(
-                wtuple, tile, n_tiles, dp_pad, ep_treedef, er_treedef,
-            )
-            er_dev = jax.tree.map(jnp.asarray, er)
-            thresh = jnp.asarray(fwd_c[:, -1])
-        enter = np.asarray(
-            run(
-                ep_dirty, jnp.asarray(p_ids), jnp.asarray(p_valid),
-                er_dev, thresh,
-            )
-        )
-        enter_count = int(enter.sum())
-        rows |= enter
-    R = np.flatnonzero(rows)
+            enter_count = int(enter.sum())
+            rows |= enter
+        R = np.flatnonzero(rows)
 
     # ---- forward recompute (chunked at the generation tile's memory
     # envelope) + per-(provider, tile) dirty-cost minima for the
     # reverse block enter-mask
-    fwd_p_new, fwd_c_new = fwd_p, fwd_c
-    min_dirty_tile = np.full((Pn, n_tiles), _PAD_COST, np.float32)
-    is_dirty_t = np.zeros(T, bool)
-    is_dirty_t[dirty_t] = True
-    if R.size:
-        fwd_p_new = fwd_p.copy()
-        fwd_c_new = fwd_c.copy()
-        ep_full = jax.tree.map(jnp.asarray, ep)
-        chunk_cap = min(1024, tile)
-        for lo in range(0, R.size, chunk_cap):
-            chunk = R[lo: lo + chunk_cap]
-            c_pad = _padq("forward", chunk.size)
-            er_rows = _gather_rows(er, chunk, c_pad)
-            t_ids = np.zeros(c_pad, np.uint32)
-            t_ids[: chunk.size] = chunk
-            col_dirty = np.zeros(c_pad, bool)
-            col_dirty[: chunk.size] = is_dirty_t[chunk]
-            run = _build_repair_forward(
-                wtuple, Pn, kk, c_pad, ep_treedef,
-                jax.tree.structure(er_rows),
-            )
-            prov, cost_k, dc = run(
-                ep_full, er_rows, jnp.asarray(t_ids),
-                jnp.asarray(col_dirty),
-            )
-            fwd_p_new[chunk] = np.asarray(prov)[: chunk.size]
-            fwd_c_new[chunk] = np.asarray(cost_k)[: chunk.size]
-            if col_dirty.any():
-                dc = np.asarray(dc)[:, : chunk.size]
-                tiles_of = chunk // tile
-                for j in np.unique(tiles_of[is_dirty_t[chunk]]):
-                    sel = tiles_of == j
-                    np.minimum(
-                        min_dirty_tile[:, j], dc[:, sel].min(axis=1),
-                        out=min_dirty_tile[:, j],
-                    )
+    with _tracer.stage("repair.forward_rows", took, "rep_forward_ms"):
+        fwd_p_new, fwd_c_new = fwd_p, fwd_c
+        min_dirty_tile = np.full((Pn, n_tiles), _PAD_COST, np.float32)
+        is_dirty_t = np.zeros(T, bool)
+        is_dirty_t[dirty_t] = True
+        if R.size:
+            fwd_p_new = fwd_p.copy()
+            fwd_c_new = fwd_c.copy()
+            ep_full = jax.tree.map(jnp.asarray, ep)
+            chunk_cap = min(1024, tile)
+            for lo in range(0, R.size, chunk_cap):
+                chunk = R[lo: lo + chunk_cap]
+                c_pad = _padq("forward", chunk.size)
+                er_rows = _gather_rows(er, chunk, c_pad)
+                t_ids = np.zeros(c_pad, np.uint32)
+                t_ids[: chunk.size] = chunk
+                col_dirty = np.zeros(c_pad, bool)
+                col_dirty[: chunk.size] = is_dirty_t[chunk]
+                run = _build_repair_forward(
+                    wtuple, Pn, kk, c_pad, ep_treedef,
+                    jax.tree.structure(er_rows),
+                )
+                prov, cost_k, dc = run(
+                    ep_full, er_rows, jnp.asarray(t_ids),
+                    jnp.asarray(col_dirty),
+                )
+                fwd_p_new[chunk] = np.asarray(prov)[: chunk.size]
+                fwd_c_new[chunk] = np.asarray(cost_k)[: chunk.size]
+                if col_dirty.any():
+                    dc = np.asarray(dc)[:, : chunk.size]
+                    tiles_of = chunk // tile
+                    for j in np.unique(tiles_of[is_dirty_t[chunk]]):
+                        sel = tiles_of == j
+                        np.minimum(
+                            min_dirty_tile[:, j], dc[:, sel].min(axis=1),
+                            out=min_dirty_tile[:, j],
+                        )
 
     # ---- reverse scope: flag (provider, tile) contribution blocks
-    flag = np.zeros((Pn, n_tiles), bool)
-    flag[dirty_p, :] = True
-    if dirty_t.size:
-        pt3 = pool_t_np.reshape(Pn, n_tiles, rt)
-        pc3 = pool_c_np.reshape(Pn, n_tiles, rt)
-        flag |= np.isin(pt3, dirty_t).any(axis=2)
-        flag |= min_dirty_tile <= pc3[:, :, -1]
-    blocks = int(flag.sum())
-    if blocks:
-        s_cap = 4096
-        for j in np.flatnonzero(flag.any(axis=0)):
-            er_tile = jax.tree.map(
-                lambda a: jnp.asarray(
-                    np.asarray(a)[j * tile: (j + 1) * tile]
-                ), er,
-            )
-            t0 = jnp.uint32(j * tile)
-            sj = np.flatnonzero(flag[:, j])
-            for lo in range(0, sj.size, s_cap):
-                sc = sj[lo: lo + s_cap]
-                s_pad = _padq("tile", sc.size)
-                ep_rows = _gather_rows(ep, sc, s_pad)
-                p_ids = np.zeros(s_pad, np.uint32)
-                p_ids[: sc.size] = sc
-                run = _build_repair_tile(
-                    wtuple, tile, rt, s_pad,
-                    jax.tree.structure(ep_rows),
-                    jax.tree.structure(er_tile),
+    with _tracer.stage("repair.tile_contrib", took, "rep_tiles_ms"):
+        flag = np.zeros((Pn, n_tiles), bool)
+        flag[dirty_p, :] = True
+        if dirty_t.size:
+            pt3 = pool_t_np.reshape(Pn, n_tiles, rt)
+            pc3 = pool_c_np.reshape(Pn, n_tiles, rt)
+            flag |= np.isin(pt3, dirty_t).any(axis=2)
+            flag |= min_dirty_tile <= pc3[:, :, -1]
+        blocks = int(flag.sum())
+        if blocks:
+            s_cap = 4096
+            for j in np.flatnonzero(flag.any(axis=0)):
+                er_tile = jax.tree.map(
+                    lambda a: jnp.asarray(
+                        np.asarray(a)[j * tile: (j + 1) * tile]
+                    ), er,
                 )
-                tt, tc = run(ep_rows, jnp.asarray(p_ids), er_tile, t0)
-                pool_t_np[sc, j * rt: (j + 1) * rt] = (
-                    np.asarray(tt)[: sc.size]
-                )
-                pool_c_np[sc, j * rt: (j + 1) * rt] = (
-                    np.asarray(tc)[: sc.size]
-                )
+                t0 = jnp.uint32(j * tile)
+                sj = np.flatnonzero(flag[:, j])
+                for lo in range(0, sj.size, s_cap):
+                    sc = sj[lo: lo + s_cap]
+                    s_pad = _padq("tile", sc.size)
+                    ep_rows = _gather_rows(ep, sc, s_pad)
+                    p_ids = np.zeros(s_pad, np.uint32)
+                    p_ids[: sc.size] = sc
+                    run = _build_repair_tile(
+                        wtuple, tile, rt, s_pad,
+                        jax.tree.structure(ep_rows),
+                        jax.tree.structure(er_tile),
+                    )
+                    tt, tc = run(ep_rows, jnp.asarray(p_ids), er_tile, t0)
+                    pool_t_np[sc, j * rt: (j + 1) * rt] = (
+                        np.asarray(tt)[: sc.size]
+                    )
+                    pool_c_np[sc, j * rt: (j + 1) * rt] = (
+                        np.asarray(tc)[: sc.size]
+                    )
 
     # ---- fold replay + auction-visible merge (exact, deterministic:
     # bit-identical parts in => bit-identical merged lists out)
-    d_fold = mesh.shape[axis] if use_mesh else 1
-    refold = _build_repair_refold(Pn, n_tiles, rt, r, d_fold)
-    rev_t, rev_c = refold(
-        jnp.asarray(pool_t_np), jnp.asarray(pool_c_np)
-    )
-    cand_p, cand_c = merge_reverse_candidates(
-        jnp.asarray(fwd_p_new), jnp.asarray(fwd_c_new),
-        rev_t, rev_c, extra=extra,
-    )
+    with _tracer.stage("repair.merge", took, "rep_merge_ms"):
+        d_fold = mesh.shape[axis] if use_mesh else 1
+        refold = _build_repair_refold(Pn, n_tiles, rt, r, d_fold)
+        rev_t, rev_c = refold(
+            jnp.asarray(pool_t_np), jnp.asarray(pool_c_np)
+        )
+        cand_p, cand_c = merge_reverse_candidates(
+            jnp.asarray(fwd_p_new), jnp.asarray(fwd_c_new),
+            rev_t, rev_c, extra=extra, scope="repair.merge",
+        )
+        cand_p = np.asarray(cand_p, np.int32)
+        cand_c = np.asarray(cand_c, np.float32)
     visited = R.size * Pn + blocks * tile + dirty_p.size * T
     stats = {
         "repair_rows": int(R.size),
@@ -1133,10 +1151,11 @@ def repair_topk_bidir_sharded(
         "repair_enter_rows": enter_count,
         "visited_cells_frac": round(visited / max(Pn * T, 1), 6),
         "pad_hw": pad_hw,
+        **took,
     }
     return (
-        np.asarray(cand_p, np.int32),
-        np.asarray(cand_c, np.float32),
+        cand_p,
+        cand_c,
         fwd_p_new,
         fwd_c_new,
         pool_t_np,

@@ -472,7 +472,7 @@ class SchedulerBackendServicer:
             try:
                 self.sessions.shard_of(sid).evict(sid, reason="migrate")
                 with session.lock:
-                    flushed = self.ckpt.flush_locked(session)
+                    flushed = self._flush_locked(session)
                 if not flushed or not self.ckpt.handoff(
                     sid, target_proc_id
                 ):
@@ -791,6 +791,20 @@ class SchedulerBackendServicer:
                 m["trace_id"] = root["trace"]
                 m["spans"] = span_dicts_compact(sp)
         return m
+
+    def _flush_locked(self, session) -> bool:
+        """Checkpoint ``session`` (caller holds ``session.lock``) and
+        record what the flush cost on the seam — phases ``ckpt_flush``,
+        ``ckpt_export``, ``ckpt_deflate`` and the journal's bytes on
+        disk — so Health carries them with no field of its own."""
+        if not self.ckpt.flush_locked(session):
+            return False
+        took = self.ckpt.last_flush
+        self.seam.observe_ms("ckpt_flush", took["flush_ms"])
+        self.seam.observe_ms("ckpt_export", took["export_ms"])
+        self.seam.observe_ms("ckpt_deflate", took["deflate_ms"])
+        self.seam.add_bytes("ckpt", took["bytes_out"])
+        return True
 
     def _observe_tick(
         self,
@@ -1164,7 +1178,7 @@ class SchedulerBackendServicer:
                         gap_ceiling=_stream_gap_ceiling(),
                     )
                 if self.ckpt is not None:
-                    self.ckpt.flush_locked(session)
+                    self._flush_locked(session)
         # post-flush fence re-check (same freeze-window argument as the
         # delta path): an open that raced an ejection must not be acked
         # — the client re-opens at the new home instead of holding a
@@ -1346,10 +1360,25 @@ class SchedulerBackendServicer:
         # point seam tuning at the wrong phase (lock/budget wait + delta
         # apply land in "solve" instead, where the contention actually is)
         t_dec = time.perf_counter()
+        # "engine.solve" is everything between decode and the reply's
+        # bookkeeping: the wait for the session lock, apply_delta, the
+        # arena's solve, the flight recorder's outcome frame, the
+        # checkpoint flush and the fence re-check. Its children name
+        # the parts; its SELF time is the bookkeeping nobody named.
         with _tracer.span(
             "engine.solve", kernel=session.kernel,
             delta_rows=int(prow.size + trow.size),
-        ), session.lock:
+        ) as solve_span, session.lock:
+            t_held = time.perf_counter()
+            self.seam.observe_ms("lock_wait", (t_held - t_dec) * 1e3)
+            if solve_span is not None:
+                # stamped after the fact (the lock is taken by the
+                # `with` the lock analyses read), so the one span of
+                # the tree that never reaches the profiler's host plane
+                _tracer.record_span(
+                    "session.lock_wait", solve_span["t0_ns"],
+                    int(t_held * 1e9) - solve_span["t0_ns"],
+                )
             if session.evicted:
                 # lost the race with LRU/TTL eviction (or a same-id
                 # re-open) between the store lookup and this lock: refuse
@@ -1441,22 +1470,27 @@ class SchedulerBackendServicer:
                     "assigned": int((p4t_out >= 0).sum()),
                 }
             else:
+                t_apply = time.perf_counter()
                 try:
-                    session.apply_delta(
-                        prow, p_delta, trow, r_delta,
-                        events=(
-                            [{
-                                "kind": request.event_kind or "event",
-                                "source": request.event_source,
-                                "seq": int(request.event_seq),
-                            }]
-                            if is_event else None
-                        ),
-                    )
+                    with _tracer.span("session.apply_delta"):
+                        session.apply_delta(
+                            prow, p_delta, trow, r_delta,
+                            events=(
+                                [{
+                                    "kind": request.event_kind or "event",
+                                    "source": request.event_source,
+                                    "seq": int(request.event_seq),
+                                }]
+                                if is_event else None
+                            ),
+                        )
                 except ValueError as e:
                     context.abort(
                         grpc.StatusCode.INVALID_ARGUMENT, str(e)
                     )
+                self.seam.observe_ms(
+                    "apply", (time.perf_counter() - t_apply) * 1e3
+                )
             if is_event and not ev_deduped:
                 from protocol_tpu.stream.events import StreamEvent
 
@@ -1588,7 +1622,7 @@ class SchedulerBackendServicer:
                 # any instant leaves the cursor at-or-one-behind the
                 # client's — either the restart resumes at the next
                 # tick, or the client's retransmit hits the dedup path.
-                self.ckpt.flush_locked(session)
+                self._flush_locked(session)
             # fence re-check AFTER the flush attempt, immediately
             # before the ack: a SIGSTOP can freeze this thread at ANY
             # instruction and the ejection (fence bump + journal
@@ -1614,12 +1648,13 @@ class SchedulerBackendServicer:
         self.seam.observe_ms(
             "solve", (time.perf_counter() - t_dec) * 1e3
         )
-        self._observe_tick(
-            session.session_id, t0, session.n_tasks,
-            int((p4t_out >= 0).sum()), arena_stats,
-            delta_rows=int(prow.size + trow.size),
-            trace_tick=tick_no,
-        )
+        with _tracer.span("obs.observe_tick"):
+            self._observe_tick(
+                session.session_id, t0, session.n_tasks,
+                int((p4t_out >= 0).sum()), arena_stats,
+                delta_rows=int(prow.size + trow.size),
+                trace_tick=tick_no,
+            )
         if is_event and obs_pkg.enabled():
             # per-event stream metrics ride NEXT TO the tick roll-up:
             # event latency (µs-scale HDR), dedup/reconcile counters,
@@ -1641,17 +1676,18 @@ class SchedulerBackendServicer:
         # (the client scatters), and prices/retirement are session state —
         # shipping them back every tick would spend O(P) wire bytes on
         # data the delta protocol exists to keep off the wire
-        resp = pb.AssignDeltaResponse(
-            session_ok=True,
-            stale=bool(staleness),
-            staleness_ticks=staleness,
-            result=pb.AssignResponseV2(
-                provider_for_task=blob(p4t_out, np.int32),
-                num_assigned=int((p4t_out >= 0).sum()),
-                solve_ms=(time.perf_counter() - t0) * 1e3,
-                decode_ms=(t_dec - t0) * 1e3,
-            ),
-        )
+        with _tracer.span("wire.encode", wire="v2-session"):
+            resp = pb.AssignDeltaResponse(
+                session_ok=True,
+                stale=bool(staleness),
+                staleness_ticks=staleness,
+                result=pb.AssignResponseV2(
+                    provider_for_task=blob(p4t_out, np.int32),
+                    num_assigned=int((p4t_out >= 0).sum()),
+                    solve_ms=(time.perf_counter() - t0) * 1e3,
+                    decode_ms=(t_dec - t0) * 1e3,
+                ),
+            )
         if is_event:
             resp.event_deduped = ev_deduped
             resp.reconciled = ev_reconciled
@@ -1669,7 +1705,7 @@ class SchedulerBackendServicer:
         if self.ckpt is not None:
             for session in self.sessions.snapshot_sessions():
                 with session.lock:
-                    if not session.evicted and self.ckpt.flush_locked(
+                    if not session.evicted and self._flush_locked(
                         session
                     ):
                         flushed += 1

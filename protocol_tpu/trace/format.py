@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import struct
+import time
 import zlib
 from typing import Iterator, Optional
 
@@ -300,6 +301,12 @@ class TraceWriter:
         _check_tables()
         self.path = path
         self.compresslevel = compresslevel
+        # what this writer has cost so far (a checkpoint's writer lives
+        # for one flush): payload bytes before DEFLATE, bytes in the
+        # file, and the time inside zlib.compress
+        self.bytes_raw = 0
+        self.bytes_out = len(MAGIC)
+        self.deflate_ms = 0.0
         self._fh = open(path, "wb")
         self._fh.write(MAGIC)
         m = {"version": VERSION}
@@ -308,7 +315,10 @@ class TraceWriter:
 
     def _frame(self, kind: int, payload: bytes) -> None:
         flags = 0
+        self.bytes_raw += len(payload)
+        t0 = time.perf_counter()
         z = zlib.compress(payload, self.compresslevel)
+        self.deflate_ms += (time.perf_counter() - t0) * 1e3
         if len(z) < len(payload):
             payload, flags = z, _FLAG_DEFLATE
         self._fh.write(
@@ -316,6 +326,7 @@ class TraceWriter:
         )
         self._fh.write(payload)
         self._fh.flush()
+        self.bytes_out += _HEADER.size + len(payload)
 
     def write_snapshot(
         self, trace_id: str, fingerprint: str, request: pb.AssignRequestV2

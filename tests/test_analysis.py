@@ -355,20 +355,21 @@ class TestRealModuleMutations:
         deadline = '            self._check_deadline(context, "delta")\n'
         apply_block = (
             "                try:\n"
-            "                    session.apply_delta(\n"
-            "                        prow, p_delta, trow, r_delta,\n"
-            "                        events=(\n"
-            "                            [{\n"
-            '                                "kind": '
+            '                    with _tracer.span("session.apply_delta"):\n'
+            "                        session.apply_delta(\n"
+            "                            prow, p_delta, trow, r_delta,\n"
+            "                            events=(\n"
+            "                                [{\n"
+            '                                    "kind": '
             'request.event_kind or "event",\n'
-            '                                "source": '
+            '                                    "source": '
             "request.event_source,\n"
-            '                                "seq": '
+            '                                    "seq": '
             "int(request.event_seq),\n"
-            "                            }]\n"
-            "                            if is_event else None\n"
-            "                        ),\n"
-            "                    )\n"
+            "                                }]\n"
+            "                                if is_event else None\n"
+            "                            ),\n"
+            "                        )\n"
             "                except ValueError as e:\n"
             "                    context.abort(\n"
             "                        grpc.StatusCode.INVALID_ARGUMENT, "

@@ -452,3 +452,53 @@ class TestObsToggle:
             obs.set_enabled(True)
         # observability must observe, never perturb
         np.testing.assert_array_equal(p_on, p_off)
+
+    def _jax_chain(self, monkeypatch, rows=256):
+        """Cold solve and two warm ticks of a jax arena: the plans and
+        the stats of the last tick."""
+        import dataclasses
+
+        monkeypatch.setenv("PROTOCOL_TPU_JIT_WITNESS", "1")
+        from protocol_tpu.ops.cost import CostWeights
+        from protocol_tpu.parallel.jax_arena import JaxSolveArena
+        from tests.test_sparse import encode_random_marketplace
+
+        ep, er = encode_random_marketplace(5, rows, rows)
+        arena = JaxSolveArena(devices=1)
+        plans = [arena.solve(ep, er, CostWeights())]
+        rng = np.random.default_rng(5)
+        price = np.array(ep.price, copy=True)
+        for _ in range(2):
+            price[rng.choice(rows, 3, replace=False)] += 0.25
+            ep = dataclasses.replace(ep, price=price.copy())
+            plans.append(arena.solve(ep, er, CostWeights()))
+        return plans, arena.last_stats
+
+    def test_jax_arena_plans_identical_and_no_warm_compile(
+        self, monkeypatch
+    ):
+        """The span tree, its counters and the device scope names
+        (ISSUE 26) observe and never perturb: the same plans with the
+        plane off, and nothing built on the second warm tick."""
+        pytest.importorskip("jax")
+        from protocol_tpu.obs.spans import TRACER
+
+        on, stats_on = self._jax_chain(monkeypatch)
+        assert stats_on["cold"] is False
+        assert stats_on["eng_segments"] >= 1
+        assert stats_on["jit_compiles_delta"] == {}
+        assert obs.enabled()
+        try:
+            obs.set_enabled(False)
+            mark = TRACER.mark()
+            off, stats_off = self._jax_chain(monkeypatch)
+            assert TRACER.since(mark) == []
+        finally:
+            obs.set_enabled(True)
+        assert not any(k.startswith("eng_") for k in stats_off)
+        # the stage counters ride with the plane on or off, like gen_ms
+        for key in ("dirty_ms", "diff_ms", "rep_enter_ms", "rep_forward_ms",
+                    "rep_tiles_ms", "rep_merge_ms", "gen_ms", "solve_ms"):
+            assert key in stats_off, key
+        for a, b in zip(on, off):
+            np.testing.assert_array_equal(a, b)
