@@ -34,6 +34,18 @@ warning: the session's client falls back down the ladder exactly as it
 would have without checkpoints — recovery is an optimization, never a
 new failure mode.
 
+Overlap (ISSUE 27): almost none of a warm tick's checkpoint depends on
+its solve. When a jax arena says its candidate structure is final for
+the tick (``JaxSolveArena.structure_hook``, armed by :meth:`arm_locked`
+for a tick that is ``due``), a worker thread builds the SNAPSHOT frame
+and DEFLATEs the ARENA payload's manifest and solve-independent buffers
+while the device runs the auction; the flush joins it, feeds what the
+solve wrote, and writes. The journal is byte for byte what the
+sequential path writes, the worker never touches a file, and the flush
+takes the prefix only if it was built for this tick from the very
+objects the session and arena hold now — anything else (no prefix, a
+stale one, a raised one) writes as before, counted.
+
 Cadence: ``every=1`` (the default, and what the chaos gate runs)
 checkpoints every tick — the zero-reopen guarantee. ``every=N`` trades
 durability for throughput: a crash loses up to N-1 ticks and the
@@ -73,6 +85,8 @@ import hashlib
 import json
 import logging
 import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from typing import Optional
 
@@ -164,6 +178,122 @@ def _frame_span(writer, kind: str):
             )
 
 
+def _snapshot_request(p_cols: dict, r_cols: dict, kernel: str, top_k: int):
+    """The session's padded columns as the wire's own message (what a
+    SNAPSHOT frame holds)."""
+    from protocol_tpu.proto import scheduler_pb2 as pb
+    from protocol_tpu.proto import wire
+
+    return pb.AssignRequestV2(
+        providers=wire.encode_providers_v2(tfmt._as_ns(p_cols)),
+        requirements=wire.encode_requirements_v2(tfmt._as_ns(r_cols)),
+        kernel=kernel,
+        top_k=top_k,
+    )
+
+
+def _pop_arena_meta(state: dict) -> dict:
+    """Take the arena's scalars out of ``state`` (they ride the JSON
+    meta, not the array pack) and return META's ``arena`` entry."""
+    return {
+        "warm_solves": state.pop("warm_solves"),
+        "dual_age": state.pop("dual_age"),
+        "weights_key": list(state.pop("weights_key")),
+        # float-pipeline provenance (string scalar); restore_state cold
+        # re-grounds on a mismatched-ISA load
+        "native_isa": state.pop("native_isa", "scalar"),
+    }
+
+
+def _same(a, b) -> bool:
+    """Arrays by identity, anything else (None, a scalar) by value."""
+    if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
+        return a is b
+    return a == b
+
+
+def _holds(now: dict, then: dict) -> bool:
+    """Does ``now`` map the names of ``then`` to the same objects?"""
+    return now.keys() == then.keys() and all(
+        now[n] is a for n, a in then.items()
+    )
+
+
+class _PrefixJob:
+    """The part of one tick's checkpoint that does not wait for its
+    solve: the SNAPSHOT frame whole, and the ARENA payload's manifest
+    and every buffer but the ``last`` ones fed into one DEFLATE stream
+    (the tail's dtypes and shapes, which the manifest needs, are read
+    off the arrays the tick before left). Made under the session lock
+    from the arena's live state, run on the checkpointer's worker; it
+    keeps the objects it read so the flush can tell whether they are
+    still the session's."""
+
+    def __init__(self, session, tick: int, live: dict, last: tuple,
+                 parent: str):
+        self.session_id = session.session_id
+        self.tick = tick
+        self.fingerprint = session.fingerprint
+        self.kernel = session.kernel
+        self.top_k = session.top_k
+        self.p_cols = dict(session.p_cols)
+        self.r_cols = dict(session.r_cols)
+        self.live = dict(live)
+        _pop_arena_meta(self.live)
+        self.last = last
+        self.parent = parent
+        self.snapshot = tfmt.FrameDeflater()
+        self.arena = tfmt.FrameDeflater()
+        self.head = b""
+        self.overlap_ms = 0.0
+        self.dropped = False
+        self.future = None
+
+    def run(self) -> None:
+        with _tracer.span(
+            "ckpt.prefix", remote_parent=self.parent or None,
+            tick=self.tick,
+        ) as span:
+            self.snapshot.feed(tfmt.snapshot_payload(
+                self.session_id, self.fingerprint,
+                _snapshot_request(
+                    self.p_cols, self.r_cols, self.kernel, self.top_k
+                ),
+            ))
+            self.snapshot.finish()
+            self.head, arrays = tfmt.pack_plan(self.live, self.last)
+            self.arena.feed(self.head)
+            for name, a in arrays:
+                if self.dropped:
+                    return
+                if name not in self.last:
+                    self.arena.feed(tfmt.raw_bytes(a))
+            self.overlap_ms = round(
+                self.snapshot.take_ms() + self.arena.take_ms(), 3
+            )
+            if span is not None:
+                span["attrs"].update(
+                    bytes_raw=self.snapshot.bytes_raw + self.arena.bytes_raw,
+                    deflate_ms=self.overlap_ms,
+                )
+
+    def fits_locked(self, session, live: dict, head: bytes) -> bool:
+        """Was this prefix built for the tick ``session`` is at, from
+        what it holds now: the same column and structure objects, and a
+        manifest equal to ``head``, the one its exported state packs
+        to?"""
+        return (
+            self.tick == int(session.tick)
+            and head == self.head
+            and _holds(session.p_cols, self.p_cols)
+            and _holds(session.r_cols, self.r_cols)
+            and all(
+                n in self.last or _same(live.get(n), v)
+                for n, v in self.live.items()
+            )
+        )
+
+
 class SessionCheckpointer:
     """Per-session checkpoint writer/loader over ``<root>/<proc_id>/``
     (one namespace per servicer process; see the module docstring)."""
@@ -191,8 +321,18 @@ class SessionCheckpointer:
         self.journals_skipped = 0
         # what the last successful flush cost (flush_ms, export_ms,
         # deflate_ms, bytes_raw, bytes_out = the journal's size on
-        # disk); the servicer, which owns the seam, records it
+        # disk; prefix = hit / miss / stale / error, join_ms = its wait
+        # for the worker, overlap_ms = the zlib time a hit took off the
+        # flush); the servicer, which owns the seam, records it
         self.last_flush: dict = {}
+        # prefix jobs: at most one a session, all on one worker thread
+        # (started with the first job). No lock: every access is one
+        # dict operation, and a session's jobs are made and taken under
+        # that session's lock
+        self._jobs: dict = {}
+        self._pool = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="ckpt-prefix"
+        )
 
     def path_for(self, session_id: str) -> str:
         return os.path.join(self.directory, _fname(session_id))
@@ -233,6 +373,83 @@ class SessionCheckpointer:
         still restore the session."""
         return tick == 0 or tick % self.every == 0
 
+    # ---------------- prefix (overlap with the solve) ----------------
+
+    def arm_locked(self, session) -> None:
+        """Before a tick's solve (caller holds ``session.lock``): if
+        the tick about to be acknowledged is ``due`` and the arena can
+        say when its structure is final, have it start that tick's
+        prefix job then. An arena without the hook (native) is left
+        alone, a tick off the cadence gets none; the flush disarms."""
+        arena = session.arena
+        if not hasattr(arena, "structure_hook"):
+            return
+        tick = int(session.tick) + 1
+        if not self.due(tick):
+            arena.structure_hook = None
+            return
+
+        def start(live: dict) -> None:
+            # called inside the arena's solve: whatever goes wrong here
+            # costs the overlap, never the tick
+            try:
+                job = _PrefixJob(
+                    session, tick, live, tuple(arena.SOLVE_STATE),
+                    _tracer.header(),
+                )
+                self.forget(job.session_id)
+                self._jobs[job.session_id] = job
+                job.future = self._pool.submit(job.run)
+            except Exception:
+                log.warning(
+                    "checkpoint prefix not started for %s",
+                    session.session_id, exc_info=True,
+                )
+
+        arena.structure_hook = start
+
+    def forget(self, session_id: str) -> None:
+        """Drop the session's prefix job, if any (the session was let
+        go, or a newer job takes its place): it stops at its next
+        buffer and nobody reads it."""
+        job = self._jobs.pop(session_id, None)
+        if job is not None:
+            job.dropped = True
+            if job.future is not None:
+                job.future.cancel()
+
+    def _join_prefix_locked(self, session, state, last: tuple, took: dict):
+        """The session's prefix job if this flush can use it, else
+        None. ``took["prefix"]`` says which: ``hit``; ``miss`` (no job,
+        or it had not begun to run: sessions share the one worker);
+        ``stale`` (built for another tick, or from objects the session
+        no longer holds); ``error`` (it raised). ``took["join_ms"]`` is
+        the wait for a running job."""
+        took.update(prefix="miss", join_ms=0.0, overlap_ms=0.0)
+        if hasattr(session.arena, "structure_hook"):
+            session.arena.structure_hook = None
+        job = self._jobs.pop(session.session_id, None)
+        if job is None or state is None or job.future.cancel():
+            return None
+        t0 = time.perf_counter()
+        try:
+            job.future.result()
+        except Exception:
+            took["prefix"] = "error"
+            log.warning(
+                "checkpoint prefix failed for %s; written without it",
+                session.session_id, exc_info=True,
+            )
+            return None
+        finally:
+            took["join_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+        head, _arrays = tfmt.pack_plan(state, last)
+        if not job.fits_locked(session, session.arena.live_state(), head):
+            took["prefix"] = "stale"
+            return None
+        took.update(prefix="hit", overlap_ms=job.overlap_ms)
+        return job
+
     # ---------------- write ----------------
 
     def flush_locked(self, session) -> bool:
@@ -256,6 +473,8 @@ class SessionCheckpointer:
                     span["attrs"].update(
                         bytes_raw=took["bytes_raw"],
                         bytes_out=took["bytes_out"],
+                        prefix=took["prefix"],
+                        join_ms=took["join_ms"],
                     )
             self.flushes += 1
             self.last_flush = took
@@ -269,9 +488,6 @@ class SessionCheckpointer:
             return False
 
     def _write_locked(self, session, took: dict) -> None:
-        from protocol_tpu.proto import scheduler_pb2 as pb
-        from protocol_tpu.proto import wire
-
         with _tracer.stage("ckpt.export", took, "export_ms"):
             state = session.arena.export_state()
         meta = {
@@ -304,36 +520,31 @@ class SessionCheckpointer:
             # rebased exactly from the restored arena at re-arm.
             meta["stream"] = session.stream.export_state()
         if state is not None:
-            meta["arena"] = {
-                "warm_solves": state.pop("warm_solves"),
-                "dual_age": state.pop("dual_age"),
-                "weights_key": list(state.pop("weights_key")),
-                # float-pipeline provenance (string scalar — rides the
-                # JSON meta, not the array pack); restore_state cold
-                # re-grounds on a mismatched-ISA load
-                "native_isa": state.pop("native_isa", "scalar"),
-            }
+            meta["arena"] = _pop_arena_meta(state)
+        # what the arena's solve writes lies last in the ARENA payload,
+        # prefix or none: one layout, so one journal for one state
+        last = tuple(getattr(session.arena, "SOLVE_STATE", ()))
+        job = self._join_prefix_locked(session, state, last, took)
+        request = snapshot = arena = None
+        if job is not None:
+            snapshot, arena = job.snapshot, job.arena
         final = self.path_for(session.session_id)
         tmp = final + ".tmp"
         writer = tfmt.TraceWriter(tmp, meta=meta)
         try:
             with _frame_span(writer, "snapshot"):
+                if job is None:
+                    request = _snapshot_request(
+                        session.p_cols, session.r_cols, session.kernel,
+                        session.top_k,
+                    )
                 writer.write_snapshot(
-                    session.session_id, session.fingerprint,
-                    pb.AssignRequestV2(
-                        providers=wire.encode_providers_v2(
-                            tfmt._as_ns(session.p_cols)
-                        ),
-                        requirements=wire.encode_requirements_v2(
-                            tfmt._as_ns(session.r_cols)
-                        ),
-                        kernel=session.kernel,
-                        top_k=session.top_k,
-                    ),
+                    session.session_id, session.fingerprint, request,
+                    deflated=snapshot,
                 )
             if state is not None:
                 with _frame_span(writer, "arena"):
-                    writer.write_arena(state)
+                    writer.write_arena(state, last, deflated=arena)
             if session.last_p4t is not None:
                 with _frame_span(writer, "outcome"):
                     writer.write_outcome(

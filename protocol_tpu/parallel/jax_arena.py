@@ -159,6 +159,10 @@ class JaxSolveArena:
         self._mesh = None
         self._devices_effective: Optional[int] = None
         self.last_stats: dict = {}
+        # set by a session's checkpointer for one tick: called once,
+        # with :meth:`live_state`, when a warm tick's candidate
+        # structure is final and before its solve starts
+        self.structure_hook = None
         self._jit_mark = _jitwitness.snapshot()
         self.invalidate()
 
@@ -208,35 +212,31 @@ class JaxSolveArena:
 
     # ---------------- export / restore (checkpoint + migration) ------
 
-    def export_state(self) -> Optional[dict]:
-        """The carried warm state as a flat dict of scalars and arrays —
-        the same key classes as the native arena's export (cand_* +
-        duals + matching + cadence cursors + the arena's OWN baseline
-        columns), so ``faults/checkpoint.py`` journals and migration
-        handoffs carry it unchanged. Returns None before any solve.
-        Arrays are copies — a checkpoint must not alias live state."""
-        if self._cand_p is None:
-            return None
+    # the exported arrays a solve writes; every other entry of
+    # :meth:`export_state` is final for the tick once its
+    # ``arena.candidates`` span has closed
+    SOLVE_STATE = ("price", "retired", "p4t", "starve_age")
 
-        def _c(a):
-            return None if a is None else np.array(a, copy=True)
-
+    def live_state(self) -> dict:
+        """:meth:`export_state`'s entries as the LIVE objects, no
+        copies (identity is what tells a checkpoint prefix that the
+        structure it compressed is still the arena's)."""
         out = {
-            "cand_p": _c(self._cand_p),
-            "cand_c": _c(self._cand_c),
+            "cand_p": self._cand_p,
+            "cand_c": self._cand_c,
             # generation parts: what the warm-path repair kernels patch.
             # None under approx_recall (no repair twin — see _gen).
-            "fwd_p": _c(self._fwd_p),
-            "fwd_c": _c(self._fwd_c),
-            "pool_t": _c(self._pool_t),
-            "pool_c": _c(self._pool_c),
+            "fwd_p": self._fwd_p,
+            "fwd_c": self._fwd_c,
+            "pool_t": self._pool_t,
+            "pool_c": self._pool_c,
             # the pool width n_tiles*ceil(r/n_tiles) does not encode r
             # (rt saturates at 1), so the config rides along explicitly
             "reverse_r": int(self.reverse_r),
-            "price": _c(self._price),
-            "retired": _c(self._retired),
-            "p4t": _c(self._p4t),
-            "starve_age": _c(self._starve_age),
+            "price": self._price,
+            "retired": self._retired,
+            "p4t": self._p4t,
+            "starve_age": self._starve_age,
             "warm_solves": int(self._warm_solves),
             "dual_age": int(self._dual_age),
             "weights_key": tuple(self._weights_key),
@@ -246,10 +246,24 @@ class JaxSolveArena:
             "native_isa": jax_isa(),
         }
         for name, _ in _P_SPEC:
-            out[f"pf_{name}"] = _c(self._p_fields[name])
+            out[f"pf_{name}"] = self._p_fields[name]
         for name, _ in _R_SPEC:
-            out[f"rf_{name}"] = _c(self._r_fields[name])
+            out[f"rf_{name}"] = self._r_fields[name]
         return out
+
+    def export_state(self) -> Optional[dict]:
+        """The carried warm state as a flat dict of scalars and arrays —
+        the same key classes as the native arena's export (cand_* +
+        duals + matching + cadence cursors + the arena's OWN baseline
+        columns), so ``faults/checkpoint.py`` journals and migration
+        handoffs carry it unchanged. Returns None before any solve.
+        Arrays are copies — a checkpoint must not alias live state."""
+        if self._cand_p is None:
+            return None
+        return {
+            name: np.array(v, copy=True) if isinstance(v, np.ndarray) else v
+            for name, v in self.live_state().items()
+        }
 
     def restore_state(self, ep, er, state: dict) -> None:
         """Rehydrate the warm chain from :meth:`export_state` output.
@@ -504,8 +518,10 @@ class JaxSolveArena:
         columns, without the O(P*T) pass), then :meth:`_adopt`.
         ``approx_recall`` arenas have no parts (no exactness contract
         under approx_max_k) and keep the honest, counted full regen.
-        Returns (changed mask, stats with the stage walls, sharded,
-        cand_cold_passes)."""
+        The structure is final for the tick from here on (the solve
+        writes ``SOLVE_STATE`` only), which is when an armed
+        ``structure_hook`` is called, once. Returns (changed mask,
+        stats with the stage walls, sharded, cand_cold_passes)."""
         with _tracer.span(
             "arena.candidates", cold=False, dirty_providers=dirty_p.size,
             dirty_tasks=dirty_t.size, **attrs,
@@ -521,6 +537,9 @@ class JaxSolveArena:
                 rep = {}
                 cold_passes = 1
             changed = self._adopt(cand_p, cand_c, dirty_t, rep)
+        hook, self.structure_hook = self.structure_hook, None
+        if hook is not None:
+            hook(self.live_state())
         return changed, rep, sharded, cold_passes
 
     def _adopt(self, cand_p, cand_c, dirty_t, out: dict) -> np.ndarray:

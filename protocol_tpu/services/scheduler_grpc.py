@@ -337,6 +337,7 @@ class SchedulerBackendServicer:
             # OTHER involuntary let-go additionally tombstones the
             # session against LAZY rehydration (see _router_lock note).
             def _ckpt_gc(session, reason: str) -> None:
+                self.ckpt.forget(session.session_id)
                 if reason in ("ttl", "drop"):
                     self.ckpt.drop(session.session_id)
                 elif reason not in ("migrate", "replace"):
@@ -795,8 +796,11 @@ class SchedulerBackendServicer:
     def _flush_locked(self, session) -> bool:
         """Checkpoint ``session`` (caller holds ``session.lock``) and
         record what the flush cost on the seam — phases ``ckpt_flush``,
-        ``ckpt_export``, ``ckpt_deflate`` and the journal's bytes on
-        disk — so Health carries them with no field of its own."""
+        ``ckpt_export``, ``ckpt_deflate`` (zlib time inside the flush)
+        and the journal's bytes on disk; a flush that used the tick's
+        prefix job counts ``ckpt_prefix_hit`` and the worker's zlib
+        time as phase ``ckpt_overlap``, any other ``ckpt_prefix_miss``
+        — so Health carries them with no field of its own."""
         if not self.ckpt.flush_locked(session):
             return False
         took = self.ckpt.last_flush
@@ -804,6 +808,11 @@ class SchedulerBackendServicer:
         self.seam.observe_ms("ckpt_export", took["export_ms"])
         self.seam.observe_ms("ckpt_deflate", took["deflate_ms"])
         self.seam.add_bytes("ckpt", took["bytes_out"])
+        if took["prefix"] == "hit":
+            self.seam.observe_ms("ckpt_overlap", took["overlap_ms"])
+            self.seam.count("ckpt_prefix_hit")
+        else:
+            self.seam.count("ckpt_prefix_miss")
         return True
 
     def _observe_tick(
@@ -1491,6 +1500,11 @@ class SchedulerBackendServicer:
                 self.seam.observe_ms(
                     "apply", (time.perf_counter() - t_apply) * 1e3
                 )
+                if self.ckpt is not None:
+                    # the columns are final for this tick: the arena
+                    # may start the checkpoint's solve-independent part
+                    # while the device solves
+                    self.ckpt.arm_locked(session)
             if is_event and not ev_deduped:
                 from protocol_tpu.stream.events import StreamEvent
 

@@ -67,6 +67,7 @@ KIND_ARENA = 6     # named-ndarray pack (pack_arrays): carried solver
 #                    contract
 
 _FLAG_DEFLATE = 1
+COMPRESSLEVEL = 6
 _HEADER = struct.Struct("<BBII")
 
 # Canonical trace-frame column dtypes. These MUST match the wire tables
@@ -128,31 +129,56 @@ def _check_tables() -> None:
 # ---------------- named-ndarray pack (ARENA frames) ----------------
 
 
-def pack_arrays(named: dict[str, Optional[np.ndarray]]) -> bytes:
-    """Deterministic bytes for a dict of (optionally None) ndarrays:
-    a sorted JSON manifest (name -> dtype/shape/offset) followed by the
-    C-order little-endian raw buffers. The checkpoint codec — same
-    byte-exactness contract as the TensorBlob columns, without protobuf
-    in the way (carried solver state is not a wire message)."""
+def pack_plan(
+    named: dict[str, Optional[np.ndarray]], last: tuple = ()
+) -> tuple[bytes, list]:
+    """The two halves of :func:`pack_arrays`: the length-prefixed
+    manifest, and the contiguous arrays as ``(name, array)`` in the
+    order their buffers follow it. The manifest needs dtypes and shapes
+    only, so it can be written before the ``last`` arrays hold their
+    values."""
     manifest: dict = {}
-    buffers: list[bytes] = []
+    arrays: list = []
     off = 0
-    for name in sorted(named):
+    order = sorted(n for n in named if n not in last)
+    order += sorted(n for n in named if n in last)
+    for name in order:
         a = named[name]
         if a is None:
             manifest[name] = None
             continue
         a = np.ascontiguousarray(a)
-        raw = a.tobytes()
         manifest[name] = {
             "dtype": a.dtype.name,
             "shape": list(a.shape),
             "offset": off,
         }
-        buffers.append(raw)
-        off += len(raw)
+        arrays.append((name, a))
+        off += a.nbytes
     head = json.dumps(manifest, sort_keys=True).encode()
-    return struct.pack("<I", len(head)) + head + b"".join(buffers)
+    return struct.pack("<I", len(head)) + head, arrays
+
+
+def raw_bytes(a: np.ndarray) -> np.ndarray:
+    """A contiguous array as the bytes ``tobytes`` would copy, not
+    copied."""
+    return a.reshape(-1).view(np.uint8)
+
+
+def pack_arrays(
+    named: dict[str, Optional[np.ndarray]], last: tuple = ()
+) -> bytes:
+    """Deterministic bytes for a dict of (optionally None) ndarrays:
+    a sorted JSON manifest (name -> dtype/shape/offset) followed by the
+    C-order little-endian raw buffers, sorted by name with the names in
+    ``last`` after all others (a reader goes by ``offset``, so the
+    order of the buffers is the writer's to choose: a checkpoint puts
+    what its solve writes at the end, and can stream the rest out
+    before the solve is done). The checkpoint codec — same
+    byte-exactness contract as the TensorBlob columns, without protobuf
+    in the way (carried solver state is not a wire message)."""
+    head, arrays = pack_plan(named, last)
+    return head + b"".join(a.tobytes() for _, a in arrays)
 
 
 def unpack_arrays(payload: bytes) -> dict[str, Optional[np.ndarray]]:
@@ -291,13 +317,69 @@ def _as_ns(cols: dict[str, np.ndarray]):
 # ---------------- writer ----------------
 
 
+def snapshot_payload(
+    trace_id: str, fingerprint: str, request: pb.AssignRequestV2
+) -> bytes:
+    """A SNAPSHOT frame's payload before DEFLATE."""
+    payload = request.SerializeToString()
+    return pb.SnapshotChunk(
+        session_id=trace_id, epoch_fingerprint=fingerprint,
+        payload=payload, total_bytes=len(payload),
+    ).SerializeToString()
+
+
+class FrameDeflater:
+    """One frame's payload DEFLATEd piece by piece. A zlib stream does
+    not depend on how its input was cut, so feeding the pieces gives
+    byte for byte what ``zlib.compress`` gives for the joined payload:
+    a frame can be started before its last bytes exist, and on another
+    thread (zlib releases the GIL). Pieces are bytes-like and are kept
+    by reference, not copied, until :meth:`finish`."""
+
+    def __init__(self, compresslevel: int = COMPRESSLEVEL):
+        self.compresslevel = compresslevel
+        self._z = zlib.compressobj(compresslevel)
+        self._pieces: list = []
+        self._out: list = []
+        self._done: Optional[tuple] = None
+        self._ms = 0.0
+        self.bytes_raw = 0
+
+    def feed(self, piece) -> None:
+        t0 = time.perf_counter()
+        self._out.append(self._z.compress(piece))
+        self._ms += (time.perf_counter() - t0) * 1e3
+        self._pieces.append(piece)
+        self.bytes_raw += len(piece)
+
+    def finish(self) -> tuple[int, bytes]:
+        """``(flags, body)`` as a frame stores them: the stream where
+        it is shorter than the payload, else the payload itself."""
+        if self._done is None:
+            t0 = time.perf_counter()
+            self._out.append(self._z.flush())
+            self._ms += (time.perf_counter() - t0) * 1e3
+            z = b"".join(self._out)
+            if len(z) < self.bytes_raw:
+                self._done = (_FLAG_DEFLATE, z)
+            else:
+                self._done = (0, b"".join(self._pieces))
+            self._pieces = self._out = []
+        return self._done
+
+    def take_ms(self) -> float:
+        """Time inside zlib since the last call, in ms."""
+        ms, self._ms = self._ms, 0.0
+        return ms
+
+
 class TraceWriter:
     """Append-only frame writer. Every ``write_*`` call lands one fully
     flushed frame, so a SIGKILL can never lose more than the frame being
     written (the reader tolerates that torn tail)."""
 
     def __init__(self, path: str, meta: Optional[dict] = None,
-                 compresslevel: int = 6):
+                 compresslevel: int = COMPRESSLEVEL):
         _check_tables()
         self.path = path
         self.compresslevel = compresslevel
@@ -314,29 +396,48 @@ class TraceWriter:
         self._frame(KIND_META, json.dumps(m, sort_keys=True).encode())
 
     def _frame(self, kind: int, payload: bytes) -> None:
-        flags = 0
-        self.bytes_raw += len(payload)
         t0 = time.perf_counter()
         z = zlib.compress(payload, self.compresslevel)
         self.deflate_ms += (time.perf_counter() - t0) * 1e3
-        if len(z) < len(payload):
-            payload, flags = z, _FLAG_DEFLATE
-        self._fh.write(
-            _HEADER.pack(kind, flags, len(payload), zlib.crc32(payload))
+        flags, body = (
+            (_FLAG_DEFLATE, z) if len(z) < len(payload) else (0, payload)
         )
-        self._fh.write(payload)
+        self._put(kind, flags, body, len(payload))
+
+    def _put(self, kind: int, flags: int, body: bytes, raw_len: int) -> None:
+        self.bytes_raw += raw_len
+        self._fh.write(
+            _HEADER.pack(kind, flags, len(body), zlib.crc32(body))
+        )
+        self._fh.write(body)
         self._fh.flush()
-        self.bytes_out += _HEADER.size + len(payload)
+        self.bytes_out += _HEADER.size + len(body)
+
+    def _frame_deflated(self, kind: int, deflated: "FrameDeflater") -> None:
+        """Land a frame whose payload was fed to ``deflated``: the
+        bytes :meth:`_frame` writes for the joined payload. The zlib
+        time the deflater has spent since its last ``take_ms`` counts
+        as this writer's."""
+        if deflated.compresslevel != self.compresslevel:
+            raise ValueError("frame deflated at another level")
+        flags, body = deflated.finish()
+        self.deflate_ms += deflated.take_ms()
+        self._put(kind, flags, body, deflated.bytes_raw)
 
     def write_snapshot(
-        self, trace_id: str, fingerprint: str, request: pb.AssignRequestV2
+        self, trace_id: str, fingerprint: str,
+        request: Optional[pb.AssignRequestV2],
+        deflated: Optional["FrameDeflater"] = None,
     ) -> None:
-        payload = request.SerializeToString()
-        chunk = pb.SnapshotChunk(
-            session_id=trace_id, epoch_fingerprint=fingerprint,
-            payload=payload, total_bytes=len(payload),
+        """``deflated``: a deflater that was fed
+        :func:`snapshot_payload` of these arguments elsewhere (its
+        stream is what lands; ``request`` is not read)."""
+        if deflated is not None:
+            self._frame_deflated(KIND_SNAPSHOT, deflated)
+            return
+        self._frame(
+            KIND_SNAPSHOT, snapshot_payload(trace_id, fingerprint, request)
         )
-        self._frame(KIND_SNAPSHOT, chunk.SerializeToString())
 
     def write_delta(
         self, delta: pb.AssignDeltaRequest, events: Optional[list] = None
@@ -377,11 +478,23 @@ class TraceWriter:
             ).encode(),
         )
 
-    def write_arena(self, named: dict[str, Optional[np.ndarray]]) -> None:
+    def write_arena(
+        self, named: dict[str, Optional[np.ndarray]], last: tuple = (),
+        deflated: Optional["FrameDeflater"] = None,
+    ) -> None:
         """Carried solver state as one ARENA frame (checkpoint files;
         workload traces never carry one — the replayer skips the
-        kind)."""
-        self._frame(KIND_ARENA, pack_arrays(named))
+        kind). ``last`` as in :func:`pack_arrays`. ``deflated``: a
+        deflater that was fed the manifest of ``pack_plan(named,
+        last)`` and every buffer not in ``last`` elsewhere; the
+        ``last`` buffers are fed here and its stream lands."""
+        if deflated is None:
+            self._frame(KIND_ARENA, pack_arrays(named, last))
+            return
+        for name, a in pack_plan(named, last)[1]:
+            if name in last:
+                deflated.feed(raw_bytes(a))
+        self._frame_deflated(KIND_ARENA, deflated)
 
     def write_outcome(
         self,

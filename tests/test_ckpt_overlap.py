@@ -1,0 +1,353 @@
+"""The checkpoint prefix (ISSUE 27): on a warm jax tick the
+solve-independent part of the journal is DEFLATEd on the checkpointer's
+worker while the solve runs, and the flush writes byte for byte what
+the sequential path writes for the same session state. Whatever the
+flush cannot use (no solve before it, a native arena, a stale job, a
+job that raised) is written by the sequential path, whole and loadable,
+and counted. CPU, 256 rows, through the loopback servicer of
+``test_span_tree.py``."""
+
+import os
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+pytest.importorskip("jax")
+pytest.importorskip("grpc")
+
+from protocol_tpu import native  # noqa: E402
+from protocol_tpu.faults import checkpoint as ckpt_mod  # noqa: E402
+from protocol_tpu.faults.checkpoint import SessionCheckpointer  # noqa: E402
+from protocol_tpu.obs.spans import TRACER  # noqa: E402
+from protocol_tpu.trace import format as tfmt  # noqa: E402
+from tests.test_span_tree import ROWS, _Served, time_limit  # noqa: E402,F401
+
+NATIVE = native.available()
+
+
+def _session(served):
+    session, _why = served.server.servicer.sessions.get(served.sid, served.fp)
+    return session
+
+
+def _journal(ckpt: SessionCheckpointer, sid: str) -> bytes:
+    with open(ckpt.path_for(sid), "rb") as fh:
+        return fh.read()
+
+
+def _sequential(session, directory) -> bytes:
+    """The journal a checkpointer that never saw the tick (so has no
+    prefix job) writes for the session's state as it stands."""
+    ref = SessionCheckpointer(str(directory))
+    with session.lock:
+        assert ref.flush_locked(session)
+    assert ref.last_flush["prefix"] == "miss"
+    return _journal(ref, session.session_id)
+
+
+def _loads(journal: bytes, sid: str, directory):
+    """``load_one`` of ``journal`` from a namespace of its own."""
+    loader = SessionCheckpointer(str(directory))
+    with open(loader.path_for(sid), "wb") as fh:
+        fh.write(journal)
+    loaded = loader.load_one(sid)
+    assert loaded is not None and loader.journals_skipped == 0
+    return loaded
+
+
+def _kinds(journal: bytes, tmp_path) -> list:
+    path = os.path.join(str(tmp_path), "walk.ckpt")
+    with open(path, "wb") as fh:
+        fh.write(journal)
+    return [kind for kind, _ in tfmt.read_frames(path)]
+
+
+WHOLE = [tfmt.KIND_META, tfmt.KIND_SNAPSHOT, tfmt.KIND_ARENA,
+         tfmt.KIND_OUTCOME]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    s = _Served(str(tmp_path_factory.mktemp("ckpt")))
+    try:
+        yield s
+    finally:
+        s.close()
+
+
+def test_every_acks_journal_is_the_sequential_one_and_continues_the_chain(
+    served, tmp_path
+):
+    ckpt = served.server.servicer.ckpt
+    session = _session(served)
+    # the cold open's flush: no warm structure to start from
+    assert ckpt.last_flush["prefix"] == "miss"
+    assert _journal(ckpt, served.sid) == _sequential(
+        session, tmp_path / "ref0"
+    )
+    journals, deltas, plans = [], [], []
+    for n in range(1, 6):
+        mark = TRACER.mark()
+        trace = served.tick()
+        journal = _journal(ckpt, served.sid)
+        assert journal == _sequential(session, tmp_path / f"ref{n}"), n
+        assert _kinds(journal, tmp_path) == WHOLE
+        assert ckpt.last_flush["prefix"] == "hit", n
+        assert ckpt.last_flush["overlap_ms"] > ckpt.last_flush["deflate_ms"]
+        spans = {
+            s["name"]: s for s in TRACER.since(mark, trace=trace)
+        }
+        assert spans["ckpt.flush"]["attrs"]["prefix"] == "hit"
+        assert spans["ckpt.prefix"]["attrs"]["tick"] == n
+        journals.append(journal)
+        deltas.append((
+            served.rows,
+            {k: v[served.rows] for k, v in served.p_cols.items()},
+        ))
+        plans.append(served.plan.copy())
+    assert ckpt.flush_failures == 0
+    seam = served.seam()
+    assert seam["session_ckpt_prefix_hit"] == 5
+    assert seam["session_ckpt_prefix_miss"] == 1
+    assert seam["ckpt_overlap_count"] == 5
+    # the journal of tick 2, loaded, continues the chain bit for bit
+    loaded = _loads(journals[1], served.sid, tmp_path / "load")
+    assert loaded.tick == 2
+    np.testing.assert_array_equal(loaded.last_p4t, plans[1])
+    none = np.zeros(0, np.int32)
+    for n in (3, 4, 5):
+        rows, p_delta = deltas[n - 1]
+        loaded.apply_delta(rows, p_delta, none, {})
+        p4t, _t4p, _price = loaded.solve()
+        np.testing.assert_array_equal(p4t, plans[n - 1])
+    assert loaded.arena.last_stats["cold"] is False
+
+
+def _miss(served, before: dict, tmp_path, why: str) -> None:
+    """The last flush fell back: counted, and the journal is the
+    sequential one, whole and loadable."""
+    servicer = served.server.servicer
+    session = _session(served)
+    assert servicer.ckpt.last_flush["prefix"] == why
+    assert servicer.ckpt.last_flush["overlap_ms"] == 0.0
+    journal = _journal(servicer.ckpt, served.sid)
+    assert journal == _sequential(session, tmp_path / "ref")
+    assert _kinds(journal, tmp_path) == WHOLE
+    loaded = _loads(journal, served.sid, tmp_path / "load")
+    assert loaded.tick == session.tick
+    np.testing.assert_array_equal(loaded.last_p4t, served.plan)
+    after = served.seam()
+    assert after["session_ckpt_prefix_miss"] == (
+        before["session_ckpt_prefix_miss"] + 1
+    )
+    assert after.get("session_ckpt_prefix_hit", 0) == before.get(
+        "session_ckpt_prefix_hit", 0
+    )
+    assert after["ckpt_flush_failures"] == 0
+
+
+class TestFallbacks:
+    def test_a_flush_with_no_solve_before_it(self, served, tmp_path):
+        # eviction, drain and the handoff flush a session as it stands
+        served.tick()
+        before = served.seam()
+        servicer = served.server.servicer
+        session = _session(served)
+        with session.lock:
+            assert servicer._flush_locked(session)
+        _miss(served, before, tmp_path, "miss")
+
+    def test_a_job_whose_arrays_were_replaced(
+        self, served, tmp_path, monkeypatch
+    ):
+        ckpt = served.server.servicer.ckpt
+        arm = ckpt.arm_locked
+
+        def arm_then_replace(session):
+            arm(session)
+            arena, start = session.arena, session.arena.structure_hook
+
+            def hook(live):
+                start(live)
+                # equal values, other objects: the solve is the same,
+                # the prefix no longer the arena's
+                arena._cand_p = arena._cand_p.copy()
+
+            arena.structure_hook = hook
+
+        monkeypatch.setattr(ckpt, "arm_locked", arm_then_replace)
+        before = served.seam()
+        served.tick()
+        _miss(served, before, tmp_path, "stale")
+
+    def test_a_job_that_raises(self, served, tmp_path, monkeypatch):
+        def boom(self):
+            raise RuntimeError("planted in the prefix job")
+
+        monkeypatch.setattr(ckpt_mod._PrefixJob, "run", boom)
+        before = served.seam()
+        served.tick()
+        _miss(served, before, tmp_path, "error")
+
+    def test_the_tick_after_a_fallback_hits_again(self, served):
+        served.tick()
+        assert served.server.servicer.ckpt.last_flush["prefix"] == "hit"
+
+    def test_a_tick_off_the_cadence_starts_no_job(self, served, monkeypatch):
+        ckpt = served.server.servicer.ckpt
+        monkeypatch.setattr(ckpt, "every", 1000)
+        flushes = ckpt.flushes
+        served.tick()
+        assert ckpt.flushes == flushes and not ckpt._jobs
+        assert _session(served).arena.structure_hook is None
+
+    @pytest.mark.skipif(not NATIVE, reason="no native toolchain")
+    def test_a_native_arena_session(self, tmp_path):
+        s = _Served(str(tmp_path / "ckpt"), kernel="native-mt")
+        try:
+            before = s.seam()
+            assert before["session_ckpt_prefix_miss"] == 1  # the open
+            s.tick()
+            assert not hasattr(_session(s).arena, "structure_hook")
+            _miss(s, before, tmp_path, "miss")
+        finally:
+            s.close()
+
+    def test_the_handoff_moves_a_whole_journal(self, tmp_path):
+        s = _Served(str(tmp_path / "ckpt"))
+        try:
+            s.tick()
+            servicer = s.server.servicer
+            before = s.seam()
+            assert servicer.migrate_out("127.0.0.1:1", "p1") == 1
+            assert servicer.ckpt.last_flush["prefix"] == "miss"
+            after = s.seam()
+            assert after["session_ckpt_prefix_miss"] == (
+                before["session_ckpt_prefix_miss"] + 1
+            )
+            assert after["ckpt_flush_failures"] == 0
+            moved = servicer.ckpt.peer_path(s.sid, "p1")
+            with open(moved, "rb") as fh:
+                journal = fh.read()
+            loaded = _loads(journal, s.sid, tmp_path / "load")
+            assert loaded.tick == 1
+            np.testing.assert_array_equal(loaded.last_p4t, s.plan)
+        finally:
+            s.close()
+
+
+def test_a_stream_fed_in_pieces_is_the_stream_of_one_call():
+    import zlib
+
+    rng = np.random.default_rng(27)
+    named = {
+        "cand_p": rng.integers(0, ROWS, (ROWS, 80)).astype(np.int32),
+        "cand_c": rng.random((ROWS, 80)).astype(np.float32),
+        "none": None,
+        "empty": np.zeros((0, 4), np.int32),
+        "price": rng.random(ROWS).astype(np.float32),
+        "retired": rng.random(ROWS) > 0.5,
+    }
+    last = ("price", "retired")
+    whole = tfmt.pack_arrays(named, last)
+    head, arrays = tfmt.pack_plan(named, last)
+    d = tfmt.FrameDeflater()
+    d.feed(head)
+    for _name, a in arrays:
+        d.feed(tfmt.raw_bytes(a))
+    assert d.bytes_raw == len(whole)
+    assert d.finish() == (1, zlib.compress(whole, tfmt.COMPRESSLEVEL))
+    assert d.finish() is d.finish()
+    assert d.take_ms() > 0 and d.take_ms() == 0
+    # a payload DEFLATE cannot shorten is stored as it is, as _frame does
+    noise = rng.bytes(4096)
+    d = tfmt.FrameDeflater()
+    d.feed(noise[:100])
+    d.feed(noise[100:])
+    assert d.finish() == (0, noise)
+
+
+def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):
+    """More sessions than the one worker can serve at once, ticking
+    from threads of their own under a short switch interval: a flush
+    takes its own session's prefix or none (never another's, never
+    half of one), so each journal is the sequential one."""
+    from protocol_tpu.ops.cost import CostWeights
+    from protocol_tpu.proto import wire
+    from protocol_tpu.services.session_store import (
+        SolveSession,
+        make_solve_arena,
+    )
+    from protocol_tpu.trace.synth import synth_providers, synth_requirements
+
+    n_sessions, ticks, rows = 6, 4, 64
+    ckpt = SessionCheckpointer(str(tmp_path / "shared"))
+    refs = SessionCheckpointer(str(tmp_path / "refs"))
+    sessions = []
+    for i in range(n_sessions):
+        rng = np.random.default_rng(100 + i)
+        p_cols = wire.canon_columns(
+            synth_providers(rng, rows), wire.P_WIRE_DTYPES
+        )
+        r_cols = wire.canon_columns(
+            synth_requirements(rng, rows), wire.R_WIRE_DTYPES
+        )
+        session = SolveSession(
+            session_id=f"s{i}@t", fingerprint=f"fp{i}",
+            weights=CostWeights(), kernel="jax", threads=0, top_k=16,
+            p_cols=p_cols, r_cols=r_cols, n_providers=rows, n_tasks=rows,
+            arena=make_solve_arena("jax", k=16, threads=0),
+        )
+        with session.lock:
+            session.last_p4t = session.solve()[0]
+        sessions.append((session, rng))
+    failures, outcomes = [], []
+
+    def drive(session, rng):
+        try:
+            for _ in range(ticks):
+                chosen = np.sort(rng.choice(rows, 2, replace=False))
+                with session.lock:
+                    ckpt.arm_locked(session)
+                    session.apply_delta(
+                        chosen.astype(np.int32),
+                        {
+                            k: (
+                                rng.uniform(0.5, 9.0, 2).astype(v.dtype)
+                                if k == "price" else v[chosen]
+                            )
+                            for k, v in session.p_cols.items()
+                        },
+                        np.zeros(0, np.int32), {},
+                    )
+                    session.last_p4t = session.solve()[0]
+                    session.tick += 1
+                    assert ckpt.flush_locked(session)
+                    outcomes.append(ckpt.last_flush["prefix"])
+                    assert refs.flush_locked(session)
+                    assert _journal(ckpt, session.session_id) == _journal(
+                        refs, session.session_id
+                    )
+        except BaseException as e:  # noqa: BLE001 - reported below
+            failures.append(repr(e))
+            raise
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=drive, args=pair) for pair in sessions
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=200)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not failures, failures
+    assert len(outcomes) == n_sessions * ticks
+    assert set(outcomes) <= {"hit", "miss"} and "hit" in outcomes
+    assert ckpt.flush_failures == 0 and not ckpt._jobs

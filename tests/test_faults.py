@@ -712,6 +712,32 @@ def test_pack_arrays_roundtrip_and_torn_payload_refused():
         tfmt.unpack_arrays(payload[:-3])
     with pytest.raises(ValueError, match="too short"):
         tfmt.unpack_arrays(b"\x01")
+    # layout (ISSUE 27): the buffers named ``last`` (what a solve
+    # writes) lie after all others, the manifest stays sorted by name,
+    # and readers go by offset, so either order unpacks the same
+    import json
+    import struct
+
+    last = ("price", "f")
+    packed = tfmt.pack_arrays(named, last)
+    (n,) = struct.unpack_from("<I", packed)
+    manifest = json.loads(packed[4:4 + n])
+    assert list(manifest) == sorted(named)
+    offsets = {k: m["offset"] for k, m in manifest.items() if m is not None}
+    assert offsets["price"] == max(offsets.values())
+    assert offsets["price"] + named["price"].nbytes == len(packed) - 4 - n
+    assert offsets["cand_p"] < offsets["scalar_shaped"] < offsets["price"]
+    head, arrays = tfmt.pack_plan(named, last)
+    assert [name for name, _ in arrays] == [
+        "cand_p", "scalar_shaped", "price"
+    ]
+    assert packed == head + b"".join(a.tobytes() for _, a in arrays)
+    old_order = tfmt.unpack_arrays(payload)
+    new_order = tfmt.unpack_arrays(packed)
+    assert old_order.keys() == new_order.keys()
+    for name in ("cand_p", "price", "scalar_shaped"):
+        np.testing.assert_array_equal(new_order[name], old_order[name])
+    assert payload != packed and len(payload) == len(packed)
 
 
 @pytest.mark.skipif(not NATIVE, reason="no native toolchain")
