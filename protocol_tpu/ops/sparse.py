@@ -31,6 +31,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from protocol_tpu.obs.spans import TRACER as _tracer
@@ -435,9 +436,15 @@ def _sparse_auction_phase(
     frontier: int = 4096,
     retire: bool = True,
     stall_limit: int = 0,
+    reserve: jax.Array | None = None,
 ):
     """One eps phase of the frontier auction; ``state`` carries
     (it, price, owner, p4t, retired) across phases for warm starts.
+
+    ``reserve`` (a scalar) is the reverse pass's floor, where the
+    bidders are providers (see :func:`_forward_reverse`): a bidder whose
+    best value is not eps above it gives up, and no bid takes the
+    bidder's own implied price (its value less the increment) below it.
 
     ``stall_limit`` > 0 additionally ends the phase after that many
     consecutive rounds with NO NET assignment progress. Per-task
@@ -456,6 +463,8 @@ def _sparse_auction_phase(
     cand_safe = jnp.where(cand_valid, cand_provider, 0)
     finite_max = jnp.max(jnp.where(cand_valid, cand_cost, 0.0))
     give_up = -(2.0 * finite_max + 10.0) if retire else _NEG
+    if reserve is not None:
+        give_up = reserve + eps
 
     def cond(loop):
         (it, price, owner, p4t, retired), best, stall = loop
@@ -477,7 +486,12 @@ def _sparse_auction_phase(
 
             newly_retired = f_ok & (v1 < give_up)
             bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
-            bid_amt = price[p1] + (v1 - v2) + eps  # [B]
+            if reserve is None:
+                bid_amt = price[p1] + (v1 - v2) + eps  # [B]
+            else:
+                bid_amt = price[p1] + jnp.minimum(
+                    (v1 - v2) + eps, v1 - reserve
+                )
             tgt = jnp.where(bidding, p1, P)
 
         with jax.named_scope("auction.resolve"):
@@ -554,6 +568,208 @@ def _unassign_unhappy(cand_provider, cand_cost, price, owner, p4t, eps_next):
     return owner, p4t
 
 
+# The reverse pass runs in a pool whose slack (:func:`_stranded`) is at
+# least one in ``_SLACK_SHARE`` of the providers its tasks list. Only
+# such a pool has the asymmetric problem's condition to meet. Where the
+# free providers are matched by open tasks, or outnumber them by a
+# handful (a full pool with its unseatable tail and a few tasks nobody
+# can serve), the forward auction's give-up level decides, and a reverse
+# chain would wander the whole pool, since every provider a steal
+# vacates finds a taker: measured on the chip at 8,192 x 8,192, 4.7 s a
+# tick for seats worth 0.004 a task (PERF.md section 6, PR 29).
+_SLACK_SHARE = 64
+
+# takers a provider's reverse bid looks at: the first ``_REVERSE_WIDTH``
+# tasks that list it (the mean is T*K/P, 48-80 in the served pools; a
+# provider listed by more does not see the rest, and a seat that ends
+# up preferring it is re-opened by the next solve's eps-CS repair)
+_REVERSE_WIDTH = 256
+
+
+@partial(jax.jit, static_argnames=("num_providers", "width"))
+@jax.named_scope("auction.reverse")
+def _transpose_candidates(cand_provider, cand_cost, num_providers: int, width: int):
+    """The candidate graph from the providers' side: for each provider
+    the tasks that list it, in task order, as ``rev_task``/``rev_cost``
+    [P, width] with -1 in empty slots — the shape
+    :func:`_sparse_auction_phase` takes when providers bid. One stable
+    single-key sort (what the TPU compiler builds fastest: a second key
+    doubles its 20 s), then counts and gathers."""
+    T, K = cand_cost.shape
+    P = num_providers
+    prov = jnp.where(cand_provider >= 0, cand_provider, P).ravel()
+    order = jnp.argsort(prov, stable=True).astype(jnp.int32)
+    count = jnp.zeros(P + 1, jnp.int32).at[prov].add(1)[:P]
+    start = jnp.cumsum(count) - count
+    slot = jnp.arange(width, dtype=jnp.int32)[None, :]
+    ok = slot < count[:, None]
+    flat = order[jnp.minimum(start[:, None] + slot, T * K - 1)]
+    return (
+        jnp.where(ok, flat // K, -1),
+        jnp.where(ok, cand_cost.ravel()[flat], INFEASIBLE),
+    )
+
+
+@jax.jit
+@jax.named_scope("auction.reverse")
+def _stranded(cand_provider, price, owner, p4t):
+    """(floor, stranded mask, [stranded, slack, listed providers]).
+
+    Stranded: the providers a converged forward phase left free at a
+    price above the floor, the lowest price any provider carries (what
+    one nobody ever bid for still has); prices within float dust of the
+    floor do not count. Slack: the providers left free that some task
+    lists, less TWICE the tasks left open that list any; positive where
+    most of the free providers are free for want of tasks and not
+    because their tasks were priced out. :func:`_forward_reverse` holds
+    it against the listed providers (``_SLACK_SHARE``)."""
+    P = price.shape[0]
+    listed = cand_provider >= 0
+    reach = jnp.zeros(P + 1, jnp.int32).at[
+        jnp.where(listed, cand_provider, P).ravel()
+    ].add(1)[:P] > 0
+    floor = jnp.min(price)
+    tol = 1e-5 * (1.0 + jnp.max(jnp.abs(price)))
+    free = owner < 0
+    stranded = free & (price > floor + tol)
+    slack = jnp.sum(free & reach, dtype=jnp.int32) - 2 * jnp.sum(
+        (p4t < 0) & jnp.any(listed, axis=1), dtype=jnp.int32
+    )
+    return floor, stranded, jnp.stack([
+        jnp.sum(stranded, dtype=jnp.int32), slack,
+        jnp.sum(reach, dtype=jnp.int32),
+    ])
+
+
+@jax.jit
+@jax.named_scope("auction.reverse")
+def _reverse_seed(cand_provider, cand_cost, price, owner, p4t):
+    """State of the reverse phase, in :func:`_sparse_auction_phase`'s
+    layout with the roles swapped (providers bid, tasks are bid for):
+    a task's "price" is its profit, the value of its seat (of its best
+    candidate when it has none), so a provider's value for a task,
+    ``-cost - profit``, is the price at which that task would just take
+    it. Free providers at the floor do not bid (no seated task prefers
+    one by more than eps, or the forward phase would not have ended)."""
+    cand_valid = cand_provider >= 0
+    cand_safe = jnp.where(cand_valid, cand_provider, 0)
+    value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)
+    seat = jnp.max(
+        jnp.where(cand_safe == jnp.maximum(p4t, 0)[:, None], value, _NEG), axis=1
+    )
+    profit = jnp.where(p4t >= 0, seat, jnp.max(value, axis=1))
+    floor, stranded, _ = _stranded(cand_provider, price, owner, p4t)
+    return (jnp.int32(0), profit, p4t, owner, (owner < 0) & ~stranded), floor
+
+
+@jax.jit
+@jax.named_scope("auction.reverse")
+def _reverse_finish(cand_provider, cand_cost, price, profit0, rstate, floor):
+    """Back to the forward layout: a provider that took a task is priced
+    at what its bid left that task indifferent to (``-cost - profit``);
+    a provider the pass leaves free goes to the floor; nobody else's
+    price moves. Then every price comes down by the floor where that is
+    positive (a uniform shift changes no value difference), so that the
+    free providers of a pool with slack sit at 0, the lowest feasible
+    dual, and the quality certificate's ``idle_price`` counts only what
+    is stranded. Returns (price, owner, p4t, providers lowered)."""
+    _, profit, p4t, owner, _ = rstate
+    P = price.shape[0]
+    cand_safe = jnp.where(cand_provider >= 0, cand_provider, 0)
+    seat_cost = jnp.max(
+        jnp.where(
+            (cand_provider >= 0) & (cand_safe == jnp.maximum(p4t, 0)[:, None]),
+            cand_cost, _NEG,
+        ), axis=1,
+    )
+    # a task that was bid for, even by the provider it had, holds its
+    # seat at a new profit
+    won = (p4t >= 0) & (profit != profit0)
+    out = price.at[jnp.where(won, p4t, P)].set(
+        jnp.maximum(-seat_cost - profit, floor), mode="drop"
+    )
+    tol = 1e-5 * (1.0 + jnp.max(jnp.abs(price)))
+    out = jnp.where((owner < 0) & (out > floor + tol), floor, out)
+    lowered = jnp.sum(out < price, dtype=jnp.int32)
+    return out - jnp.maximum(floor, 0.0), owner, p4t, lowered
+
+
+def _forward_reverse(
+    run_phase, cand_provider, cand_cost, num_providers: int, state, eps,
+    stats_out: dict | None, transposed: list,
+):
+    """One eps phase to the condition a pool with free providers needs.
+
+    With fewer tasks than providers a forward auction is eps-optimal
+    only if no provider it leaves free is priced above a seated one
+    (Bertsekas and Castanon 1992, forward/reverse auction for
+    asymmetric assignment). A provider that was bid up and then
+    abandoned, by a coarser rung's eviction or by churn ticks ago,
+    keeps its raised price and attracts nobody, however cheap it is.
+    So after the forward phase ``run_phase(state) -> (state, stall)``
+    the stranded providers bid for tasks: the SAME phase kernel on the
+    transposed candidate graph, each lowering its price to where its
+    second-best taker would just take it (never below the floor) and
+    taking the best one, whose old provider bids in turn; one that no
+    task would take above the floor goes to the floor and stays free.
+    No task loses its seat, so the forward phase need not run again.
+
+    One read of three scalars says whether any provider is stranded and
+    whether the pool has slack enough (:func:`_stranded`,
+    ``_SLACK_SHARE``); unless both, nothing else runs and the prices
+    come back bit for bit. ``transposed``
+    caches the transposed graph for the solve. Each step of the pass is
+    an ``auction.reverse`` span. ``stats_out`` gains ``free_repriced``
+    (providers the pass lowered), ``reverse_rounds`` and ``reverse_ms``.
+    Returns (state, stall, rounds of the forward phase)."""
+    state, stall = run_phase(state)
+    it, price, owner, p4t, retired = state
+    rounds = int(it) if stats_out is not None else 0
+    t0 = time.perf_counter()
+    with _tracer.span("auction.reverse", eps=eps, step="check") as sp:
+        n_stranded, slack, listed = (
+            int(n) for n in np.asarray(
+                _stranded(cand_provider, price, owner, p4t)[2]
+            )
+        )
+        if sp is not None:
+            sp["attrs"].update(stranded=n_stranded, slack=slack)
+    lowered = reverse_rounds = 0
+    if n_stranded > 0 and slack > 0 and slack * _SLACK_SHARE >= listed:
+        with _tracer.span("auction.reverse", step="seed", dispatch_only=True):
+            if not transposed:
+                transposed.extend(_transpose_candidates(
+                    cand_provider, cand_cost, num_providers, _REVERSE_WIDTH
+                ))
+            rstate, floor = _reverse_seed(
+                cand_provider, cand_cost, price, owner, p4t
+            )
+        profit0 = rstate[1]
+        fit = 512
+        while fit < n_stranded:
+            fit *= 2
+        rstate, _ = _phase_adaptive(
+            transposed[0], transposed[1], p4t.shape[0], rstate,
+            eps=eps, max_iters=20000, frontier=fit, retire=True,
+            stall_limit=0, reserve=floor, span="auction.reverse",
+        )
+        reverse_rounds = int(rstate[0])
+        with _tracer.span("auction.reverse", step="finish"):
+            price, owner, p4t, n_lowered = _reverse_finish(
+                cand_provider, cand_cost, price, profit0, rstate, floor
+            )
+            lowered = int(n_lowered)
+        state = (it, price, owner, p4t, retired)
+    if stats_out is not None:
+        for key, value in (
+            ("free_repriced", lowered),
+            ("reverse_rounds", reverse_rounds),
+            ("reverse_ms", (time.perf_counter() - t0) * 1e3),
+        ):
+            stats_out[key] = round(stats_out.get(key, 0) + value, 3)
+    return state, stall, rounds
+
+
 @partial(jax.jit, static_argnames=("budget",))
 @jax.named_scope("auction.greedy_cleanup")
 def _greedy_cleanup_compacted(cand_provider, cand_cost, owner, p4t, budget: int):
@@ -570,7 +786,9 @@ def _greedy_cleanup_compacted(cand_provider, cand_cost, owner, p4t, budget: int)
     cand_valid = cand_provider >= 0
     cand_safe = jnp.where(cand_valid, cand_provider, 0)
 
-    open_idx = jnp.flatnonzero(p4t < 0, size=budget, fill_value=T).astype(jnp.int32)
+    open_idx = jnp.flatnonzero(
+        (p4t < 0) & jnp.any(cand_valid, axis=1), size=budget, fill_value=T
+    ).astype(jnp.int32)
     ok = open_idx < T
     safe_idx = jnp.where(ok, open_idx, 0)
 
@@ -593,8 +811,10 @@ def _greedy_cleanup_compacted(cand_provider, cand_cost, owner, p4t, budget: int)
 
 def _greedy_cleanup(cand_provider, cand_cost, owner, p4t):
     """Host wrapper: one scalar readback decides whether cleanup is needed;
-    the compaction budget is a pow-2 bucket of the open count."""
-    n_open = int(jnp.sum(p4t < 0))
+    the compaction budget is a pow-2 bucket of the open count (of tasks
+    that list a provider: a session's padded rows never do, and a pool
+    with fewer tasks than its row bucket would sweep them every tick)."""
+    n_open = int(jnp.sum((p4t < 0) & jnp.any(cand_provider >= 0, axis=1)))
     if n_open == 0:
         return p4t
     budget = 1024
@@ -664,6 +884,7 @@ def assign_auction_sparse_scaled(
     state = None
     eps = eps_start
     rounds_total = 0
+    transposed: list = []
     # frontier_ladder: adaptive per-phase frontier shrink (see
     # _phase_adaptive) — disable to pin the exact Jacobi schedule (the
     # sharded-parity tests compare against the fixed-frontier mesh kernel)
@@ -673,20 +894,22 @@ def assign_auction_sparse_scaled(
     )
     while True:
         final = eps <= eps_end
-        state, stall = phase_fn(
-            cand_provider, cand_cost, num_providers, state,
-            eps=eps, max_iters=max_iters_per_phase, frontier=frontier,
-            # the FINAL phase's retirement is binding and its eviction
-            # chains (closing eps_end-sized price gaps) legitimately make
-            # no net progress for long stretches — give it 8x the
-            # circuit-breaker budget of the disposable coarse phases
-            retire=True,
-            stall_limit=stall_limit * (8 if final else 1),
+        state, stall, rounds = _forward_reverse(
+            partial(
+                phase_fn, cand_provider, cand_cost, num_providers,
+                eps=eps, max_iters=max_iters_per_phase, frontier=frontier,
+                # the FINAL phase's retirement is binding and its eviction
+                # chains (closing eps_end-sized price gaps) legitimately
+                # make no net progress for long stretches — give it 8x the
+                # circuit-breaker budget of the disposable coarse phases
+                retire=True,
+                stall_limit=stall_limit * (8 if final else 1),
+            ),
+            cand_provider, cand_cost, num_providers, state, eps, stats_out,
+            transposed,
         )
-        if stats_out is not None:
-            # per-phase round count; readback only when asked for — the
-            # fixed-frontier path otherwise keeps async phase dispatch
-            rounds_total += int(state[0])
+        # per-phase round count (read back only when asked for)
+        rounds_total += rounds
         if final:
             _report_stall("scaled", stall, stall_limit * 8, stats_out)
             if stats_out is not None:
@@ -730,6 +953,8 @@ def _phase_adaptive(
     retire: bool,
     stall_limit: int,
     stats_out: dict | None = None,
+    reserve=None,
+    span: str = "auction.segment",
 ):
     """One eps phase run in SEGMENTS with a shrinking frontier executable.
 
@@ -753,10 +978,12 @@ def _phase_adaptive(
     segment granularity (up to seg_rounds-1 extra rounds past
     ``max_iters``, a budget-cap semantic, not a correctness one).
 
-    Each segment is one ``auction.segment`` span (the finest grain the
-    solve is traced at: nothing per round). ``stats_out`` gains
-    ``segments`` and ``wait_ms``, the time the host spent inside the
-    segment's blocking scalar reads — its view of the device's time.
+    Each segment is one ``span`` span, ``auction.segment`` unless the
+    reverse pass names its own (the finest grain the solve is traced
+    at: nothing per round); ``reserve`` goes to the kernel as it is.
+    ``stats_out`` gains ``segments`` and ``wait_ms``, the time the host
+    spent inside the segment's blocking scalar reads — its view of the
+    device's time.
     """
     seg_rounds = 256
     T = cand_cost.shape[0]
@@ -768,11 +995,13 @@ def _phase_adaptive(
     segments = 0
     wait_s = 0.0
     while iters_left > 0:
-        with _tracer.span("auction.segment", frontier=B) as seg:
+        with _tracer.span(span, frontier=B) as seg:
+            # (the forward phase's ``reserve`` is None: its program is
+            # the one it always was)
             state, stall = _sparse_auction_phase(
                 cand_provider, cand_cost, num_providers, state,
                 eps=eps, max_iters=seg_rounds, frontier=B, retire=retire,
-                stall_limit=0,
+                stall_limit=0, reserve=reserve,
             )
             t_wait = time.perf_counter()
             it = int(state[0])
@@ -812,9 +1041,10 @@ def _phase_adaptive(
     # report the PHASE's total rounds in the state's counter slot (each
     # segment resets it; the ladder's rounds_total sums these) and the
     # ACCUMULATED stall so _report_stall sees breaker trips (the last
-    # segment alone can never reach a limit > seg_rounds)
-    state = (jnp.int32(total_it),) + tuple(state[1:])
-    return state, jnp.int32(carried_stall)
+    # segment alone can never reach a limit > seg_rounds). Host scalars:
+    # the callers' ``int()`` of them costs no trip to the device
+    state = (np.int32(total_it),) + tuple(state[1:])
+    return state, np.int32(carried_stall)
 
 
 def _report_stall(kind: str, stall, limit: int, stats_out: dict | None) -> None:
@@ -928,19 +1158,23 @@ def assign_auction_sparse_warm(
         partial(_phase_adaptive, stats_out=stats_out)
         if frontier_ladder else _sparse_auction_phase
     )
-    state, stall = phase_fn(
-        cand_provider, cand_cost, num_providers, state,
-        eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
-        # the warm solve is a binding final phase: same 8x stall budget as
-        # the scaled ladder's last phase (see assign_auction_sparse_scaled);
-        # stall_limit=0 opts out (run to max_iters)
-        stall_limit=stall_limit * 8,
+    state, stall, rounds = _forward_reverse(
+        partial(
+            phase_fn, cand_provider, cand_cost, num_providers,
+            eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
+            # the warm solve is a binding final phase: same 8x stall budget
+            # as the scaled ladder's last phase (see
+            # assign_auction_sparse_scaled); stall_limit=0 opts out (run to
+            # max_iters)
+            stall_limit=stall_limit * 8,
+        ),
+        cand_provider, cand_cost, num_providers, state, eps, stats_out, [],
     )
     _report_stall("warm", stall, stall_limit * 8, stats_out)
     if stats_out is not None:
         # same cost driver the cold ladder exposes: wall = rounds x
         # per-round kernel cost (see assign_auction_sparse_scaled)
-        stats_out["rounds_total"] = int(state[0])
+        stats_out["rounds_total"] = rounds
     _, price, owner, p4t, retired = state
     with _tracer.span("auction.cleanup"):
         p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)

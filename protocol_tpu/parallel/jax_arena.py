@@ -621,6 +621,19 @@ class JaxSolveArena:
         )
         return self._readback(res, price, retired, eng)
 
+    @staticmethod
+    def _count_free(pf: dict, p4t: np.ndarray, eng: Optional[dict]) -> None:
+        """``eng["free_providers"]``: live providers the plan leaves
+        free, the ones the solve's reverse pass answers for (its
+        ``free_repriced`` / ``reverse_rounds`` / ``reverse_ms`` ride
+        ``eng`` beside it)."""
+        if eng is not None:
+            used = np.zeros(pf["valid"].shape[0], bool)
+            used[p4t[p4t >= 0]] = True
+            eng["free_providers"] = int(
+                (pf["valid"].astype(bool) & ~used).sum()
+            )
+
     def _quality_pass(
         self, rf: dict, p4t, price, prev_p4t, eng: Optional[dict] = None
     ) -> dict:
@@ -667,6 +680,7 @@ class JaxSolveArena:
         with _tracer.span("arena.engine", engine="jax", cold=True):
             p4t, price, retired = self._ladder(P, eng)
         t_solve = time.perf_counter()
+        self._count_free(pf, p4t, eng)
         self._p_fields, self._r_fields = pf, rf
         self._owned_cols = set()
         self._weights_key = self._wkey(weights)
@@ -964,6 +978,7 @@ class JaxSolveArena:
                 )
                 self._dual_age += 1
         t_solve = time.perf_counter()
+        self._count_free(pf, p4t, eng)
         self._price, self._retired, self._p4t = price, retired, p4t
         self._warm_solves += 1
         qual = (

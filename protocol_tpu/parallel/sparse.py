@@ -290,6 +290,7 @@ def assign_auction_sparse_scaled_sharded(
     replicated arrays (O(T*K) elementwise — negligible next to the
     sharded while_loop they bracket)."""
     from protocol_tpu.ops.sparse import (
+        _forward_reverse,
         _greedy_cleanup,
         _report_stall,
         _unassign_unhappy,
@@ -309,15 +310,27 @@ def assign_auction_sparse_scaled_sharded(
     p4t = jnp.full(T, -1, jnp.int32)
     task_feasible = jnp.any(cand_provider >= 0, axis=1)
     eps = eps_start
+    transposed: list = []
     while True:
         final = eps <= eps_end
-        # binding final phase gets 8x the disposable phases' stall budget
-        # (same discipline as the single-device ladder)
-        price, owner, p4t, retired, stall = _run_phase_sharded(
-            mesh, axis, num_providers, B, max_iters_per_phase,
-            cand_p_dev, cand_c_dev, task_feasible, eps,
-            stall_limit * (8 if final else 1), price, owner, p4t,
-            frontier_ladder,
+
+        def run(state, eps=eps, final=final):
+            # binding final phase gets 8x the disposable phases' stall
+            # budget (same discipline as the single-device ladder)
+            price, owner, p4t, retired, stall = _run_phase_sharded(
+                mesh, axis, num_providers, B, max_iters_per_phase,
+                cand_p_dev, cand_c_dev, task_feasible, eps,
+                stall_limit * (8 if final else 1), *state[1:4],
+                frontier_ladder,
+            )
+            return (jnp.int32(0), price, owner, p4t, retired), stall
+
+        # the reverse pass over the providers the phase left free runs
+        # on replicated arrays, like the repair and the cleanup: the
+        # single-device ladder's, call for call
+        (_, price, owner, p4t, retired), stall, _ = _forward_reverse(
+            run, cand_provider, cand_cost, num_providers,
+            (None, price, owner, p4t, None), eps, None, transposed,
         )
         if final:
             _report_stall("scaled-sharded", stall, stall_limit * 8, stats_out)
@@ -365,6 +378,7 @@ def assign_auction_sparse_warm_sharded(
     is dual state). Returns (AssignResult, final prices [P]), plus the
     final retirement mask when ``with_state=True``."""
     from protocol_tpu.ops.sparse import (
+        _forward_reverse,
         _greedy_cleanup,
         _report_stall,
         _unassign_unhappy,
@@ -395,11 +409,19 @@ def assign_auction_sparse_warm_sharded(
     sharding = NamedSharding(mesh, P(axis, None))
     cand_p_dev = jax.device_put(cand_provider, sharding)
     cand_c_dev = jax.device_put(cand_cost, sharding)
-    price, owner, p4t, retired, stall = _run_phase_sharded(
-        mesh, axis, num_providers, min(frontier, T // D), max_iters,
-        cand_p_dev, cand_c_dev, jnp.any(cand_provider >= 0, axis=1), eps,
-        stall_limit * 8, price0, owner0, p4t0, frontier_ladder,
-        retired=retired_seed,
+
+    def run(state):
+        price, owner, p4t, retired, stall = _run_phase_sharded(
+            mesh, axis, num_providers, min(frontier, T // D), max_iters,
+            cand_p_dev, cand_c_dev, task_has_cand, eps,
+            stall_limit * 8, *state[1:4], frontier_ladder,
+            retired=state[4],
+        )
+        return (jnp.int32(0), price, owner, p4t, retired), stall
+
+    (_, price, owner, p4t, retired), stall, _ = _forward_reverse(
+        run, cand_provider, cand_cost, num_providers,
+        (None, price0, owner0, p4t0, retired_seed), eps, None, [],
     )
     _report_stall("warm-sharded", stall, stall_limit * 8, stats_out)
     p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)

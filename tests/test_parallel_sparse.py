@@ -95,8 +95,11 @@ class TestScaledSharded:
             np.asarray(res_sh.provider_for_task),
             np.asarray(res_sg.provider_for_task),
         )
+        # float dust of the mesh kernel's bid sums, as before; absolute
+        # too since the reverse pass brings prices down by the floor
+        # (P > T here), which leaves the dust and shrinks the prices
         np.testing.assert_allclose(
-            np.asarray(price_sh), np.asarray(price_sg), rtol=1e-6
+            np.asarray(price_sh), np.asarray(price_sg), rtol=1e-6, atol=2e-5
         )
 
     def test_warm_jacobi_parity_with_single_device(self):

@@ -28,7 +28,7 @@ ROWS = 256
 TEST_SECONDS = 240
 
 # name -> parent's name, the tree of ISSUE 26 §1 (a warm repair tick),
-# with ISSUE 27's ``ckpt.prefix``
+# with ISSUE 27's ``ckpt.prefix`` and ISSUE 29's ``auction.reverse``
 TREE = {
     "rpc.AssignDelta": None,
     "session.lookup": "rpc.AssignDelta",
@@ -47,6 +47,7 @@ TREE = {
     "arena.engine": "arena.solve",
     "auction.seed": "arena.engine",
     "auction.segment": "arena.engine",
+    "auction.reverse": "arena.engine",
     "auction.cleanup": "arena.engine",
     "arena.readback": "arena.engine",
     "arena.quality": "arena.solve",
@@ -278,6 +279,37 @@ class TestSpanTree:
         assert 0 < stats["eng_wait_ms"] <= stats["solve_ms"]
 
 
+    def test_reverse_spans_count_the_pass(self, served):
+        """Every solve checks for stranded providers and for slack (one
+        ``auction.reverse`` span, step ``check``, closed at its one
+        read); where it finds both, the seed, the reverse segments and
+        the finish are spans of the same name (a full pool, as here,
+        has no slack: ``tests/test_pool_slack.py`` drives the pass), and
+        the counters beside them ride ``last_stats``."""
+        stats = served.stats()
+        rev = [s for s in served.spans if s["name"] == "auction.reverse"]
+        checks = [s for s in rev if s["attrs"].get("step") == "check"]
+        assert len(checks) == 1 and checks[0]["attrs"]["stranded"] >= 0
+        segs = [s for s in rev if "rounds" in s["attrs"]]
+        assert (
+            sum(s["attrs"]["rounds"] for s in segs)
+            == stats["eng_reverse_rounds"]
+        )
+        steps = {s["attrs"].get("step") for s in rev}
+        ran = "seed" in steps
+        assert ("finish" in steps) == ran
+        # no stranded provider, or no slack: nothing but the check
+        if min(checks[0]["attrs"][k] for k in ("stranded", "slack")) <= 0:
+            assert not ran
+        assert (stats["eng_free_repriced"] > 0) <= ran
+        assert 0 < stats["eng_reverse_ms"] <= stats["solve_ms"]
+        assert stats["eng_reverse_ms"] >= sum(
+            s["dur_ns"] for s in rev
+        ) / 1e6 - 0.5
+        plan = served.plan
+        assert stats["eng_free_providers"] == ROWS - int((plan >= 0).sum())
+
+
 class TestCounters:
     def test_last_stats_split_the_stage_walls(self, served):
         stats = served.stats()
@@ -413,6 +445,23 @@ class TestScopeNames:
             cp, cc, owner, p4t, budget=16
         ).as_text(debug_info=True)
         assert "auction.greedy_cleanup" in text
+        rstate = (jnp.int32(0), jnp.zeros(self.T), p4t, owner,
+                  jnp.zeros(self.P, bool))
+        for lowered in (
+            sparse._transpose_candidates.lower(
+                cp, cc, num_providers=self.P, width=16),
+            sparse._stranded.lower(cp, jnp.zeros(self.P), owner, p4t),
+            sparse._reverse_seed.lower(cp, cc, jnp.zeros(self.P), owner, p4t),
+            sparse._reverse_finish.lower(
+                cp, cc, jnp.zeros(self.P), jnp.zeros(self.T), rstate,
+                jnp.float32(0.0)),
+        ):
+            assert "auction.reverse" in lowered.as_text(debug_info=True)
+        # the names reverse_roofline finds the pass's own programs by
+        assert sparse._transpose_candidates.__name__ == "_transpose_candidates"
+        assert sparse._reverse_seed.__name__ == "_reverse_seed"
+        assert sparse._reverse_finish.__name__ == "_reverse_finish"
+        assert sparse._stranded.__name__ == "_stranded"
         # the names the accepted solve_roofline finds the solve by
         assert sparse._sparse_auction_phase.__name__ == "_sparse_auction_phase"
         assert sparse._unassign_unhappy.__name__ == "_unassign_unhappy"
@@ -478,18 +527,24 @@ class TestScopeNames:
             assert scope in lowered.as_text(debug_info=True), scope
 
 
-# ---- the thirteen per-layer metrics of ISSUE 26 and ISSUE 27's two, read through the benchmark's own
-# generic reader from canned contexts (data files only: no reader code)
+# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two and ISSUE 29's six that read counters,
+# read through the benchmark's own generic reader from canned contexts (data files only: no reader code)
 
 _ACKS = [
     {"wall_ms": 4000.0, "gen_ms": 500.0, "solve_ms": 3000.0,
      "dirty_ms": 10.0, "diff_ms": 40.0, "rep_enter_ms": 100.0,
      "rep_forward_ms": 200.0, "rep_tiles_ms": 60.0, "rep_merge_ms": 90.0,
-     "eng_segments": 17, "eng_wait_ms": 2900.0},
+     "eng_segments": 17, "eng_wait_ms": 2900.0,
+     "gap_per_task": 0.010, "idle_price": 0.0, "eng_free_providers": 3277,
+     "eng_free_repriced": 100, "eng_reverse_rounds": 40,
+     "eng_reverse_ms": 30.0},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
-     "eng_segments": 18, "eng_wait_ms": 2980.0},
+     "eng_segments": 18, "eng_wait_ms": 2980.0,
+     "gap_per_task": 0.012, "idle_price": 1.0, "eng_free_providers": 3277,
+     "eng_free_repriced": 140, "eng_reverse_rounds": 60,
+     "eng_reverse_ms": 50.0},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
@@ -542,6 +597,30 @@ METRICS = {
     "ckpt_prefix_hits_per_ack": (
         "session, arena bookkeeping and checkpoint", "hits",
         "program_counter", "session_ckpt_prefix_hit", 1.0, "higher"),
+    "certified_gap_per_task": (
+        "quality pass", "cost/task", "program_counter", "gap_per_task",
+        0.011),
+    "idle_price_per_ack": (
+        "quality pass", "cost", "program_counter", "idle_price", 0.5),
+    "free_providers_per_ack": (
+        "auction solve", "providers", "program_counter",
+        "eng_free_providers", 3277.0),
+    "free_repriced_per_ack": (
+        "auction solve", "providers", "program_counter",
+        "eng_free_repriced", 120.0),
+    "reverse_rounds_per_ack": (
+        "auction solve", "rounds", "program_counter", "eng_reverse_rounds",
+        50.0),
+    "reverse_ms_per_ack": (
+        "auction solve", "ms", "program_span", "eng_reverse_ms", 40.0),
+}
+# the cells a metric is declared for, where not ``pool-large.ticks``
+CELLS = {
+    name: ["pool-slack.ticks"] for name in (
+        "certified_gap_per_task", "idle_price_per_ack",
+        "free_providers_per_ack", "free_repriced_per_ack",
+        "reverse_rounds_per_ack", "reverse_ms_per_ack",
+    )
 }
 
 
@@ -569,7 +648,7 @@ def test_a_new_metric_reads_its_counter_through_the_generic_reader(name):
                          ("source", source), ("moves", "ack_p50_ms"),
                          ("better", better)):
         assert spec[field] == entry[field] == value, field
-    assert entry["workloads"] == ["pool-large.ticks"]
+    assert entry["workloads"] == CELLS.get(name, ["pool-large.ticks"])
     assert readers.read_metric(spec, _without("")) == pytest.approx(want)
     # the parent commit has no such counter: nothing is read, nothing
     # is raised, and the line leaves the metric out
