@@ -401,8 +401,12 @@ def assign_auction_sparse(
     number of bid events (~O(T) for marketplace costs), not rounds x T.
     Bertsekas auction is correct for any nonempty subset of unassigned
     bidders per round, so this changes which eps-optimal matching is found
-    (tie outcomes), not feasibility or quality. Set ``frontier >= T`` to
-    recover the dense-parity Jacobi schedule.
+    (tie outcomes), not feasibility or quality. ``frontier`` is the most
+    that bid in one round, not what a round pays for: the kernel runs
+    each round at the narrowest width that holds every open task
+    (``_FRONTIER_RUNGS``), with the same outcome. Set ``frontier >= T``
+    to recover the dense-parity Jacobi schedule (every open task bids
+    every round).
 
     ``retire=True`` stops tasks whose best achievable value has been bid
     below -(2*max_cost + 10): economically "not worth it", and the
@@ -414,12 +418,25 @@ def assign_auction_sparse(
     to the give-up level in eps-sized steps (millions of bid events at 32k);
     eps-scaling covers the same price range geometrically.
     """
-    state, _stall = _sparse_auction_phase(
+    state, _stall, _rows = _sparse_auction_phase(
         cand_provider, cand_cost, num_providers, None,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=retire,
     )
     p4t = state[3]
     return AssignResult(p4t, _invert(p4t, num_providers))
+
+
+# Frontier widths a round can run at, below the phase's own
+# ``min(frontier, T)``. Most rounds of a solve are eviction chains with
+# a few dozen open tasks, and a round's price gather is [width, K]: on a
+# v5e at T = P = 8,192, K = 80 a round takes 0.21 / 0.23 / 0.27 / 0.76 /
+# 2.34 ms at 32 / 64 / 128 / 1,024 / 4,096 rows, 0.18 of it whatever
+# the width, so each round takes the narrowest rung that holds every
+# open task. A warm tick's rounds there: 63% with at most 32 open,
+# 34% with 33-64, 2% with 65-128, under 1% with more. Five branches
+# cost the round no more than two do; a sixth added 0.013 ms to every
+# round (PERF.md section 5).
+_FRONTIER_RUNGS = (32, 64, 128, 1024)
 
 
 @partial(
@@ -440,6 +457,17 @@ def _sparse_auction_phase(
 ):
     """One eps phase of the frontier auction; ``state`` carries
     (it, price, owner, p4t, retired) across phases for warm starts.
+    Returns (state, trailing no-progress rounds, frontier rows).
+
+    Every round counts its open tasks and bids at the narrowest width
+    that holds them: a rung of ``_FRONTIER_RUNGS``, or ``min(frontier,
+    T)`` when more are open than the widest rung below it holds (then
+    the first ``frontier`` of them bid, in index order). A width that
+    holds every open task is invisible in the state: the open tasks are
+    listed in index order, the fill rows are dropped from every scatter,
+    the top-2 reductions are per row, and scatter-max / scatter-min take
+    no notice of order. The third result is the sum of the widths the
+    rounds ran at.
 
     ``reserve`` (a scalar) is the reverse pass's floor, where the
     bidders are providers (see :func:`_forward_reverse`): a bidder whose
@@ -456,6 +484,7 @@ def _sparse_auction_phase(
     T, K = cand_cost.shape
     P = num_providers
     B = min(frontier, T)
+    widths = tuple(w for w in _FRONTIER_RUNGS if w < B) + (B,)
 
     cand_valid = cand_provider >= 0
     value_base = jnp.where(cand_valid, -cand_cost, _NEG)  # [T, K]
@@ -467,27 +496,27 @@ def _sparse_auction_phase(
         give_up = reserve + eps
 
     def cond(loop):
-        (it, price, owner, p4t, retired), best, stall = loop
+        (it, price, owner, p4t, retired), best, stall, rows = loop
         go = (it < max_iters) & jnp.any((p4t < 0) & task_feasible & ~retired)
         if stall_limit > 0:
             go &= stall < stall_limit
         return go
 
-    def body(loop):
-        state, best, stall = loop
-        it, price, owner, p4t, retired = state
-        open_mask = (p4t < 0) & task_feasible & ~retired  # [T]
-
-        # ---- frontier selection: up to B open tasks (fill = T -> dropped)
+    def bid_and_resolve(width, price, retired, open_mask):
+        """The part of a round whose shapes follow the frontier width;
+        what it hands back ([P] winners, [T] retirements) does not."""
+        # ---- frontier selection: up to width open tasks (fill = T -> dropped)
         with jax.named_scope("auction.bid"):
-            f_idx = jnp.flatnonzero(open_mask, size=B, fill_value=T).astype(jnp.int32)
+            f_idx = jnp.flatnonzero(
+                open_mask, size=width, fill_value=T
+            ).astype(jnp.int32)
             f_ok = f_idx < T
             p1, v1, v2 = frontier_bids(cand_safe, value_base, price, f_idx, f_ok, K)
 
             newly_retired = f_ok & (v1 < give_up)
             bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
             if reserve is None:
-                bid_amt = price[p1] + (v1 - v2) + eps  # [B]
+                bid_amt = price[p1] + (v1 - v2) + eps  # [width]
             else:
                 bid_amt = price[p1] + jnp.minimum(
                     (v1 - v2) + eps, v1 - reserve
@@ -503,10 +532,27 @@ def _sparse_auction_phase(
             win_task = jnp.full(P, T, jnp.int32).at[tgt].min(
                 jnp.where(is_winner_bid, f_idx, T), mode="drop"
             )
-            got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
 
         with jax.named_scope("auction.commit"):
-            retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(True, mode="drop")
+            retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(
+                True, mode="drop"
+            )
+        return win_bid, win_task, retired
+
+    def body(loop):
+        state, best, stall, rows = loop
+        it, price, owner, p4t, retired = state
+        open_mask = (p4t < 0) & task_feasible & ~retired  # [T]
+        n_open = jnp.sum(open_mask, dtype=jnp.int32)
+        rung = sum((n_open > w).astype(jnp.int32) for w in widths[:-1])
+        win_bid, win_task, retired = lax.switch(
+            rung, [partial(bid_and_resolve, w) for w in widths],
+            price, retired, open_mask,
+        )
+        rows = rows + jnp.asarray(widths, jnp.int32)[rung]
+
+        with jax.named_scope("auction.commit"):
+            got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
             evict_t = jnp.where(got_bid & (owner >= 0), owner, T)
             p4t = p4t.at[evict_t].set(-1, mode="drop")
             p_idx = jnp.arange(P, dtype=jnp.int32)
@@ -518,7 +564,7 @@ def _sparse_auction_phase(
             improved = n_now > best
             best = jnp.maximum(best, n_now)
             stall = jnp.where(improved, 0, stall + 1)
-        return (it + 1, price, owner, p4t, retired), best, stall
+        return (it + 1, price, owner, p4t, retired), best, stall, rows
 
     if state is None:
         state = (
@@ -531,9 +577,9 @@ def _sparse_auction_phase(
     else:
         # reset the iteration counter for this phase
         state = (jnp.int32(0),) + tuple(state[1:])
-    loop0 = (state, jnp.sum(state[3] >= 0), jnp.int32(0))
-    out, _best, stall = lax.while_loop(cond, body, loop0)
-    return out, stall
+    loop0 = (state, jnp.sum(state[3] >= 0), jnp.int32(0), jnp.int32(0))
+    out, _best, stall, rows = lax.while_loop(cond, body, loop0)
+    return out, stall, rows
 
 
 @jax.jit
@@ -706,8 +752,9 @@ def _forward_reverse(
     asymmetric assignment). A provider that was bid up and then
     abandoned, by a coarser rung's eviction or by churn ticks ago,
     keeps its raised price and attracts nobody, however cheap it is.
-    So after the forward phase ``run_phase(state) -> (state, stall)``
-    the stranded providers bid for tasks: the SAME phase kernel on the
+    So after the forward phase ``run_phase(state) -> (state, stall,
+    frontier rows)`` the stranded providers bid for tasks: the SAME
+    phase kernel on the
     transposed candidate graph, each lowering its price to where its
     second-best taker would just take it (never below the floor) and
     taking the best one, whose old provider bids in turn; one that no
@@ -720,9 +767,11 @@ def _forward_reverse(
     come back bit for bit. ``transposed``
     caches the transposed graph for the solve. Each step of the pass is
     an ``auction.reverse`` span. ``stats_out`` gains ``free_repriced``
-    (providers the pass lowered), ``reverse_rounds`` and ``reverse_ms``.
+    (providers the pass lowered), ``reverse_rounds``, ``reverse_ms`` and
+    ``frontier_rows`` (the widths every round of the phase ran at, both
+    directions, summed).
     Returns (state, stall, rounds of the forward phase)."""
-    state, stall = run_phase(state)
+    state, stall, rows = run_phase(state)
     it, price, owner, p4t, retired = state
     rounds = int(it) if stats_out is not None else 0
     t0 = time.perf_counter()
@@ -734,7 +783,7 @@ def _forward_reverse(
         )
         if sp is not None:
             sp["attrs"].update(stranded=n_stranded, slack=slack)
-    lowered = reverse_rounds = 0
+    lowered = reverse_rounds = reverse_rows = 0
     if n_stranded > 0 and slack > 0 and slack * _SLACK_SHARE >= listed:
         with _tracer.span("auction.reverse", step="seed", dispatch_only=True):
             if not transposed:
@@ -745,12 +794,12 @@ def _forward_reverse(
                 cand_provider, cand_cost, price, owner, p4t
             )
         profit0 = rstate[1]
-        fit = 512
-        while fit < n_stranded:
-            fit *= 2
-        rstate, _ = _phase_adaptive(
+        # every stranded provider may bid at once: the kernel fits each
+        # round's width to the ones still open, and one executable serves
+        # every count of them
+        rstate, _, reverse_rows = _phase_adaptive(
             transposed[0], transposed[1], p4t.shape[0], rstate,
-            eps=eps, max_iters=20000, frontier=fit, retire=True,
+            eps=eps, max_iters=20000, frontier=num_providers, retire=True,
             stall_limit=0, reserve=floor, span="auction.reverse",
         )
         reverse_rounds = int(rstate[0])
@@ -765,6 +814,7 @@ def _forward_reverse(
             ("free_repriced", lowered),
             ("reverse_rounds", reverse_rounds),
             ("reverse_ms", (time.perf_counter() - t0) * 1e3),
+            ("frontier_rows", int(rows) + reverse_rows),
         ):
             stats_out[key] = round(stats_out.get(key, 0) + value, 3)
     return state, stall, rounds
@@ -885,9 +935,10 @@ def assign_auction_sparse_scaled(
     eps = eps_start
     rounds_total = 0
     transposed: list = []
-    # frontier_ladder: adaptive per-phase frontier shrink (see
-    # _phase_adaptive) — disable to pin the exact Jacobi schedule (the
-    # sharded-parity tests compare against the fixed-frontier mesh kernel)
+    # frontier_ladder: the phase in host-driven segments under the stall
+    # breaker (see _phase_adaptive) — disable for one call of the kernel
+    # a phase with its stall limit inside (the sharded-parity tests
+    # compare against the mesh kernel, which runs that way)
     phase_fn = (
         partial(_phase_adaptive, stats_out=stats_out)
         if frontier_ladder else _sparse_auction_phase
@@ -956,49 +1007,48 @@ def _phase_adaptive(
     reserve=None,
     span: str = "auction.segment",
 ):
-    """One eps phase run in SEGMENTS with a shrinking frontier executable.
+    """One eps phase run in SEGMENTS of the phase kernel, the host
+    holding the stall breaker and the budget between them.
 
     Measured (16k, CPU): round count is nearly flat in the frontier size
     (4105 rounds at B=4096 vs 4731 at B=512) because most rounds are tail
-    eviction chains with a SMALL open set — a large static frontier makes
-    every round pay large gathers for parallelism that isn't there. wall
-    7.9 s at B=512 vs 16.9 s at B=4096 on the same instance. Every
-    segment boundary, B DIRECT-FITS to the live open set: the smallest
-    pow2 (floor 512) covering it, monotone non-increasing; segments
-    re-enter the SAME phase kernel with carried state, so auction
-    semantics are unchanged — only the per-round batch shape adapts.
+    eviction chains with a SMALL open set — a wide frontier makes every
+    round pay large gathers for parallelism that isn't there. The width
+    is the kernel's own business (it fits every round to the open set,
+    see :func:`_sparse_auction_phase`); every segment is the SAME
+    executable at ``min(frontier, T)``, re-entered with carried state.
 
     The stall circuit breaker lives at segment granularity out here (a
     per-segment stall_limit static would re-trace the kernel every
-    segment — measured to dwarf the frontier win): the kernel's trailing
-    no-progress count accumulates across whole-segment stalls, so a trip
-    can land up to one segment late — benign, the tail then falls to
-    greedy cleanup exactly as a true stall would. Segments are a FIXED
-    size for the same retrace reason; the phase budget is honored at
-    segment granularity (up to seg_rounds-1 extra rounds past
-    ``max_iters``, a budget-cap semantic, not a correctness one).
+    segment): the kernel's trailing no-progress count accumulates across
+    whole-segment stalls, so a trip can land up to one segment late —
+    benign, the tail then falls to greedy cleanup exactly as a true
+    stall would. Segments are a FIXED size for the same retrace reason;
+    the phase budget is honored at segment granularity (up to
+    seg_rounds-1 extra rounds past ``max_iters``, a budget-cap semantic,
+    not a correctness one).
 
     Each segment is one ``span`` span, ``auction.segment`` unless the
     reverse pass names its own (the finest grain the solve is traced
-    at: nothing per round); ``reserve`` goes to the kernel as it is.
+    at: nothing per round), with the attr ``rows``, the widths its
+    rounds ran at, summed; ``reserve`` goes to the kernel as it is.
     ``stats_out`` gains ``segments`` and ``wait_ms``, the time the host
     spent inside the segment's blocking scalar reads — its view of the
-    device's time.
+    device's time. Returns (state, accumulated stall, the phase's rows).
     """
     seg_rounds = 256
     T = cand_cost.shape[0]
     task_feasible = jnp.any(cand_provider >= 0, axis=1)
     iters_left = max_iters
     total_it = 0
+    total_rows = 0
     B = min(frontier, T)
     carried_stall = 0
     segments = 0
     wait_s = 0.0
     while iters_left > 0:
         with _tracer.span(span, frontier=B) as seg:
-            # (the forward phase's ``reserve`` is None: its program is
-            # the one it always was)
-            state, stall = _sparse_auction_phase(
+            state, stall, rows = _sparse_auction_phase(
                 cand_provider, cand_cost, num_providers, state,
                 eps=eps, max_iters=seg_rounds, frontier=B, retire=retire,
                 stall_limit=0, reserve=reserve,
@@ -1006,14 +1056,16 @@ def _phase_adaptive(
             t_wait = time.perf_counter()
             it = int(state[0])
             s = int(stall)
+            seg_rows = int(rows)
             total_it += it
+            total_rows += seg_rows
             iters_left -= it
             carried_stall = carried_stall + it if s >= it else s
             # the phase goes on only after a full segment under the
             # circuit breaker (checked at segment-boundary granularity)
             # with tasks still open; candidate-less tasks stay open
-            # forever and must not pin the frontier large (the kernel's
-            # own open_mask excludes them too)
+            # forever and do not count (the kernel's own open_mask
+            # excludes them too)
             open_count = 0
             if it == seg_rounds and not (
                 stall_limit > 0 and carried_stall >= stall_limit
@@ -1026,15 +1078,11 @@ def _phase_adaptive(
             wait_s += waited
             if seg is not None:
                 seg["attrs"].update(
-                    rounds=it, open_count=open_count,
+                    rounds=it, rows=seg_rows, open_count=open_count,
                     wait_ms=round(waited * 1e3, 3),
                 )
         if open_count == 0:
             break
-        fit = 512
-        while fit < open_count and fit < B:
-            fit *= 2
-        B = min(B, fit)
     if stats_out is not None:
         stats_out["segments"] = stats_out.get("segments", 0) + segments
         stats_out["wait_ms"] = stats_out.get("wait_ms", 0.0) + wait_s * 1e3
@@ -1044,7 +1092,7 @@ def _phase_adaptive(
     # segment alone can never reach a limit > seg_rounds). Host scalars:
     # the callers' ``int()`` of them costs no trip to the device
     state = (np.int32(total_it),) + tuple(state[1:])
-    return state, np.int32(carried_stall)
+    return state, np.int32(carried_stall), total_rows
 
 
 def _report_stall(kind: str, stall, limit: int, stats_out: dict | None) -> None:

@@ -219,8 +219,10 @@ def _run_phase_sharded(
 ):
     """One sharded eps phase, optionally in fixed-size segments with the
     per-shard frontier executable direct-fit to the live open set — the
-    mesh twin of ops.sparse._phase_adaptive (same measured rationale:
-    most rounds are tail eviction chains with a small open set). The
+    mesh twin of ops.sparse._phase_adaptive's segments (same measured
+    rationale: most rounds are tail eviction chains with a small open
+    set; the single-device kernel fits its width inside the program,
+    round by round, this one on the host between segments). The
     per-B executables come from the lru_cache'd builder, so the ladder
     costs at most a handful of compiles per config. The retirement mask
     threads through segments (and back to the caller) exactly like the
@@ -323,7 +325,8 @@ def assign_auction_sparse_scaled_sharded(
                 stall_limit * (8 if final else 1), *state[1:4],
                 frontier_ladder,
             )
-            return (jnp.int32(0), price, owner, p4t, retired), stall
+            # (the mesh kernel keeps no count of its frontier rows)
+            return (jnp.int32(0), price, owner, p4t, retired), stall, 0
 
         # the reverse pass over the providers the phase left free runs
         # on replicated arrays, like the repair and the cleanup: the
@@ -417,7 +420,7 @@ def assign_auction_sparse_warm_sharded(
             stall_limit * 8, *state[1:4], frontier_ladder,
             retired=state[4],
         )
-        return (jnp.int32(0), price, owner, p4t, retired), stall
+        return (jnp.int32(0), price, owner, p4t, retired), stall, 0
 
     (_, price, owner, p4t, retired), stall, _ = _forward_reverse(
         run, cand_provider, cand_cost, num_providers,

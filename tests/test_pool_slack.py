@@ -69,11 +69,9 @@ def _optimum(p_cols: dict, r_cols: dict):
     return dense, float(dense[rows, picks][seated].sum()), int(seated.sum())
 
 
-def _chain(n_providers: int, n_tasks: int, blocked: int = 0,
-           ticks: int = TICKS):
-    """Cold open and ``ticks`` warm ticks; one dict per solve. With
-    ``blocked``, the first two warm ticks are the harness's warm-up
-    pair instead: that many tasks made unassignable, then put back."""
+def open_session(n_providers: int, n_tasks: int):
+    """(the benchmark's marketplace generator, a jax arena, a session
+    over both): what the servicer holds after ``OpenSession``."""
     gen = population.Pool(
         np.random.default_rng([25001, 0]), n_providers, n_tasks, 0.01, 0.002,
     )
@@ -85,6 +83,15 @@ def _chain(n_providers: int, n_tasks: int, blocked: int = 0,
         r_cols=_pad_cols(copy.deepcopy(gen.r_cols), n_tasks),
         n_providers=n_providers, n_tasks=n_tasks, arena=arena,
     )
+    return gen, arena, session
+
+
+def _chain(n_providers: int, n_tasks: int, blocked: int = 0,
+           ticks: int = TICKS):
+    """Cold open and ``ticks`` warm ticks; one dict per solve. With
+    ``blocked``, the first two warm ticks are the harness's warm-up
+    pair instead: that many tasks made unassignable, then put back."""
+    gen, arena, session = open_session(n_providers, n_tasks)
     none = np.zeros(0, np.int32)
     special = dict(enumerate(gen.block_tasks(blocked), 1)) if blocked else {}
     out = []
@@ -129,7 +136,7 @@ def chains():
 
                 def forward_only(run_phase, cand_p, cand_c, n_providers,
                                  state, eps, stats_out, transposed):
-                    state, stall = run_phase(state)
+                    state, stall, _rows = run_phase(state)
                     return state, stall, int(state[0])
 
                 sparse._forward_reverse = forward_only

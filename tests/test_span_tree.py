@@ -278,6 +278,26 @@ class TestSpanTree:
         )
         assert 0 < stats["eng_wait_ms"] <= stats["solve_ms"]
 
+    def test_segment_rows_count_the_frontier(self, served):
+        """Every segment span says how many frontier rows its rounds
+        ran at, and the solve's sum rides ``last_stats``."""
+        from protocol_tpu.ops.sparse import _FRONTIER_RUNGS
+
+        stats = served.stats()
+        segs = [
+            s for s in served.spans
+            if s["name"] in ("auction.segment", "auction.reverse")
+            and "rounds" in s["attrs"]
+        ]
+        assert any(s["name"] == "auction.segment" for s in segs)
+        for s in segs:
+            a = s["attrs"]
+            low = min(_FRONTIER_RUNGS[0], a["frontier"])
+            assert a["rounds"] * low <= a["rows"] <= a["rounds"] * a["frontier"]
+        assert isinstance(stats["eng_frontier_rows"], int)
+        assert stats["eng_frontier_rows"] == sum(
+            s["attrs"]["rows"] for s in segs
+        ) > 0
 
     def test_reverse_spans_count_the_pass(self, served):
         """Every solve checks for stranded providers and for slack (one
@@ -527,7 +547,7 @@ class TestScopeNames:
             assert scope in lowered.as_text(debug_info=True), scope
 
 
-# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two and ISSUE 29's six that read counters,
+# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six and ISSUE 30's one that read counters,
 # read through the benchmark's own generic reader from canned contexts (data files only: no reader code)
 
 _ACKS = [
@@ -537,14 +557,14 @@ _ACKS = [
      "eng_segments": 17, "eng_wait_ms": 2900.0,
      "gap_per_task": 0.010, "idle_price": 0.0, "eng_free_providers": 3277,
      "eng_free_repriced": 100, "eng_reverse_rounds": 40,
-     "eng_reverse_ms": 30.0},
+     "eng_reverse_ms": 30.0, "eng_frontier_rows": 300000},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
      "eng_segments": 18, "eng_wait_ms": 2980.0,
      "gap_per_task": 0.012, "idle_price": 1.0, "eng_free_providers": 3277,
      "eng_free_repriced": 140, "eng_reverse_rounds": 60,
-     "eng_reverse_ms": 50.0},
+     "eng_reverse_ms": 50.0, "eng_frontier_rows": 340000},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
@@ -613,6 +633,9 @@ METRICS = {
         50.0),
     "reverse_ms_per_ack": (
         "auction solve", "ms", "program_span", "eng_reverse_ms", 40.0),
+    "frontier_rows_per_ack": (
+        "auction solve", "rows", "program_counter", "eng_frontier_rows",
+        320000.0),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -622,6 +645,7 @@ CELLS = {
         "reverse_rounds_per_ack", "reverse_ms_per_ack",
     )
 }
+CELLS["frontier_rows_per_ack"] = ["pool-large.ticks", "pool-slack.ticks"]
 
 
 def _without(key: str) -> dict:
@@ -671,3 +695,62 @@ def test_the_stage_metrics_add_up_to_the_outside_ones():
     assert read("solve_wait_ms_per_ack") + read(
         "solve_host_ms_per_ack"
     ) == pytest.approx(read("solve_ms_per_ack"))
+
+
+# ---- ISSUE 30: the frontier's width is fitted inside the phase kernel
+
+class TestFrontierRows:
+    @pytest.mark.parametrize("n_tasks", [512, 358])
+    def test_a_warm_chain_runs_one_phase_executable_a_shape(
+        self, n_tasks, monkeypatch
+    ):
+        """512 providers, a full pool and one with slack (whose reverse
+        pass runs the kernel in its second shape): the open counts of a
+        warm chain cross several rungs, and every segment of every tick
+        is the one executable its shape has; the solve builds nothing
+        on a warm tick, whatever the open counts."""
+        from protocol_tpu.ops import sparse
+        from protocol_tpu.utils import jitwitness
+        from tests.test_pool_slack import open_session
+
+        monkeypatch.setenv("PROTOCOL_TPU_JIT_WITNESS", "1")
+        P = 512
+        gen, arena, session = open_session(P, n_tasks)
+        phase = sparse._sparse_auction_phase
+        traced_before = jitwitness.counts()
+        widths, built = [], []
+        for tick in range(6):
+            mark = TRACER.mark()
+            with session.lock:
+                if tick:
+                    session.apply_delta(*gen.next_delta())
+                session.solve()
+            stats = dict(arena.last_stats)
+            segs = [
+                s["attrs"] for s in TRACER.since(mark)
+                if s["name"] in ("auction.segment", "auction.reverse")
+                and s["attrs"].get("rounds")
+            ]
+            assert stats["eng_frontier_rows"] == sum(a["rows"] for a in segs)
+            widths += [a["rows"] / a["rounds"] for a in segs]
+            built.append(phase._cache_size())
+            if tick >= 2:
+                # (the repair builds its tile programs by the churn's
+                # bucket, another module's business)
+                assert not [
+                    name for name in stats["jit_compiles_delta"]
+                    if name.startswith("protocol_tpu.ops.")
+                ], (tick, stats["jit_compiles_delta"])
+        # traced for a phase entered cold (no state) and for one entered
+        # on carried state, and for the reverse pass's shape where there
+        # is slack: no executable a width, and no new entry in the jit's
+        # own cache after the first warm tick
+        traced = jitwitness.delta(traced_before)
+        (name,) = [n for n in traced if n.endswith(":_sparse_auction_phase")]
+        assert traced[name] <= 2 + (n_tasks < P)
+        assert built[-1] == built[1]
+        # a segment's mean width between two rungs: its rounds ran at
+        # more than one; and some ran at the narrowest alone
+        rungs = sparse._FRONTIER_RUNGS
+        assert any(w not in rungs + (P,) for w in widths), widths
+        assert min(widths) == rungs[0], widths

@@ -1,6 +1,8 @@
 """Sparse top-K pipeline tests: candidate generation vs brute force, full-K
 parity with the dense auction, restricted-graph quality vs scipy optimum."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 from scipy.optimize import linear_sum_assignment
@@ -209,7 +211,7 @@ class TestStallDetection:
         # 3 tasks fighting over 2 providers: one permanent hole
         cand_p = jnp.asarray([[0, 1], [0, 1], [0, 1]], jnp.int32)
         cand_c = jnp.asarray([[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32)
-        state, stall = _sparse_auction_phase(
+        state, stall, _rows = _sparse_auction_phase(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=5000,
             frontier=4, retire=False, stall_limit=16,
         )
@@ -226,7 +228,7 @@ class TestStallDetection:
 
         cand_p = jnp.asarray([[0, 1], [0, 1], [0, 1]], jnp.int32)
         cand_c = jnp.asarray([[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32)
-        state, _stall = _sparse_auction_phase(
+        state, _stall, _rows = _sparse_auction_phase(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=300,
             frontier=4, retire=False, stall_limit=0,
         )
@@ -385,9 +387,11 @@ class TestBidirCandidates:
 
 
 class TestAdaptiveFrontierLadder:
-    """_phase_adaptive: segment-wise frontier shrink with host-side stall
-    accounting (the per-segment stall_limit static would re-trace the
-    kernel every boundary)."""
+    """_phase_adaptive: the phase in 256-round segments of one kernel
+    executable with host-side stall accounting (the per-segment
+    stall_limit static would re-trace the kernel every boundary); the
+    frontier's width is fitted inside the kernel, round by round
+    (TestFrontierRungs)."""
 
     def test_breaker_accumulates_across_segments(self):
         """With retirement off, an unfillable hole stalls forever; the
@@ -400,7 +404,7 @@ class TestAdaptiveFrontierLadder:
         cand_c = jnp.asarray(
             [[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32
         )
-        state, stall = _phase_adaptive(
+        state, stall, _rows = _phase_adaptive(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=100_000,
             frontier=4, retire=False, stall_limit=600,
         )
@@ -433,6 +437,218 @@ class TestAdaptiveFrontierLadder:
             assert (p4t >= 0).all()
             got = sum(cost[p4t[t], t] for t in range(n))
             assert got <= opt + n * 0.006, f"ladder={ladder}: {got} vs {opt}"
+
+
+@partial(jax.jit, static_argnames=("num_providers", "width", "retire"))
+def _plain_round(
+    cand_provider, cand_cost, num_providers, loop, eps, width, retire,
+    reserve=None,
+):
+    """One auction round at ONE static width: the phase kernel's body as
+    it was before the width was fitted inside it (commit 807580a), kept
+    here as the reference. Returns (loop, open tasks the round saw)."""
+    from protocol_tpu.ops.sparse import _NEG, frontier_bids
+
+    T, K = cand_cost.shape
+    P = num_providers
+    B = width
+    cand_valid = cand_provider >= 0
+    value_base = jnp.where(cand_valid, -cand_cost, _NEG)
+    task_feasible = jnp.any(cand_valid, axis=1)
+    cand_safe = jnp.where(cand_valid, cand_provider, 0)
+    finite_max = jnp.max(jnp.where(cand_valid, cand_cost, 0.0))
+    give_up = -(2.0 * finite_max + 10.0) if retire else _NEG
+    if reserve is not None:
+        give_up = reserve + eps
+    state, best, stall = loop
+    it, price, owner, p4t, retired = state
+    open_mask = (p4t < 0) & task_feasible & ~retired
+    f_idx = jnp.flatnonzero(open_mask, size=B, fill_value=T).astype(jnp.int32)
+    f_ok = f_idx < T
+    p1, v1, v2 = frontier_bids(cand_safe, value_base, price, f_idx, f_ok, K)
+    newly_retired = f_ok & (v1 < give_up)
+    bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
+    if reserve is None:
+        bid_amt = price[p1] + (v1 - v2) + eps
+    else:
+        bid_amt = price[p1] + jnp.minimum((v1 - v2) + eps, v1 - reserve)
+    tgt = jnp.where(bidding, p1, P)
+    win_bid = jnp.full(P, _NEG).at[tgt].max(
+        jnp.where(bidding, bid_amt, _NEG), mode="drop"
+    )
+    is_winner_bid = bidding & (bid_amt >= win_bid[p1])
+    win_task = jnp.full(P, T, jnp.int32).at[tgt].min(
+        jnp.where(is_winner_bid, f_idx, T), mode="drop"
+    )
+    got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
+    retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(
+        True, mode="drop"
+    )
+    evict_t = jnp.where(got_bid & (owner >= 0), owner, T)
+    p4t = p4t.at[evict_t].set(-1, mode="drop")
+    p_idx = jnp.arange(P, dtype=jnp.int32)
+    win_t_safe = jnp.where(got_bid, win_task, T)
+    p4t = p4t.at[win_t_safe].set(jnp.where(got_bid, p_idx, -1), mode="drop")
+    owner = jnp.where(got_bid, win_task, owner)
+    price = jnp.where(got_bid, win_bid, price)
+    n_now = jnp.sum(p4t >= 0)
+    improved = n_now > best
+    best = jnp.maximum(best, n_now)
+    stall = jnp.where(improved, 0, stall + 1)
+    loop = ((it + 1, price, owner, p4t, retired), best, stall)
+    return loop, jnp.sum(open_mask)
+
+
+def _plain_phase(
+    cand_provider, cand_cost, num_providers, state, eps, max_iters,
+    frontier, retire, stall_limit, reserve=None,
+):
+    """The phase as a host loop over :func:`_plain_round` at the one
+    width ``min(frontier, T)``, under the kernel's own loop condition.
+    Returns (state, stall, open tasks at the start of every round)."""
+    T = cand_cost.shape[0]
+    P = num_providers
+    if state is None:
+        state = (
+            jnp.int32(0), jnp.zeros(P, jnp.float32),
+            jnp.full(P, -1, jnp.int32), jnp.full(T, -1, jnp.int32),
+            jnp.zeros(T, bool),
+        )
+    state = (jnp.int32(0),) + tuple(state[1:])
+    feasible = np.asarray(jnp.any(cand_provider >= 0, axis=1))
+    loop = (state, jnp.sum(state[3] >= 0), jnp.int32(0))
+    opens = []
+    while True:
+        (it, _price, _owner, p4t, retired), _best, stall = loop
+        n_open = int(
+            ((np.asarray(p4t) < 0) & feasible & ~np.asarray(retired)).sum()
+        )
+        if int(it) >= max_iters or n_open == 0 or (
+            stall_limit > 0 and int(stall) >= stall_limit
+        ):
+            return loop[0], loop[2], opens
+        loop, seen = _plain_round(
+            cand_provider, cand_cost, P, loop, eps, width=min(frontier, T),
+            retire=retire, reserve=reserve,
+        )
+        assert int(seen) == n_open
+        opens.append(n_open)
+
+
+def _uniform_graph(seed, P, T, K, spread=0.0):
+    """Each task's K cheapest of P providers under costs drawn uniformly
+    (top-K costs of 0-12), plus ``spread`` x a per-provider price that
+    makes the tasks' lists overlap."""
+    rng = np.random.default_rng(seed)
+    cost = rng.uniform(0, 1000.0 * K / P, (T, P))
+    cost = (cost + spread * rng.uniform(0, 1, P)[None, :]).astype(np.float32)
+    order = np.argsort(cost, axis=1, kind="stable")[:, :K]
+    return (
+        jnp.asarray(order.astype(np.int32)),
+        jnp.asarray(np.take_along_axis(cost, order, axis=1)),
+    )
+
+
+def _rung_case(name):
+    """(cand_provider, cand_cost, num_providers, state, phase kwargs):
+    the four shapes the phase kernel is entered in."""
+    from protocol_tpu.ops import sparse
+
+    if name == "reverse":
+        # providers bid for tasks over the transposed graph, against the
+        # floor: the pass of a pool with slack (P > T), seeded from a
+        # coarse phase and a finer one that strand bid-up providers
+        P, T = 1024, 600
+        cp, cc = _uniform_graph(2, P, T, 12, spread=2.0)
+        price = owner = p4t = None
+        state = None
+        for eps in (4.0, 0.25):
+            if state is not None:
+                owner, p4t = sparse._unassign_unhappy(
+                    cp, cc, price, owner, p4t, eps
+                )
+                state = (jnp.int32(0), price, owner, p4t, jnp.zeros(T, bool))
+            state, _stall, _rows = sparse._sparse_auction_phase(
+                cp, cc, P, state, eps=eps, max_iters=4000, frontier=4096,
+            )
+            _, price, owner, p4t, _ = state
+        rev_t, rev_c = sparse._transpose_candidates(cp, cc, P, 32)
+        rstate, floor = sparse._reverse_seed(cp, cc, price, owner, p4t)
+        return rev_t, rev_c, T, rstate, dict(
+            eps=0.25, max_iters=600, frontier=P, retire=True, stall_limit=0,
+            reserve=floor,
+        )
+    P, T = 2000, 2048
+    cp, cc = _uniform_graph(1, P, T, 12)
+    if name == "cold":
+        # more open tasks than ``frontier``: the top rung is the plain
+        # round, the first ``frontier`` open tasks in index order
+        return cp, cc, P, None, dict(
+            eps=0.25, max_iters=300, frontier=1024, retire=True,
+            stall_limit=0,
+        )
+    # a converged phase, then a handful of tasks unseated on its prices
+    state, _stall, _rows = sparse._sparse_auction_phase(
+        cp, cc, P, None, eps=0.02, max_iters=20000, frontier=4096,
+    )
+    _, price, _owner, p4t, retired = state
+    p4t = p4t.at[jnp.asarray([3, 77, 500, 1200, 2000])].set(-1)
+    state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t, retired)
+    if name == "warm":
+        return cp, cc, P, state, dict(
+            eps=0.02, max_iters=600, frontier=4096, retire=True,
+            stall_limit=0,
+        )
+    assert name == "stall"
+    # every eighth task unseated too, nobody retires and nothing is
+    # retired: 48 tasks have no seat, and the phase ends by its stall
+    # limit
+    p4t = p4t.at[::8].set(-1)
+    state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t,
+             jnp.zeros(T, bool))
+    return cp, cc, P, state, dict(
+        eps=0.02, max_iters=4000, frontier=4096, retire=False,
+        stall_limit=48,
+    )
+
+
+class TestFrontierRungs:
+    """The phase kernel fits its frontier to the open set every round
+    (``_FRONTIER_RUNGS``); a width that holds every open task must be
+    invisible in the state."""
+
+    @pytest.mark.parametrize("name", ["cold", "warm", "stall", "reverse"])
+    def test_state_equals_the_single_width_round(self, name):
+        from protocol_tpu.ops import sparse
+
+        cp, cc, P, state, kw = _rung_case(name)
+        want, want_stall, opens = _plain_phase(cp, cc, P, state, **kw)
+        got, got_stall, rows = sparse._sparse_auction_phase(
+            cp, cc, P, state, **kw
+        )
+        assert len(opens) == int(got[0]) > 0
+        for field, a, b in zip(
+            ("it", "price", "owner", "p4t", "retired"), want, got
+        ):
+            # exactly: no tolerance on the prices either
+            assert np.array_equal(np.asarray(a), np.asarray(b)), field
+        assert int(got_stall) == int(want_stall)
+        top = min(kw["frontier"], cc.shape[0])
+        widths = [w for w in sparse._FRONTIER_RUNGS if w < top] + [top]
+        ran_at = [next((w for w in widths if n <= w), top) for n in opens]
+        assert int(rows) == sum(ran_at)
+        # the case is the shape it says it is, and crosses rungs (but
+        # the warm one: a handful open, the narrowest rung all along)
+        assert len(set(ran_at)) >= (1 if name == "warm" else 2), set(ran_at)
+        if name == "cold":
+            assert opens[0] > top
+        if name == "warm":
+            assert set(ran_at) == {sparse._FRONTIER_RUNGS[0]}
+            assert int(np.asarray(got[4]).sum()) > 0
+        if name == "stall":
+            assert int(got_stall) >= kw["stall_limit"] and opens[-1] > 0
+        if name == "reverse":
+            assert opens[-1] > 0 or len(opens) < kw["max_iters"]
 
 
 class TestWarmColdRegression:
