@@ -6,7 +6,7 @@ two stages of the sparse pipeline —
   stage A  candidates_topk   streaming top-K candidate generation,
                              peak memory O(P_shard * tile)
   stage B  sparse auction    frontier auction over [T, K] candidates
-                             (single-device and mesh-sharded)
+                             (one device)
 
 — plus compile-time HBM envelopes from XLA's buffer assignment at the FULL
 ladder-#4 shapes (P_shard = 1M/8 per v5e-8 chip, T = 1M, K = 64), which do
@@ -79,7 +79,6 @@ def main() -> None:
     from protocol_tpu.ops.cost import CostWeights
     from protocol_tpu.ops.encoding import FeatureEncoder
     from protocol_tpu.ops.sparse import assign_auction_sparse, candidates_topk
-    from protocol_tpu.parallel import assign_auction_sparse_sharded, make_mesh
 
     n_dev = len(jax.devices())
     log(f"platform={platform} devices={n_dev}")
@@ -303,28 +302,6 @@ def main() -> None:
     )
     log(f"  {secs_b:.3f}s, {assigned}/{T_AUCTION} assigned "
         f"({assigned / secs_b:,.0f} assignments/s)")
-
-    # stage B sharded over the mesh (same wire-path candidates)
-    log(f"stage B: mesh-sharded auction over {n_dev} devices")
-    mesh = make_mesh(n_dev)
-    secs_s, res_s = measure(
-        lambda: assign_auction_sparse_sharded(
-            cpb, ccb, num_providers=P_B, mesh=mesh,
-            eps=0.05, max_iters=2000, frontier=min(T_AUCTION, 8192),
-            retire=True,
-        ).provider_for_task
-    )
-    assigned_s = int((np.asarray(res_s) >= 0).sum())
-    emit(
-        {
-            "stage": f"B sparse auction (measured, {n_dev}-device mesh, bidir)",
-            "platform": platform,
-            "shape": f"T={T_AUCTION} K={K} reverse_r=8 extra=16",
-            "wall_s": round(secs_s, 3),
-            "assignments_per_s": round(assigned_s / secs_s, 0),
-        }
-    )
-    log(f"  {secs_s:.3f}s sharded ({assigned_s} assigned)")
 
     # stage B memory envelope at T=1M
     try:

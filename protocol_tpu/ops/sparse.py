@@ -741,7 +741,8 @@ def _reverse_finish(cand_provider, cand_cost, price, profit0, rstate, floor):
 
 
 def _forward_reverse(
-    run_phase, cand_provider, cand_cost, num_providers: int, state, eps,
+    cand_provider, cand_cost, num_providers: int, state, eps,
+    max_iters: int, frontier: int, stall_limit: int,
     stats_out: dict | None, transposed: list,
 ):
     """One eps phase to the condition a pool with free providers needs.
@@ -752,9 +753,9 @@ def _forward_reverse(
     asymmetric assignment). A provider that was bid up and then
     abandoned, by a coarser rung's eviction or by churn ticks ago,
     keeps its raised price and attracts nobody, however cheap it is.
-    So after the forward phase ``run_phase(state) -> (state, stall,
-    frontier rows)`` the stranded providers bid for tasks: the SAME
-    phase kernel on the
+    So after the forward phase (:func:`_phase_adaptive` under
+    ``max_iters``, ``frontier`` and ``stall_limit``) the stranded
+    providers bid for tasks: the SAME phase kernel on the
     transposed candidate graph, each lowering its price to where its
     second-best taker would just take it (never below the floor) and
     taking the best one, whose old provider bids in turn; one that no
@@ -771,7 +772,11 @@ def _forward_reverse(
     ``frontier_rows`` (the widths every round of the phase ran at, both
     directions, summed).
     Returns (state, stall, rounds of the forward phase)."""
-    state, stall, rows = run_phase(state)
+    state, stall, rows = _phase_adaptive(
+        cand_provider, cand_cost, num_providers, state,
+        eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
+        stall_limit=stall_limit, stats_out=stats_out,
+    )
     it, price, owner, p4t, retired = state
     rounds = int(it) if stats_out is not None else 0
     t0 = time.perf_counter()
@@ -814,7 +819,7 @@ def _forward_reverse(
             ("free_repriced", lowered),
             ("reverse_rounds", reverse_rounds),
             ("reverse_ms", (time.perf_counter() - t0) * 1e3),
-            ("frontier_rows", int(rows) + reverse_rows),
+            ("frontier_rows", rows + reverse_rows),
         ):
             stats_out[key] = round(stats_out.get(key, 0) + value, 3)
     return state, stall, rounds
@@ -895,7 +900,6 @@ def assign_auction_sparse_scaled(
     with_prices: bool = False,
     stall_limit: int = 64,
     stats_out: dict | None = None,
-    frontier_ladder: bool = True,
     with_state: bool = False,
 ):
     """eps-scaling auction: geometric eps ladder with warm-started prices
@@ -935,29 +939,17 @@ def assign_auction_sparse_scaled(
     eps = eps_start
     rounds_total = 0
     transposed: list = []
-    # frontier_ladder: the phase in host-driven segments under the stall
-    # breaker (see _phase_adaptive) — disable for one call of the kernel
-    # a phase with its stall limit inside (the sharded-parity tests
-    # compare against the mesh kernel, which runs that way)
-    phase_fn = (
-        partial(_phase_adaptive, stats_out=stats_out)
-        if frontier_ladder else _sparse_auction_phase
-    )
     while True:
         final = eps <= eps_end
         state, stall, rounds = _forward_reverse(
-            partial(
-                phase_fn, cand_provider, cand_cost, num_providers,
-                eps=eps, max_iters=max_iters_per_phase, frontier=frontier,
-                # the FINAL phase's retirement is binding and its eviction
-                # chains (closing eps_end-sized price gaps) legitimately
-                # make no net progress for long stretches — give it 8x the
-                # circuit-breaker budget of the disposable coarse phases
-                retire=True,
-                stall_limit=stall_limit * (8 if final else 1),
-            ),
-            cand_provider, cand_cost, num_providers, state, eps, stats_out,
-            transposed,
+            cand_provider, cand_cost, num_providers, state, eps,
+            max_iters=max_iters_per_phase, frontier=frontier,
+            # the FINAL phase's retirement is binding and its eviction
+            # chains (closing eps_end-sized price gaps) legitimately
+            # make no net progress for long stretches — give it 8x the
+            # circuit-breaker budget of the disposable coarse phases
+            stall_limit=stall_limit * (8 if final else 1),
+            stats_out=stats_out, transposed=transposed,
         )
         # per-phase round count (read back only when asked for)
         rounds_total += rounds
@@ -1125,7 +1117,6 @@ def assign_auction_sparse_warm(
     frontier: int = 4096,
     stall_limit: int = 64,
     stats_out: dict | None = None,
-    frontier_ladder: bool = True,
     retired0: jax.Array | None = None,
     with_state: bool = False,
 ) -> tuple[AssignResult, jax.Array]:
@@ -1202,21 +1193,15 @@ def assign_auction_sparse_warm(
             p4t0,
             retired_seed,
         )
-    phase_fn = (
-        partial(_phase_adaptive, stats_out=stats_out)
-        if frontier_ladder else _sparse_auction_phase
-    )
     state, stall, rounds = _forward_reverse(
-        partial(
-            phase_fn, cand_provider, cand_cost, num_providers,
-            eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
-            # the warm solve is a binding final phase: same 8x stall budget
-            # as the scaled ladder's last phase (see
-            # assign_auction_sparse_scaled); stall_limit=0 opts out (run to
-            # max_iters)
-            stall_limit=stall_limit * 8,
-        ),
-        cand_provider, cand_cost, num_providers, state, eps, stats_out, [],
+        cand_provider, cand_cost, num_providers, state, eps,
+        max_iters=max_iters, frontier=frontier,
+        # the warm solve is a binding final phase: same 8x stall budget
+        # as the scaled ladder's last phase (see
+        # assign_auction_sparse_scaled); stall_limit=0 opts out (run to
+        # max_iters)
+        stall_limit=stall_limit * 8,
+        stats_out=stats_out, transposed=[],
     )
     _report_stall("warm", stall, stall_limit * 8, stats_out)
     if stats_out is not None:

@@ -18,19 +18,11 @@ from protocol_tpu.parallel.mesh import make_mesh, pad_to_multiple
 from protocol_tpu.parallel.auction import assign_auction_sharded
 from protocol_tpu.parallel.jax_arena import JaxSolveArena
 from protocol_tpu.parallel.sinkhorn import sinkhorn_potentials_sharded
-from protocol_tpu.parallel.sparse import (
-    assign_auction_sparse_scaled_sharded,
-    assign_auction_sparse_sharded,
-    assign_auction_sparse_warm_sharded,
-    candidates_topk_bidir_sharded,
-)
+from protocol_tpu.parallel.sparse import candidates_topk_bidir_sharded
 
 __all__ = [
     "JaxSolveArena",
     "assign_auction_sharded",
-    "assign_auction_sparse_scaled_sharded",
-    "assign_auction_sparse_sharded",
-    "assign_auction_sparse_warm_sharded",
     "candidates_topk_bidir_sharded",
     "make_mesh",
     "pad_to_multiple",

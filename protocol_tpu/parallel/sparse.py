@@ -1,23 +1,12 @@
-"""Task-sharded sparse auction over a device mesh.
+"""Candidate generation and repair, task-sharded over a device mesh.
 
-The 1M x 1M configuration (BASELINE.md ladder #4/#5): candidate lists
-[T, K] are sharded task-wise across the mesh (tasks outnumber everything
-and their state is per-task), while the per-provider price/owner vectors
-[P] are replicated and combined with max/min collectives each round —
-P floats of ICI traffic per array, independent of T*K.
-
-Round structure per device (mirrors ops/sparse.py's frontier auction):
-  1. local frontier of open local tasks -> local bids
-  2. local provider-side winner resolution (scatter-max / scatter-min)
-  3. global combine: win_bid = pmax, win_task = pmin among max-bidders
-     (task ids are globally formed as shard_offset + local index, so ties
-     break identically to the single-device kernel)
-  4. replicated price/owner update; each shard applies evictions/wins to
-     the task rows it owns
-
-With frontier >= T/D and retire=False this is the Jacobi schedule and is
-exactly parity with the single-device sparse kernel — tested on the
-virtual 8-device CPU mesh.
+What the jax arena serves across chips (and, for the repair, on one):
+the bidirectional candidate pass :func:`candidates_topk_bidir_sharded`
+and the warm tick's :func:`repair_topk_bidir_sharded`. Both split the
+task rows over the mesh and are bit-identical to the single-device
+pass, so a plan does not depend on the device count. The auction solve
+does not shard: its round is latency, not bytes, and it runs on one
+device (ops/sparse.py).
 """
 
 from __future__ import annotations
@@ -31,407 +20,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from protocol_tpu.obs.spans import TRACER as _tracer
-from protocol_tpu.ops.assign import AssignResult, _invert
-from protocol_tpu.ops.sparse import frontier_bids
-
-_NEG = -1e18
-
-
-def assign_auction_sparse_sharded(
-    cand_provider: jax.Array,
-    cand_cost: jax.Array,
-    num_providers: int,
-    mesh: Mesh,
-    eps: float = 0.01,
-    max_iters: int = 10000,
-    frontier: int = 4096,
-    retire: bool = True,
-    axis: str = "p",
-) -> AssignResult:
-    """Sparse auction with tasks sharded over ``mesh`` axis ``axis``.
-
-    cand_provider/cand_cost are [T, K] with T divisible by the mesh size.
-    Returns a replicated AssignResult. A thin wrapper over the state-
-    passing phase kernel with zero-initialized dual state — ONE shard_map
-    body serves this, the eps ladder, and the warm solve, so the
-    winner-resolution math the Jacobi parity guarantee rests on exists in
-    exactly one sharded copy.
-    """
-    T, K = cand_cost.shape
-    D = mesh.shape[axis]
-    if T % D != 0:
-        raise ValueError(f"T={T} not divisible by mesh size {D}; pad first")
-    Pn = num_providers
-    B = min(frontier, T // D)
-
-    sharding = NamedSharding(mesh, P(axis, None))
-    cand_provider = jax.device_put(cand_provider, sharding)
-    cand_cost = jax.device_put(cand_cost, sharding)
-
-    run = _build_sharded_phase(mesh, axis, Pn, B, int(max_iters), bool(retire))
-    _price, _owner, p4t, _retired, _stall = run(
-        cand_provider, cand_cost, jnp.float32(eps), jnp.int32(0),
-        jnp.zeros(Pn, jnp.float32), jnp.full(Pn, -1, jnp.int32),
-        jnp.full(T, -1, jnp.int32), jnp.zeros(T, bool),
-    )
-    return AssignResult(p4t, _invert(p4t, Pn))
-
-
-@lru_cache(maxsize=64)
-def _build_sharded_phase(
-    mesh: Mesh,
-    axis: str,
-    Pn: int,
-    B: int,
-    max_iters: int,
-    retire: bool,
-):
-    """The ONE sharded auction body: an eps PHASE that accepts carried
-    dual state (prices, owner, assignment) and returns it, so the plain
-    solve (zero state), the eps-scaling ladder, and the warm/incremental
-    solve all compose over the mesh exactly like their single-device
-    twins (ops/sparse._sparse_auction_phase). eps AND the stall limit
-    ride in as traced scalars — one cached executable serves every rung
-    of the ladder (limit <= 0 disables stall termination). Built once per
-    static config and cached: a fresh closure per call would re-trace and
-    re-compile the whole while_loop each solve (~9.5 s/call measured on
-    the 8-dev CPU mesh)."""
-    D = mesh.shape[axis]
-
-    @jax.jit
-    @partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None), P(), P(), P(), P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), P()),
-        check_vma=False,
-    )
-    def run(cand_p_local, cand_c_local, eps, stall_limit, price0, owner0, p4t0,
-            retired0):
-        Tl, K = cand_p_local.shape
-        T = Tl * D
-        shard = lax.axis_index(axis)
-        offset = (shard * Tl).astype(jnp.int32)
-        p4t_local = lax.dynamic_slice_in_dim(p4t0, offset, Tl)
-        retired_local = lax.dynamic_slice_in_dim(retired0, offset, Tl)
-
-        cand_valid = cand_p_local >= 0
-        value_base = jnp.where(cand_valid, -cand_c_local, _NEG)  # [Tl, K]
-        task_feasible = jnp.any(cand_valid, axis=1)
-        cand_safe = jnp.where(cand_valid, cand_p_local, 0)
-        finite_max = lax.pmax(
-            jnp.max(jnp.where(cand_valid, cand_c_local, 0.0)), axis
-        )
-        give_up = -(2.0 * finite_max + 10.0) if retire else jnp.float32(_NEG)
-
-        def n_assigned(p4t_l):
-            return lax.psum(jnp.sum(p4t_l >= 0), axis)
-
-        def cond(loop):
-            (it, price, owner, p4t_local, retired), best, stall = loop
-            n_open = lax.psum(
-                jnp.sum((p4t_local < 0) & task_feasible & ~retired), axis
-            )
-            go = (it < max_iters) & (n_open > 0)
-            go &= (stall_limit <= 0) | (stall < stall_limit)
-            return go
-
-        def body(loop):
-            state, best, stall = loop
-            it, price, owner, p4t_local, retired = state
-            open_mask = (p4t_local < 0) & task_feasible & ~retired
-
-            f_idx = jnp.flatnonzero(open_mask, size=B, fill_value=Tl).astype(
-                jnp.int32
-            )
-            f_ok = f_idx < Tl
-            # shared bid math: bit-identical to the single-device kernel
-            p1, v1, v2 = frontier_bids(
-                cand_safe, value_base, price, f_idx, f_ok, K
-            )
-
-            newly_retired = f_ok & (v1 < give_up)
-            retired = retired.at[jnp.where(newly_retired, f_idx, Tl)].set(
-                True, mode="drop"
-            )
-
-            bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
-            bid_amt = price[p1] + (v1 - v2) + eps
-            tgt = jnp.where(bidding, p1, Pn)
-            gtask = offset + f_idx  # global task ids of the frontier
-
-            win_bid_l = jnp.full(Pn, _NEG).at[tgt].max(
-                jnp.where(bidding, bid_amt, _NEG), mode="drop"
-            )
-            win_bid = lax.pmax(win_bid_l, axis)
-            is_winner = bidding & (bid_amt >= win_bid[p1])
-            win_task_l = jnp.full(Pn, T, jnp.int32).at[tgt].min(
-                jnp.where(is_winner, gtask, T), mode="drop"
-            )
-            win_task = lax.pmin(win_task_l, axis)
-            got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
-
-            evict_g = jnp.where(got_bid & (owner >= 0), owner, T)
-            e_in = (evict_g >= offset) & (evict_g < offset + Tl)
-            p4t_local = p4t_local.at[jnp.where(e_in, evict_g - offset, Tl)].set(
-                -1, mode="drop"
-            )
-            p_idx = jnp.arange(Pn, dtype=jnp.int32)
-            w_in = got_bid & (win_task >= offset) & (win_task < offset + Tl)
-            p4t_local = p4t_local.at[jnp.where(w_in, win_task - offset, Tl)].set(
-                jnp.where(w_in, p_idx, -1), mode="drop"
-            )
-
-            owner = jnp.where(got_bid, win_task, owner)
-            price = jnp.where(got_bid, win_bid, price)
-            n_now = n_assigned(p4t_local)
-            improved = n_now > best
-            best = jnp.maximum(best, n_now)
-            stall = jnp.where(improved, 0, stall + 1)
-            return (it + 1, price, owner, p4t_local, retired), best, stall
-
-        state0 = (
-            jnp.int32(0),
-            jnp.asarray(price0, jnp.float32),
-            jnp.asarray(owner0, jnp.int32),  # GLOBAL task ids
-            p4t_local,
-            retired_local,
-        )
-        loop0 = (state0, n_assigned(p4t_local), jnp.int32(0))
-        (_, price, owner, p4t_local, retired_l), _best, stall = lax.while_loop(
-            cond, body, loop0
-        )
-        return (
-            price,
-            owner,
-            lax.all_gather(p4t_local, axis).reshape(T),
-            lax.all_gather(retired_l, axis).reshape(T),
-            stall,
-        )
-
-    return run
-
-
-def _run_phase_sharded(
-    mesh, axis, Pn, B0, max_iters, cand_p_dev, cand_c_dev,
-    task_feasible, eps, stall_limit, price, owner, p4t,
-    frontier_ladder, retired=None,
-):
-    """One sharded eps phase, optionally in fixed-size segments with the
-    per-shard frontier executable direct-fit to the live open set — the
-    mesh twin of ops.sparse._phase_adaptive's segments (same measured
-    rationale: most rounds are tail eviction chains with a small open
-    set; the single-device kernel fits its width inside the program,
-    round by round, this one on the host between segments). The
-    per-B executables come from the lru_cache'd builder, so the ladder
-    costs at most a handful of compiles per config. The retirement mask
-    threads through segments (and back to the caller) exactly like the
-    single-device state tuple — resetting it per segment would re-open
-    retired tasks mid-phase, a semantics drift from _phase_adaptive."""
-    D = mesh.shape[axis]
-    if retired is None:
-        retired = jnp.zeros(p4t.shape[0], bool)
-    if not frontier_ladder:
-        run = _build_sharded_phase(mesh, axis, Pn, B0, int(max_iters), True)
-        return run(
-            cand_p_dev, cand_c_dev, jnp.float32(eps),
-            jnp.int32(stall_limit), price, owner, p4t, retired,
-        )
-    seg_rounds = 256
-    iters_left = int(max_iters)
-    B = B0
-    carried = 0
-    floor = max(64, 512 // D)
-    while iters_left > 0:
-        run = _build_sharded_phase(mesh, axis, Pn, B, seg_rounds, True)
-        price, owner, p4t, retired, stall = run(
-            cand_p_dev, cand_c_dev, jnp.float32(eps), jnp.int32(0),
-            price, owner, p4t, retired,
-        )
-        # the segment kernel reports only its own trailing stall; rounds
-        # are bounded by seg_rounds so a whole-segment stall accumulates
-        s = int(stall)
-        carried = carried + seg_rounds if s >= seg_rounds else s
-        iters_left -= seg_rounds
-        open_count = int(jnp.sum((p4t < 0) & task_feasible & ~retired))
-        if open_count == 0:
-            break
-        if stall_limit > 0 and carried >= int(stall_limit):
-            break
-        fit = floor
-        while fit * D < open_count and fit < B:
-            fit *= 2
-        B = min(B, fit)
-    return price, owner, p4t, retired, jnp.int32(carried)
-
-
-def assign_auction_sparse_scaled_sharded(
-    cand_provider: jax.Array,
-    cand_cost: jax.Array,
-    num_providers: int,
-    mesh: Mesh,
-    eps_start: float = 4.0,
-    eps_end: float = 0.02,
-    scale: float = 0.25,
-    max_iters_per_phase: int = 4000,
-    frontier: int = 4096,
-    with_prices: bool = False,
-    stall_limit: int = 64,
-    axis: str = "p",
-    stats_out: dict | None = None,
-    frontier_ladder: bool = False,
-    with_state: bool = False,
-):
-    """The eps-scaling ladder over the task-sharded phase kernel — the
-    multi-chip twin of ops.sparse.assign_auction_sparse_scaled with the
-    SAME phase discipline (disposable coarse phases whose retirements are
-    reversed, eps-CS repair between rungs, binding final phase with an 8x
-    stall budget, final greedy cleanup). Stage-B completeness at the 1M
-    ladder shape = bidirectional candidates + this ladder over v5e-8
-    (SCALING.md stage B2). The inter-phase repair and cleanup run on
-    replicated arrays (O(T*K) elementwise — negligible next to the
-    sharded while_loop they bracket)."""
-    from protocol_tpu.ops.sparse import (
-        _forward_reverse,
-        _greedy_cleanup,
-        _report_stall,
-        _unassign_unhappy,
-    )
-
-    T, K = cand_cost.shape
-    D = mesh.shape[axis]
-    if T % D != 0:
-        raise ValueError(f"T={T} not divisible by mesh size {D}; pad first")
-    B = min(frontier, T // D)
-    sharding = NamedSharding(mesh, P(axis, None))
-    cand_p_dev = jax.device_put(cand_provider, sharding)
-    cand_c_dev = jax.device_put(cand_cost, sharding)
-
-    price = jnp.zeros(num_providers, jnp.float32)
-    owner = jnp.full(num_providers, -1, jnp.int32)
-    p4t = jnp.full(T, -1, jnp.int32)
-    task_feasible = jnp.any(cand_provider >= 0, axis=1)
-    eps = eps_start
-    transposed: list = []
-    while True:
-        final = eps <= eps_end
-
-        def run(state, eps=eps, final=final):
-            # binding final phase gets 8x the disposable phases' stall
-            # budget (same discipline as the single-device ladder)
-            price, owner, p4t, retired, stall = _run_phase_sharded(
-                mesh, axis, num_providers, B, max_iters_per_phase,
-                cand_p_dev, cand_c_dev, task_feasible, eps,
-                stall_limit * (8 if final else 1), *state[1:4],
-                frontier_ladder,
-            )
-            # (the mesh kernel keeps no count of its frontier rows)
-            return (jnp.int32(0), price, owner, p4t, retired), stall, 0
-
-        # the reverse pass over the providers the phase left free runs
-        # on replicated arrays, like the repair and the cleanup: the
-        # single-device ladder's, call for call
-        (_, price, owner, p4t, retired), stall, _ = _forward_reverse(
-            run, cand_provider, cand_cost, num_providers,
-            (None, price, owner, p4t, None), eps, None, transposed,
-        )
-        if final:
-            _report_stall("scaled-sharded", stall, stall_limit * 8, stats_out)
-            break
-        eps = max(eps * scale, eps_end)
-        owner, p4t = _unassign_unhappy(
-            cand_provider, cand_cost, price, owner, p4t, eps
-        )
-        # coarse-phase retirement was only a circuit breaker; each
-        # _run_phase_sharded call starts from a fresh retired=0 mask, so
-        # un-retire needs no explicit step here — only the binding
-        # phase's retirement survives into the returned state
-    p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
-    res = AssignResult(p4t, _invert(p4t, num_providers))
-    if with_state:
-        return res, price, retired & (p4t < 0)
-    if with_prices:
-        return res, price
-    return res
-
-
-def assign_auction_sparse_warm_sharded(
-    cand_provider: jax.Array,
-    cand_cost: jax.Array,
-    num_providers: int,
-    mesh: Mesh,
-    price0: jax.Array,
-    p4t0: jax.Array,
-    eps: float = 0.02,
-    max_iters: int = 20000,
-    frontier: int = 4096,
-    stall_limit: int = 64,
-    axis: str = "p",
-    stats_out: dict | None = None,
-    frontier_ladder: bool = False,
-    retired0: jax.Array | None = None,
-    with_state: bool = False,
-) -> tuple[AssignResult, jax.Array]:
-    """Incremental (delta-frontier) solve over the mesh: the multi-chip
-    twin of ops.sparse.assign_auction_sparse_warm — same seed hygiene
-    (candidate-less seeds dropped, carried prices downshifted below the
-    retirement floor), same eps-CS repair admission, one binding sharded
-    phase, greedy cleanup, same optional retirement carry (``retired0`` /
-    ``with_state`` — see the single-device docstring for why retirement
-    is dual state). Returns (AssignResult, final prices [P]), plus the
-    final retirement mask when ``with_state=True``."""
-    from protocol_tpu.ops.sparse import (
-        _forward_reverse,
-        _greedy_cleanup,
-        _report_stall,
-        _unassign_unhappy,
-    )
-
-    T, K = cand_cost.shape
-    D = mesh.shape[axis]
-    if T % D != 0:
-        raise ValueError(f"T={T} not divisible by mesh size {D}; pad first")
-
-    task_has_cand = jnp.any(cand_provider >= 0, axis=1)
-    p4t0 = jnp.where(task_has_cand, jnp.asarray(p4t0, jnp.int32), -1)
-    # uniform downshift, NOT a clamp — must stay bit-identical to the
-    # single-device seed hygiene (see ops.sparse.assign_auction_sparse_warm
-    # for the measured clamp pathology)
-    finite_max = jnp.max(jnp.where(cand_provider >= 0, cand_cost, 0.0))
-    price0 = jnp.asarray(price0, jnp.float32)
-    price0 = price0 - jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
-    owner0 = _invert(p4t0, num_providers)
-    owner0, p4t0 = _unassign_unhappy(
-        cand_provider, cand_cost, price0, owner0, p4t0, eps
-    )
-
-    if retired0 is None:
-        retired_seed = jnp.zeros(T, bool)
-    else:
-        retired_seed = jnp.asarray(retired0, bool) & (p4t0 < 0)
-    sharding = NamedSharding(mesh, P(axis, None))
-    cand_p_dev = jax.device_put(cand_provider, sharding)
-    cand_c_dev = jax.device_put(cand_cost, sharding)
-
-    def run(state):
-        price, owner, p4t, retired, stall = _run_phase_sharded(
-            mesh, axis, num_providers, min(frontier, T // D), max_iters,
-            cand_p_dev, cand_c_dev, task_has_cand, eps,
-            stall_limit * 8, *state[1:4], frontier_ladder,
-            retired=state[4],
-        )
-        return (jnp.int32(0), price, owner, p4t, retired), stall, 0
-
-    (_, price, owner, p4t, retired), stall, _ = _forward_reverse(
-        run, cand_provider, cand_cost, num_providers,
-        (None, price0, owner0, p4t0, retired_seed), eps, None, [],
-    )
-    _report_stall("warm-sharded", stall, stall_limit * 8, stats_out)
-    p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
-    res = AssignResult(p4t, _invert(p4t, num_providers))
-    if with_state:
-        return res, price, retired & (p4t < 0)
-    return res, price
 
 
 def _merge_rev_pools(
@@ -477,10 +65,9 @@ def candidates_topk_bidir_sharded(
     [P, tile] cost blocks (providers replicated: P x ~14 f32 columns,
     megabytes at 1M) with ZERO per-round collectives; the only
     communication in the whole pass is one all_gather of the [T, k]
-    forward lists and the [D, P, r] reverse pools at the end — so v5e-8
-    speedup on this stage is ~linear in D, unlike the solve kernel whose
-    every round all-reduces the [P] price/owner vectors (see the ICI cost
-    model in SCALING.md).
+    forward lists and the [D, P, r] reverse pools at the end — so the
+    speedup on this stage is ~linear in D (the solve that follows runs
+    on one device).
 
     Parity: the forward tile step is ops.sparse._forward_tile_select
     (shared verbatim — jitter offsets arranged so each shard computes the
@@ -569,9 +156,9 @@ def _build_sharded_gen(
     er_treedef,
     with_pools: bool = False,
 ):
-    """Cached builder for the sharded generation executable (same
-    re-trace rationale as _build_sharded_phase: a fresh jit+shard_map
-    closure per call would recompile the whole scan each rebuild).
+    """Cached builder for the sharded generation executable (a fresh
+    jit+shard_map closure per call would re-trace and recompile the
+    whole scan each rebuild).
     ``with_pools`` additionally streams out each tile's raw reverse
     contribution [n_tiles, P, rt] (shard-major concatenation == global
     tile order) — the persistent pre-fold state the warm repair keeps."""

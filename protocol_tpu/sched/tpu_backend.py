@@ -290,21 +290,17 @@ class TpuBatchMatcher:
         self.native_threads = int(native_threads)
         self._native_arena = None
         self._last_arena_stats: dict = {}
-        # multi-chip solves: route phase 1's eps-ladder / warm kernels
-        # through the task-sharded mesh variants (parallel/sparse.py, the
-        # v5e-8 path) when more than one device is visible. Opt-in
-        # (deploy sets PROTOCOL_TPU_USE_MESH=1 via serve): the sharded
-        # frontier schedule is a different — equally valid — auction
-        # order, and single-chip deployments gain nothing from it.
+        # multi-chip hosts: shard phase 1's candidate generation over
+        # the visible devices (parallel/sparse.py; bit-identical lists,
+        # so the plan does not depend on it); the solve runs on one
+        # device. Opt-in (deploy sets PROTOCOL_TPU_USE_MESH=1 via serve).
         self.use_mesh = use_mesh
         # stage-A selection via lax.approx_max_k (TPU PartialReduce)
         # instead of exact lax.top_k — the measured stage-A bottleneck's
         # mitigation (SCALING.md); e.g. 0.95. None = exact.
         self.approx_recall = approx_recall
         self._mesh = None
-        self._last_sharded = False
         self._last_gen_sharded = False
-        self._mesh_fallback_logged = False
         if native_fallback:
             # pin the process to the host platform NOW: this matcher was
             # asked for the CPU engine, so its process must not claim the
@@ -322,7 +318,7 @@ class TpuBatchMatcher:
             else:
                 logging.getLogger(__name__).warning(
                     "use_mesh requested but only one device is visible; "
-                    "solving single-device"
+                    "generating single-device"
                 )
         self._time = time_fn
         self._dirty = True
@@ -581,8 +577,7 @@ class TpuBatchMatcher:
             gen=gen,
         )
         # "sharded generation RAN", not "was configured": a memo hit
-        # generated nothing (same actually-engaged semantics as
-        # mesh_sharded)
+        # generated nothing
         self._last_gen_sharded = (
             gen is not None and self._cand_memo.misses > misses_before
         )
@@ -595,40 +590,10 @@ class TpuBatchMatcher:
 
     def _sparse_solve(self, cand_p, cand_c, num_providers, warm, price0, p4t0,
                       stats_out=None, retired0=None):
-        """Phase 1's solve dispatch: warm vs cold ladder, single-device vs
-        the task-sharded mesh twins (bit-identical phase discipline —
-        parallel/sparse.py) when ``use_mesh`` found devices. Always
+        """Phase 1's solve dispatch: warm solve vs cold ladder. Always
         returns (result, prices, retired) — the full dual state, so
         chained warm solves can skip re-fighting priced-out slots
         (ops/sparse.py: retirement carry)."""
-        D = self._mesh.shape["p"] if self._mesh is not None else 0
-        self._last_sharded = D > 1 and cand_p.shape[0] % D == 0
-        if self._last_sharded:
-            from protocol_tpu.parallel import (
-                assign_auction_sparse_scaled_sharded,
-                assign_auction_sparse_warm_sharded,
-            )
-
-            if warm:
-                return assign_auction_sparse_warm_sharded(
-                    cand_p, cand_c, num_providers, self._mesh,
-                    price0=price0, p4t0=p4t0, stats_out=stats_out,
-                    frontier_ladder=True, retired0=retired0,
-                    with_state=True,
-                )
-            return assign_auction_sparse_scaled_sharded(
-                cand_p, cand_c, num_providers, self._mesh,
-                stats_out=stats_out, frontier_ladder=True, with_state=True,
-            )
-        if D > 1 and not self._mesh_fallback_logged:
-            # a requested-but-never-engaging mesh must be observable, not
-            # indistinguishable from a working one
-            self._mesh_fallback_logged = True
-            logging.getLogger(__name__).warning(
-                "mesh solve requested but slot count %d is not divisible "
-                "by the %d-device mesh; solving single-device",
-                int(cand_p.shape[0]), D,
-            )
         if warm:
             return assign_auction_sparse_warm(
                 cand_p, cand_c, num_providers,
@@ -1289,7 +1254,6 @@ class TpuBatchMatcher:
                     self.max_replica_slots,
                     truncated_slots,
                 )
-        self._last_sharded = False  # set by _sparse_solve when it engages
         self._last_arena_stats = {}  # set by _bounded_t4p on the native path
         self._claim_rows_now = None  # set by the claim-masking block below
         s_bucket = _pow2_bucket(len(slot_task)) if slot_task else 0
@@ -1521,15 +1485,17 @@ class TpuBatchMatcher:
             "solve_ms": (time.perf_counter() - t_start) * 1e3,
             "truncated_replica_slots": truncated_slots,
             "kernel": kernel_used,  # dense_auction | sparse_topk | native_cpu
-            # True when phase 1 ran the task-sharded mesh kernels (the
-            # use_mesh path actually engaging, not merely requested)
-            "mesh_sharded": self._last_sharded,
+            # True when phase 1's candidates came from the task-sharded
+            # mesh generator this solve (engaged, not merely requested)
             "mesh_gen_sharded": self._last_gen_sharded,
             "warm": warm_used,
             "warm_seeded_slots": warm_seeded,
             # binding-phase stall circuit breaker (ops/sparse.py): True
             # means tail quality fell to greedy cleanup this solve
             "stall_exit": self._last_stall.get("stall_exit", False),
+            # the widths the sparse auction's rounds ran at, summed (its
+            # device cost driver; ops/sparse.py)
+            "frontier_rows": self._last_stall.get("frontier_rows", 0),
             "anti_affinity_assigned": aa_assigned,
             "truncated_aa_slots": self._aa_truncated,
             "group_assignments": len(self._group_assignment),

@@ -298,8 +298,9 @@ async def serve_orchestrator(args) -> None:
             dense_cell_budget=int(
                 os.environ.get("PROTOCOL_TPU_DENSE_CELL_BUDGET", 1 << 24)
             ),
-            # multi-chip pods: solve phase 1 over the device mesh (the
-            # task-sharded eps-ladder/warm kernels, parallel/sparse.py)
+            # multi-chip hosts: phase 1's candidate generation shards
+            # over the device mesh (parallel/sparse.py); the solve runs
+            # on one device
             use_mesh=os.environ.get("PROTOCOL_TPU_USE_MESH", "").lower()
             in ("1", "true", "yes"),
             # stage-A approx_max_k selection (e.g. 0.95); empty = exact

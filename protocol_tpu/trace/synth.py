@@ -2,9 +2,9 @@
 populations for every bench/script/test in this repo, and the trace
 factory behind ``python -m protocol_tpu.trace synth``.
 
-Before the flight recorder, three scripts (bench.py, bench_scaling.py,
-scripts/warm_chain_1m.py) each carried their own inline copy of the
-marketplace generator; numbers measured on "the 16k synthetic fleet"
+Before the flight recorder, three scripts (bench.py, bench_scaling.py
+and a 1M warm-chain script since removed) each carried their own inline
+copy of the marketplace generator; numbers measured on "the 16k synthetic fleet"
 were never provably the SAME fleet. Now the generators live here, and
 :func:`synth_trace` freezes a parameterized workload — churn rate, pool
 growth/shrink via validity headroom, hotspot bursts, mass-disconnect —

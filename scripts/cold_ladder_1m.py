@@ -12,7 +12,8 @@ wall at 65k. This script retires them with MEASURED rows:
               not a claim
   cold 1M     a NativeSolveArena cold solve at the full 1M x 1M shape:
               bucketed vector gen + bounded eps-ladder auction
-              (eps 4.0 -> 1.0, the stageb_1m_smoke convention)
+              (eps 4.0 -> 1.0, the convention of every earlier 1M
+              artifact)
   warm 1M     ONE 1%-churn batch tick on the same arena (the repair
               kernel's transposed pass at shape; zero cold passes)
   stream 1M   single-provider heartbeat events through the
@@ -146,8 +147,8 @@ def main() -> int:
           file=sys.stderr, flush=True)
 
     # eps 4.0 -> 1.0: the bounded cold ladder every prior 1M artifact
-    # used (stageb_1m_smoke, warm_chain_1m) — completeness evidence at
-    # this eps is the smoke's 99.97%
+    # used — completeness evidence at this eps is the 1M smoke's 99.97%
+    # (SCALING.md)
     arena = NativeSolveArena(threads=0, eps_start=4.0, eps_end=1.0,
                              event_max_bids=4096)
     t0 = time.perf_counter()

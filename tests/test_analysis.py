@@ -235,7 +235,7 @@ class TestRealTree:
         assert any("sched/tpu_backend.py" in r for r in rels)
         # the jax engine's sharded builders (nested jitted closures in
         # parallel/sparse.py) are trace roots the closure must reach —
-        # the mesh kernels the JaxSolveArena solves through
+        # the mesh kernels the JaxSolveArena generates and repairs with
         assert any(
             "parallel/sparse.py" in q and ".<locals>." in q
             for q in entries
@@ -286,6 +286,31 @@ class TestRealTree:
         # helpers, or the placement rule (S4) stops meaning anything
         region = sm._sharded_region(sharded)
         assert len(region) > len(sharded)
+
+    def test_the_phase_kernel_has_one_driver(self):
+        """``_sparse_auction_phase`` is named by its host loop
+        (``_phase_adaptive``) and by the one-phase ``assign_auction_sparse``
+        and nowhere else in the program, the scripts or the entry
+        points: the eps ladder, the warm solve and the reverse pass all
+        go through the one loop."""
+        import ast
+
+        from scripts.analysis.callgraph import Index
+
+        idx = Index.build((
+            "protocol_tpu", "scripts", "bench.py", "bench_scaling.py",
+            "chip_smoke.py", "__graft_entry__.py",
+        ))
+        users = set()
+        for qname, info in idx.functions.items():
+            for node in ast.walk(info.node):
+                name = getattr(node, "id", None) or getattr(node, "attr", None)
+                if name == "_sparse_auction_phase":
+                    users.add(qname.split(".<locals>.")[0])
+        assert users == {
+            "protocol_tpu/ops/sparse.py::_phase_adaptive",
+            "protocol_tpu/ops/sparse.py::assign_auction_sparse",
+        }
 
     def test_cli_clean_and_exit_codes(self):
         ok = subprocess.run(
@@ -428,14 +453,13 @@ class TestRealModuleMutations:
 
     def test_renamed_collective_axis_is_caught(self, tmp_path):
         src = (REPO / "protocol_tpu/parallel/sparse.py").read_text()
-        i = src.index("lax.psum(")
-        j = src.index("axis)", i)
-        assert j > i  # first psum passes the threaded axis carrier
+        needle = "lax.axis_index(axis)"  # the threaded axis carrier
+        assert needle in src
         mutated = tmp_path / "parallel_sparse_mutated.py"
-        mutated.write_text(src[:j] + '"q")' + src[j + len("axis)"):])
+        mutated.write_text(src.replace(needle, 'lax.axis_index("q")', 1))
         findings = spmd.run(roots=(str(mutated),))
         assert any(
-            "axis 'q'" in f.message and "psum" in f.message
+            "axis 'q'" in f.message and "axis_index" in f.message
             for f in findings
         ), findings
 

@@ -420,8 +420,8 @@ class TestDeviceInvarianceAndDegradation:
         """Every mesh kernel family stages through ``jax.shard_map``
         itself, with nothing version-shaped in between: a spy on the
         promoted API sees the dense auction, the sharded Sinkhorn and
-        the sparse phase builder each hand it their mesh, and the
-        retired ``parallel/_compat`` seam is gone."""
+        the sharded candidate generator each hand it their mesh, and
+        the retired ``parallel/_compat`` seam is gone."""
         import importlib
 
         from protocol_tpu.parallel import auction, make_mesh, sinkhorn, sparse
@@ -435,11 +435,14 @@ class TestDeviceInvarianceAndDegradation:
 
         monkeypatch.setattr(jax, "shard_map", spy)
         mesh = make_mesh(2)
+        er_treedef = jax.tree.structure(_marketplace(P=16, T=16)[1])
         builders = (
             (auction._build_sharded_dense_auction, (mesh, "p", 0.01, 8)),
             (sinkhorn._build_sharded_sinkhorn,
              (mesh, "p", (1.0, 1.0, 0.001, 0.0), 0.05, 2, 8, 16)),
-            (sparse._build_sharded_phase, (mesh, "p", 16, 8, 8, True)),
+            (sparse._build_sharded_gen,
+             (mesh, "p", dataclasses.astuple(CostWeights()), 16, 8, 8, 8,
+              4, 1, None, er_treedef)),
         )
         try:
             for build, args in builders:

@@ -134,9 +134,14 @@ def chains():
             else:
                 original = sparse._forward_reverse
 
-                def forward_only(run_phase, cand_p, cand_c, n_providers,
-                                 state, eps, stats_out, transposed):
-                    state, stall, _rows = run_phase(state)
+                def forward_only(cand_p, cand_c, n_providers, state, eps,
+                                 max_iters, frontier, stall_limit,
+                                 stats_out, transposed):
+                    state, stall, _rows = sparse._phase_adaptive(
+                        cand_p, cand_c, n_providers, state, eps=eps,
+                        max_iters=max_iters, frontier=frontier, retire=True,
+                        stall_limit=stall_limit, stats_out=stats_out,
+                    )
                     return state, stall, int(state[0])
 
                 sparse._forward_reverse = forward_only

@@ -29,6 +29,15 @@ def encode_random_marketplace(seed, P, T):
     return ep, er
 
 
+def sorted_candidates(cost: np.ndarray, k: int | None = None):
+    """Each task's k cheapest providers of a [P, T] cost matrix, sorted
+    by cost as top-k would give them (-1 where infeasible)."""
+    order = np.argsort(cost, axis=0, kind="stable").T[:, :k]
+    cand_c = np.take_along_axis(cost.T, order, axis=1).astype(np.float32)
+    cand_p = np.where(cand_c < INFEASIBLE * 0.5, order.astype(np.int32), -1)
+    return cand_p, cand_c
+
+
 def jittered_cost(cost: np.ndarray) -> np.ndarray:
     """Replicates the kernel's deterministic tie-breaking jitter."""
     P, T = cost.shape
@@ -128,6 +137,25 @@ class TestSparseAuction:
             np.asarray(res_dense.provider_for_task),
         )
 
+    def test_degenerate_all_equal_costs(self):
+        """All-equal feasible costs: every round is a pure tie-break,
+        and the lowest task index wins each provider. Every top-k window
+        is the same k providers, so the matching caps at k (the coverage
+        phenomenon bidir candidates repair) and goes to tasks 0..k-1."""
+        P = T = 64
+        k = 16
+        cand_p = np.tile(np.arange(k, dtype=np.int32), (T, 1))
+        cand_c = np.full((T, k), 3.0, np.float32)
+        res = assign_auction_sparse(
+            jnp.asarray(cand_p), jnp.asarray(cand_c), num_providers=P,
+            eps=0.05, max_iters=4000, frontier=T, retire=False,
+        )
+        p4t = np.asarray(res.provider_for_task)
+        seated = np.flatnonzero(p4t >= 0)
+        assert seated.size == k
+        assert sorted(p4t[seated]) == list(range(k))  # injective, in the lists
+        np.testing.assert_array_equal(seated, np.arange(k))
+
     @pytest.mark.parametrize("seed", [0, 1])
     def test_restricted_quality(self, seed):
         """k=16 of 64 providers: matching cost within a few % of optimal."""
@@ -184,6 +212,41 @@ class TestScaledAuction:
         )
         p4t = check_feasible(res, cost)
         assert (p4t >= 0).sum() == 16  # every provider seated
+
+
+    @pytest.mark.parametrize("T_real,mult", [(97, 128), (505, 128), (1000, 64)])
+    def test_uneven_tail_padding(self, T_real, mult):
+        """Bucket padding with an uneven real tail (what the arena's pow2
+        buckets rest on): rows padded with -1 / INFEASIBLE never seat,
+        and the real rows get the plan the unpadded solve gives them."""
+        from protocol_tpu.ops.sparse import assign_auction_sparse_scaled
+
+        rng = np.random.default_rng(T_real)
+        P, k = 128, 16
+        T_pad = -(-T_real // mult) * mult
+        cost = random_cost(rng, P, T_real, p_infeasible=0.1)
+        cand_p, cand_c = sorted_candidates(cost, k)
+        pad = T_pad - T_real
+        padded_p = np.concatenate([cand_p, np.full((pad, k), -1, np.int32)])
+        padded_c = np.concatenate(
+            [cand_c, np.full((pad, k), np.float32(INFEASIBLE))]
+        )
+        kw = dict(
+            num_providers=P, eps_start=2.0, eps_end=0.02,
+            max_iters_per_phase=4000,
+        )
+        res_pad = assign_auction_sparse_scaled(
+            jnp.asarray(padded_p), jnp.asarray(padded_c), frontier=T_pad, **kw
+        )
+        res_real = assign_auction_sparse_scaled(
+            jnp.asarray(cand_p), jnp.asarray(cand_c), frontier=T_real, **kw
+        )
+        got = np.asarray(res_pad.provider_for_task)
+        assert not (got[T_real:] >= 0).any(), "padded tail must stay open"
+        # (an injective, feasible plan: so the padded one is, seat for seat)
+        np.testing.assert_array_equal(
+            got[:T_real], check_feasible(res_real, cost)
+        )
 
 
 class TestEndToEndTopk:
@@ -414,8 +477,8 @@ class TestAdaptiveFrontierLadder:
         assert int(stall) >= 600, "accumulated (not per-segment) stall"
 
     def test_quality_parity_with_fixed_frontier(self):
-        """The ladder is a schedule change, not a semantics change: same
-        near-optimal quality as the fixed-frontier path."""
+        """Segments are a schedule change, not a semantics change: the
+        ladder stays within n * eps_end of the exact optimum."""
         from scipy.optimize import linear_sum_assignment
 
         from protocol_tpu.ops.sparse import assign_auction_sparse_scaled
@@ -428,15 +491,70 @@ class TestAdaptiveFrontierLadder:
         cand_p = order.astype(np.int32)
         ri, ci = linear_sum_assignment(cost)
         opt = cost[ri, ci].sum()
-        for ladder in (False, True):
-            res = assign_auction_sparse_scaled(
-                jnp.asarray(cand_p), jnp.asarray(cand_c), num_providers=n,
-                eps_end=0.005, frontier_ladder=ladder,
+        res = assign_auction_sparse_scaled(
+            jnp.asarray(cand_p), jnp.asarray(cand_c), num_providers=n,
+            eps_end=0.005,
+        )
+        p4t = np.asarray(res.provider_for_task)
+        assert (p4t >= 0).all()
+        got = sum(cost[p4t[t], t] for t in range(n))
+        assert got <= opt + n * 0.006, f"{got} vs {opt}"
+
+    @pytest.mark.parametrize("solve", ["scaled", "warm"])
+    def test_stats_out_holds_what_the_metrics_read(self, solve):
+        """The one driver's contract with the per-layer metrics: the
+        cold ladder and the warm solve fill the same keys of
+        ``stats_out`` (the arena forwards them as ``eng_*``)."""
+        from protocol_tpu.ops.sparse import (
+            assign_auction_sparse_scaled,
+            assign_auction_sparse_warm,
+        )
+
+        rng = np.random.default_rng(5)
+        P, T = 96, 64  # idle providers: the reverse pass's keys too
+        cost = rng.uniform(0, 10, size=(P, T)).astype(np.float32)
+        cand_p, cand_c = map(jnp.asarray, sorted_candidates(cost, 16))
+        stats: dict = {}
+        res, price = assign_auction_sparse_scaled(
+            cand_p, cand_c, num_providers=P, with_prices=True, stats_out=stats
+        )
+        if solve == "warm":
+            stats = {}
+            assign_auction_sparse_warm(
+                cand_p, cand_c, num_providers=P, price0=price,
+                p4t0=jnp.asarray(res.provider_for_task).at[:8].set(-1),
+                stats_out=stats,
             )
-            p4t = np.asarray(res.provider_for_task)
-            assert (p4t >= 0).all()
-            got = sum(cost[p4t[t], t] for t in range(n))
-            assert got <= opt + n * 0.006, f"ladder={ladder}: {got} vs {opt}"
+        assert set(stats) == {
+            "rounds_total", "segments", "wait_ms", "frontier_rows",
+            "stall_exit", "stall_rounds", "reverse_rounds", "reverse_ms",
+            "free_repriced",
+        }
+        assert stats["segments"] >= 1 and stats["rounds_total"] >= 1
+        # every round runs at one of the kernel's widths, 32 the least
+        assert stats["frontier_rows"] >= 32 * (
+            stats["rounds_total"] + stats["reverse_rounds"]
+        )
+        assert stats["stall_exit"] is False
+
+    def test_phase_stops_within_a_segment_of_max_iters(self):
+        """The budget is honoured at segment granularity: a phase that
+        never converges (retirement and breaker off) stops at the first
+        segment boundary at or past ``max_iters``."""
+        from protocol_tpu.ops.sparse import _phase_adaptive
+
+        cand_p = jnp.asarray([[0, 1], [0, 1], [0, 1]], jnp.int32)
+        cand_c = jnp.asarray(
+            [[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32
+        )
+        stats: dict = {}
+        state, _stall, rows = _phase_adaptive(
+            cand_p, cand_c, 2, None, eps=0.5, max_iters=300,
+            frontier=4, retire=False, stall_limit=0, stats_out=stats,
+        )
+        assert 300 <= int(state[0]) < 300 + 256
+        assert stats["segments"] == 2
+        assert rows == 3 * int(state[0])  # T = 3 is the only width
 
 
 @partial(jax.jit, static_argnames=("num_providers", "width", "retire"))
