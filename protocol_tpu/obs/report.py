@@ -44,6 +44,10 @@ _PHASE_COLS = (
     ("eng_cand_repair_merge_ms", "cand_rep", True),
     ("eng_cand_repair_rows", "rep_rows", False),
     ("eng_cand_repair_rescans", "rescans", False),
+    # the jax repair's traffic with the device: times a stage waited
+    # for it, and the bytes those reads copied to the host
+    ("rep_syncs", "rep_sync", False),
+    ("rep_readback_bytes", "rep_rb_B", False),
     ("cand_cold_passes", "cold_gen", False),
     ("changed_rows", "dirty", False),
     ("delta_rows", "delta", False),

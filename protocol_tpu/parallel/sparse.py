@@ -315,26 +315,31 @@ def _build_repair_enter(
 
 @lru_cache(maxsize=32)
 def _build_repair_forward(
-    weights_tuple: tuple, Pn: int, kk: int, c_pad: int,
-    ep_treedef, er_rows_treedef,
+    weights_tuple: tuple, Pn: int, kk: int, c_pad: int, tile: int,
+    n_tiles: int, ep_treedef, er_treedef,
 ):
     """Forward row recompute: the exact per-row selection of generation
-    (_forward_tile_select with provider_offset=None) on a GATHERED task
-    subset — full [Pn, c_pad] jittered cost block, stable lax.top_k, the
-    same -1 erasure of infeasible slots. A row's forward list depends on
+    (_forward_tile_select with provider_offset=None) on a task subset
+    GATHERED on the device from the full requirements by ``t_ids`` —
+    full [Pn, c_pad] jittered cost block, stable lax.top_k, the same -1
+    erasure of infeasible slots. A row's forward list depends on
     nothing but its own cost column, so recomputed rows are bit-identical
     to the columns a from-scratch pass would produce regardless of tile
-    or shard placement. Also returns the fresh cost block masked to the
-    DIRTY task columns (_PAD_COST elsewhere) — the orchestrator folds it
-    into the per-(provider, tile) minima that drive the reverse
-    enter-mask."""
+    or shard placement. The cost block never leaves the device: what the
+    reverse enter-mask needs of it, the minimum over the chunk's DIRTY
+    columns of each task tile, is folded into the carried
+    ``min_dirty_tile`` [Pn, n_tiles] here (_PAD_COST where no dirty
+    column has fallen yet). A minimum of f32 values is exact in any
+    order, so the carry after the last chunk is the fold of the whole
+    block bit for bit; pad columns are never dirty."""
     from protocol_tpu.ops.cost import INFEASIBLE, CostWeights, cost_matrix
     from protocol_tpu.ops.cost import tie_jitter_ids
 
     weights = CostWeights(*weights_tuple)
 
     @jax.named_scope("repair.forward_rows")
-    def forward_rows(ep, er_rows, t_ids, col_dirty):
+    def forward_rows(ep, er, t_ids, col_dirty, min_dirty_tile):
+        er_rows = jax.tree.map(lambda a: a[t_ids], er)
         cost, _m = cost_matrix(ep, er_rows, weights)  # [Pn, c_pad]
         jit_grid = tie_jitter_ids(jnp.arange(Pn, dtype=jnp.uint32), t_ids)
         cost = jnp.where(cost < INFEASIBLE * 0.5, cost + jit_grid, cost)
@@ -344,10 +349,18 @@ def _build_repair_forward(
             sel_k < INFEASIBLE * 0.5, idx.astype(jnp.int32), -1
         )
         cost_k = jnp.take_along_axis(cost.T, idx, axis=1)
-        dirty_cost = jnp.where(
-            col_dirty[None, :], cost, jnp.float32(_PAD_COST)
+        # [n_tiles, c_pad]: the dirty columns of each tile
+        in_tile = col_dirty[None, :] & (
+            (t_ids // jnp.uint32(tile))[None, :]
+            == jnp.arange(n_tiles, dtype=jnp.uint32)[:, None]
         )
-        return provider, cost_k, dirty_cost
+        fold = jnp.min(
+            jnp.where(
+                in_tile[:, None, :], cost[None, :, :], jnp.float32(_PAD_COST)
+            ),
+            axis=2,
+        )  # [n_tiles, Pn]
+        return provider, cost_k, jnp.minimum(min_dirty_tile, fold.T)
 
     return jax.jit(forward_rows)
 
@@ -410,25 +423,31 @@ def _build_repair_enter_sharded(
 @lru_cache(maxsize=32)
 def _build_repair_tile(
     weights_tuple: tuple, tile: int, rt: int, s_pad: int,
-    ep_rows_treedef, er_tile_treedef,
+    ep_treedef, er_treedef,
 ):
     """Per-tile reverse CONTRIBUTION recompute: one tile's raw
-    top-``rt`` per gathered provider — the exact per-tile half of the
-    generation fold (same cost ops, same global-id jitter, same
-    argmin/top_k branch), nothing folded. A contribution (p, j) depends
-    on nothing but provider p's own cost row over tile j, so recomputed
-    blocks are bit-identical to the blocks a from-scratch pass emits
-    regardless of batch membership or device count; the fold itself is
-    replayed over the persisted pools by _build_repair_refold. No -1
-    masking here: pools persist raw (infeasible entries keep their
-    INFEASIBLE+jitter cost), matching the gen-side emission."""
+    top-``rt`` per provider of ``p_ids`` — the exact per-tile half of
+    the generation fold (same cost ops, same global-id jitter, same
+    argmin/top_k branch), nothing folded. Provider rows are gathered
+    and the tile's requirements sliced on the device from the full
+    columns, so a call uploads its ids and nothing else. A contribution
+    (p, j) depends on nothing but provider p's own cost row over tile
+    j, so recomputed blocks are bit-identical to the blocks a
+    from-scratch pass emits regardless of batch membership or device
+    count; the fold itself is replayed over the persisted pools by
+    _build_repair_refold. No -1 masking here: pools persist raw
+    (infeasible entries keep their INFEASIBLE+jitter cost), matching
+    the gen-side emission."""
     from protocol_tpu.ops.cost import INFEASIBLE, CostWeights, cost_matrix
     from protocol_tpu.ops.cost import tie_jitter_ids
+    from protocol_tpu.ops.sparse import _slice_requirements
 
     weights = CostWeights(*weights_tuple)
 
     @jax.named_scope("repair.tile_contrib")
-    def tile_contrib(ep_rows, p_ids, er_tile, t0):
+    def tile_contrib(ep, p_ids, er, t0):
+        ep_rows = jax.tree.map(lambda a: a[p_ids], ep)
+        er_tile = _slice_requirements(er, t0, tile)
         cost, _m = cost_matrix(ep_rows, er_tile, weights)  # [s_pad, tile]
         jit_grid = tie_jitter_ids(
             p_ids,
@@ -558,7 +577,10 @@ def repair_topk_bidir_sharded(
     (``repair_rows``, ``repair_providers``, ``repair_blocks``,
     ``visited_cells_frac`` — the fraction of the P*T cost grid
     re-evaluated; the refold and final merge are structure ops both
-    paths pay and are excluded).
+    paths pay and are excluded), the four stage walls, and what the
+    stages cost in traffic with the device: ``rep_syncs`` (times the
+    host waited for a device value: one a stage that ran) and
+    ``rep_readback_bytes`` (bytes those reads copied to the host).
 
     ``pad_floors`` is the pad-bucket ratchet: a mapping of kernel
     family ("enter" / "forward" / "tile") to the largest pow-2 pad that
@@ -614,12 +636,27 @@ def repair_topk_bidir_sharded(
         and (T // mesh.shape[axis]) % tile == 0
     )
 
-    # four host-sequenced stages: each a span closed at the read-back
-    # that already ends it, its wall beside it in the stats
+    # four host-sequenced stages: each dispatches its kernel calls back
+    # to back and is a span closed at the ONE read-back that ends it
+    # (``_read``, the only place the host waits for the device), its
+    # wall beside it in the stats
     took: dict = {}
+    io = {"rep_syncs": 0, "rep_readback_bytes": 0}
+
+    def _read(tree):
+        out = jax.device_get(tree)
+        io["rep_syncs"] += 1
+        io["rep_readback_bytes"] += sum(
+            a.nbytes for a in jax.tree.leaves(out)
+        )
+        return out
 
     # ---- forward scope
     with _tracer.stage("repair.enter_scan", took, "rep_enter_ms"):
+        # one upload of the current columns a tick: the kernels gather
+        # and slice their rows from these on the device
+        ep_full = jax.tree.map(jnp.asarray, ep)
+        er_full = jax.tree.map(jnp.asarray, er)
         rows = np.zeros(T, bool)
         rows[dirty_t] = True
         enter_count = 0
@@ -649,9 +686,9 @@ def repair_topk_bidir_sharded(
                 run = _build_repair_enter(
                     wtuple, tile, n_tiles, dp_pad, ep_treedef, er_treedef,
                 )
-                er_dev = jax.tree.map(jnp.asarray, er)
+                er_dev = er_full
                 thresh = jnp.asarray(fwd_c[:, -1])
-            enter = np.asarray(
+            enter = _read(
                 run(
                     ep_dirty, jnp.asarray(p_ids), jnp.asarray(p_valid),
                     er_dev, thresh,
@@ -662,45 +699,42 @@ def repair_topk_bidir_sharded(
         R = np.flatnonzero(rows)
 
     # ---- forward recompute (chunked at the generation tile's memory
-    # envelope) + per-(provider, tile) dirty-cost minima for the
-    # reverse block enter-mask
+    # envelope); each chunk folds its dirty columns into the per-
+    # (provider, tile) minima for the reverse block enter-mask on the
+    # device. Nothing a chunk needs from the host waits for a device
+    # result, so the chunks queue behind one another.
     with _tracer.stage("repair.forward_rows", took, "rep_forward_ms"):
         fwd_p_new, fwd_c_new = fwd_p, fwd_c
         min_dirty_tile = np.full((Pn, n_tiles), _PAD_COST, np.float32)
-        is_dirty_t = np.zeros(T, bool)
-        is_dirty_t[dirty_t] = True
         if R.size:
             fwd_p_new = fwd_p.copy()
             fwd_c_new = fwd_c.copy()
-            ep_full = jax.tree.map(jnp.asarray, ep)
+            is_dirty_t = np.zeros(T, bool)
+            is_dirty_t[dirty_t] = True
             chunk_cap = min(1024, tile)
-            for lo in range(0, R.size, chunk_cap):
-                chunk = R[lo: lo + chunk_cap]
+            chunks = [
+                R[lo: lo + chunk_cap] for lo in range(0, R.size, chunk_cap)
+            ]
+            lists = []
+            mdt = jnp.asarray(min_dirty_tile)
+            for chunk in chunks:
                 c_pad = _padq("forward", chunk.size)
-                er_rows = _gather_rows(er, chunk, c_pad)
                 t_ids = np.zeros(c_pad, np.uint32)
                 t_ids[: chunk.size] = chunk
                 col_dirty = np.zeros(c_pad, bool)
                 col_dirty[: chunk.size] = is_dirty_t[chunk]
                 run = _build_repair_forward(
-                    wtuple, Pn, kk, c_pad, ep_treedef,
-                    jax.tree.structure(er_rows),
+                    wtuple, Pn, kk, c_pad, tile, n_tiles,
+                    ep_treedef, er_treedef,
                 )
-                prov, cost_k, dc = run(
-                    ep_full, er_rows, jnp.asarray(t_ids),
-                    jnp.asarray(col_dirty),
+                prov, cost_k, mdt = run(
+                    ep_full, er_full, t_ids, col_dirty, mdt
                 )
-                fwd_p_new[chunk] = np.asarray(prov)[: chunk.size]
-                fwd_c_new[chunk] = np.asarray(cost_k)[: chunk.size]
-                if col_dirty.any():
-                    dc = np.asarray(dc)[:, : chunk.size]
-                    tiles_of = chunk // tile
-                    for j in np.unique(tiles_of[is_dirty_t[chunk]]):
-                        sel = tiles_of == j
-                        np.minimum(
-                            min_dirty_tile[:, j], dc[:, sel].min(axis=1),
-                            out=min_dirty_tile[:, j],
-                        )
+                lists.append((prov, cost_k))
+            lists, min_dirty_tile = _read((lists, mdt))
+            for chunk, (prov, cost_k) in zip(chunks, lists):
+                fwd_p_new[chunk] = prov[: chunk.size]
+                fwd_c_new[chunk] = cost_k[: chunk.size]
 
     # ---- reverse scope: flag (provider, tile) contribution blocks
     with _tracer.stage("repair.tile_contrib", took, "rep_tiles_ms"):
@@ -714,32 +748,24 @@ def repair_topk_bidir_sharded(
         blocks = int(flag.sum())
         if blocks:
             s_cap = 4096
+            calls, outs = [], []
             for j in np.flatnonzero(flag.any(axis=0)):
-                er_tile = jax.tree.map(
-                    lambda a: jnp.asarray(
-                        np.asarray(a)[j * tile: (j + 1) * tile]
-                    ), er,
-                )
-                t0 = jnp.uint32(j * tile)
                 sj = np.flatnonzero(flag[:, j])
                 for lo in range(0, sj.size, s_cap):
                     sc = sj[lo: lo + s_cap]
                     s_pad = _padq("tile", sc.size)
-                    ep_rows = _gather_rows(ep, sc, s_pad)
                     p_ids = np.zeros(s_pad, np.uint32)
                     p_ids[: sc.size] = sc
                     run = _build_repair_tile(
-                        wtuple, tile, rt, s_pad,
-                        jax.tree.structure(ep_rows),
-                        jax.tree.structure(er_tile),
+                        wtuple, tile, rt, s_pad, ep_treedef, er_treedef,
                     )
-                    tt, tc = run(ep_rows, jnp.asarray(p_ids), er_tile, t0)
-                    pool_t_np[sc, j * rt: (j + 1) * rt] = (
-                        np.asarray(tt)[: sc.size]
+                    calls.append((sc, j))
+                    outs.append(
+                        run(ep_full, p_ids, er_full, np.uint32(j * tile))
                     )
-                    pool_c_np[sc, j * rt: (j + 1) * rt] = (
-                        np.asarray(tc)[: sc.size]
-                    )
+            for (sc, j), (tt, tc) in zip(calls, _read(outs)):
+                pool_t_np[sc, j * rt: (j + 1) * rt] = tt[: sc.size]
+                pool_c_np[sc, j * rt: (j + 1) * rt] = tc[: sc.size]
 
     # ---- fold replay + auction-visible merge (exact, deterministic:
     # bit-identical parts in => bit-identical merged lists out)
@@ -753,8 +779,7 @@ def repair_topk_bidir_sharded(
             jnp.asarray(fwd_p_new), jnp.asarray(fwd_c_new),
             rev_t, rev_c, extra=extra, scope="repair.merge",
         )
-        cand_p = np.asarray(cand_p, np.int32)
-        cand_c = np.asarray(cand_c, np.float32)
+        cand_p, cand_c = _read((cand_p, cand_c))
     visited = R.size * Pn + blocks * tile + dirty_p.size * T
     stats = {
         "repair_rows": int(R.size),
@@ -764,6 +789,7 @@ def repair_topk_bidir_sharded(
         "visited_cells_frac": round(visited / max(Pn * T, 1), 6),
         "pad_hw": pad_hw,
         **took,
+        **io,
     }
     return (
         cand_p,

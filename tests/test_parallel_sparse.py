@@ -231,3 +231,158 @@ class TestCandidateRepair:
         )
         for g, o in zip(got[:6], oracle):
             np.testing.assert_array_equal(g, o)
+
+    # ---- ISSUE 32: the forward rows' per-tile minima are folded on
+    # the device, and every stage reads back once
+
+    @pytest.mark.parametrize(
+        "chunks",
+        [
+            # (task ids, which of them are dirty, c_pad)
+            pytest.param(
+                [(range(10, 26), [12, 20], 16)], id="spans_two_tiles"),
+            pytest.param(
+                [(range(10, 26), [], 16)], id="no_dirty_column"),
+            pytest.param(
+                [([3, 40, 41, 100], [3, 40, 41, 100], 8)],
+                id="only_dirty_columns"),
+            # pad columns repeat task 0, of tile 0, where task 1 is
+            # dirty: they must not lower tile 0's minimum
+            pytest.param(
+                [([1, 2, 17, 18, 33], [1, 17], 8)], id="padded"),
+            pytest.param(
+                [(range(0, 16), [5], 16), (range(16, 40), [5, 30, 39], 32),
+                 ([41, 127], [127], 8)],
+                id="carried_over_three_chunks"),
+        ],
+    )
+    def test_device_fold_matches_the_numpy_fold(self, chunks):
+        """``_build_repair_forward`` folds the dirty columns of its
+        cost block into ``min_dirty_tile`` on the device; the NumPy
+        fold it replaces, kept here as the reference, read the whole
+        block back and folded it tile by tile. Bit for bit."""
+        import dataclasses
+
+        import jax
+
+        from protocol_tpu.ops.cost import (
+            INFEASIBLE,
+            CostWeights,
+            cost_matrix,
+            tie_jitter_ids,
+        )
+        from protocol_tpu.parallel import sparse as psparse
+
+        P, T, kk, tile = 96, 128, 16, 16
+        n_tiles = T // tile
+        ep, er = self._marketplace(P, T)
+        w = CostWeights()
+
+        @jax.jit
+        def cost_block(er_rows, t_ids):
+            cost, _ = cost_matrix(ep, er_rows, w)
+            grid = tie_jitter_ids(jnp.arange(P, dtype=jnp.uint32), t_ids)
+            return jnp.where(cost < INFEASIBLE * 0.5, cost + grid, cost)
+
+        want = np.full((P, n_tiles), psparse._PAD_COST, np.float32)
+        got = jnp.asarray(want)
+        for ids, dirty, c_pad in chunks:
+            chunk = np.asarray(list(ids), np.int64)
+            is_dirty = np.isin(chunk, dirty)
+            t_ids = np.zeros(c_pad, np.uint32)
+            t_ids[: chunk.size] = chunk
+            col_dirty = np.zeros(c_pad, bool)
+            col_dirty[: chunk.size] = is_dirty
+            run = psparse._build_repair_forward(
+                dataclasses.astuple(w), P, kk, c_pad, tile, n_tiles,
+                jax.tree.structure(ep), jax.tree.structure(er),
+            )
+            _prov, _cost_k, got = run(ep, er, t_ids, col_dirty, got)
+            # the reference: the parent's host fold of the block
+            block = np.asarray(
+                cost_block(psparse._gather_rows(er, chunk, c_pad), t_ids)
+            )
+            dc = np.where(col_dirty[None, :], block, psparse._PAD_COST)
+            dc = dc.astype(np.float32)[:, : chunk.size]
+            tiles_of = chunk // tile
+            for j in np.unique(tiles_of[is_dirty]):
+                np.minimum(
+                    want[:, j], dc[:, tiles_of == j].min(axis=1),
+                    out=want[:, j],
+                )
+        np.testing.assert_array_equal(np.asarray(got), want)
+        touched = {
+            int(t) // tile for _ids, dirty, _pad in chunks for t in dirty
+        }
+        for j in range(n_tiles):
+            if j in touched:
+                assert (want[:, j] < psparse._PAD_COST).all()
+            else:
+                assert (want[:, j] == np.float32(psparse._PAD_COST)).all()
+
+    @pytest.fixture(scope="class")
+    def two_ticks(self):
+        """A large tick (a dozen providers, five tasks) and a small one
+        on top of it, through the repair with its pad ratchet carried
+        over; returns each tick's stats and the jit witness's delta
+        over the second."""
+        from protocol_tpu.ops.cost import CostWeights
+        from protocol_tpu.parallel.sparse import repair_topk_bidir_sharded
+        from protocol_tpu.utils import jitwitness
+
+        P, T, k, tile, r, extra = 96, 256, 16, 16, 8, 8
+        ep, er = self._marketplace(P, T)
+        w = CostWeights()
+        parts = self._full(ep, er, w, None, k, tile, r, extra)[2:]
+        pads: dict = {}
+        out = []
+        for dirty_p, dirty_t in (
+            (list(range(3, 90, 8)), [7, 50, 120, 121, 250]),
+            ([4, 61], [9]),
+        ):
+            ep = self._bump_price(ep, dirty_p)
+            er = self._bump_req(er, dirty_t)
+            before = jitwitness.counts()
+            *_, fwd_p, fwd_c, pool_t, pool_c, stats = (
+                repair_topk_bidir_sharded(
+                    ep, er, w, fwd_p=parts[0], fwd_c=parts[1],
+                    pool_t=parts[2], pool_c=parts[3],
+                    dirty_p=np.asarray(dirty_p, np.int64),
+                    dirty_t=np.asarray(dirty_t, np.int64), reverse_r=r,
+                    mesh=None, tile=tile, extra=extra, pad_floors=pads,
+                )
+            )
+            parts = (fwd_p, fwd_c, pool_t, pool_c)
+            pads = stats["pad_hw"]
+            out.append((stats, jitwitness.delta(before)))
+        oracle = self._full(ep, er, w, None, k, tile, r, extra)
+        for g, o in zip(parts, oracle[2:]):
+            np.testing.assert_array_equal(g, o)
+        return (P, T, k, tile, extra), out
+
+    def test_every_stage_reads_back_once(self, two_ticks):
+        """A tick of several forward chunks and several tiles waits for
+        the device four times, once a stage, and copies back the lists
+        it keeps: never a cost block ([P, chunk] f32 a chunk)."""
+        (P, T, k, tile, extra), ((stats, _), (small, _)) = two_ticks
+        n_chunks = -(-stats["repair_rows"] // tile)
+        assert n_chunks >= 3
+        assert stats["rep_syncs"] == small["rep_syncs"] == 4
+        lists = 2 * 4 * k * stats["repair_rows"]
+        merged = 2 * 4 * T * (k + extra)
+        minima = 4 * P * (T // tile)
+        assert merged < stats["rep_readback_bytes"] < 2 * (
+            lists + merged + minima
+        )
+        assert small["rep_readback_bytes"] < stats["rep_readback_bytes"]
+        # what the parent read back for the fold alone
+        assert stats["rep_readback_bytes"] < n_chunks * 4 * P * tile + merged
+
+    def test_a_smaller_tick_builds_no_program(self, two_ticks):
+        """The pad ratchet covers every compile key of the repair's
+        programs: after a large tick a smaller one traces nothing."""
+        _shape, ((big, _built), (small, again)) = two_ticks
+        assert small["repair_rows"] < big["repair_rows"]
+        assert small["repair_blocks"] < big["repair_blocks"]
+        assert again == {}, again
+        assert small["pad_hw"] == big["pad_hw"]
