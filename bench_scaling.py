@@ -388,7 +388,7 @@ def main() -> None:
         )
     )
     cold_pct = tick_pct()
-    res_cold, price_cold, retired_cold = out_cold
+    res_cold, price_cold, retired_cold, _reserve = out_cold
     # 1% churn: drop a contiguous 1% of the matching (freed providers /
     # re-opened tasks) and re-solve warm from the carried duals — prices
     # AND the retirement mask (the production chain shape; without the

@@ -19,6 +19,15 @@ on; matchings are bit-for-bit either way).
         gap = plan_cost - dual_bound
             = sum_t eps-CS slack(t) + sum_{reachable idle p} pi_p
 
+    Where tasks have to wait (a pool with a queue: more of them wait
+    than the free providers they list could seat), the LP is the one
+    that seats as many tasks as the plan does, whichever they are; its
+    multiplier ``theta`` is the value of waiting, and the bound gains a
+    third addend, ``waiting_excess``: what the waiting tasks' best
+    candidates are worth to them above ``theta``, plus what the seated
+    tasks' are worth below it, at the ``theta`` that makes the sum
+    least. It is 0 when the right tasks wait. The result
+
     is a certificate, not an estimate: the true optimum lies within
     ``gap`` of the plan, whatever the engine did to get there. The
     certificate's dual point caps prices at the give-up magnitude
@@ -82,8 +91,8 @@ def duality_gap(
     -1 = empty slot); ``p4t``: [T] plan (provider per task, -1 =
     unassigned); ``price``: [P] dual prices the engine carried out of
     the solve. Returns plan_cost, dual_bound, gap_total, gap_per_task
-    (gap normalized by assigned count), plus the certificate's two
-    addends (cs_slack, idle_price) for diagnosis.
+    (gap normalized by assigned count), plus the certificate's three
+    addends (cs_slack, idle_price, waiting_excess) for diagnosis.
     """
     cand_p = np.asarray(cand_p)
     cand_c = np.asarray(cand_c)
@@ -107,6 +116,7 @@ def duality_gap(
         return {
             "plan_cost": 0.0, "dual_bound": 0.0, "gap_total": 0.0,
             "gap_per_task": 0.0, "cs_slack": 0.0, "idle_price": 0.0,
+            "waiting_excess": 0.0,
         }
     seat = p4t[rows]
     seat_m = (cand_p[rows] == seat[:, None]) & feas[rows]
@@ -127,11 +137,21 @@ def duality_gap(
     reach[cand_p[rows][fr]] = True
     used = np.zeros(price.shape[0], bool)
     used[seat] = True
+
+    # where tasks have to wait, the LP covers them and the providers
+    # they list too (see :func:`queue_rows`)
+    waiting_excess = 0.0
+    waiting, listed = queue_rows(
+        cand_p, cand_c, p4t, price.shape[0], covered=(rows, reach)
+    )
+    if waiting.size:
+        reach |= listed
+        waiting_excess = _waiting_excess(-seat_adj, -best[waiting])
     idle_price = float(price[reach & ~used].sum())
 
     plan_cost = float(seat_c.sum())
     cs_slack = float(slack.sum())
-    gap_total = cs_slack + idle_price
+    gap_total = cs_slack + idle_price + waiting_excess
     n = int(rows.size)
     return {
         "plan_cost": round(plan_cost, 4),
@@ -140,7 +160,81 @@ def duality_gap(
         "gap_per_task": round(gap_total / max(n, 1), 6),
         "cs_slack": round(cs_slack, 6),
         "idle_price": round(idle_price, 6),
+        "waiting_excess": round(waiting_excess, 6),
     }
+
+
+def queue_rows(
+    cand_p: np.ndarray, cand_c: np.ndarray, p4t: np.ndarray, n_p: int,
+    covered: Optional[tuple[np.ndarray, np.ndarray]] = None,
+) -> tuple[np.ndarray, np.ndarray]:
+    """(the rows of the tasks that wait, a [P] mask of the providers
+    any task lists), where the plan has a queue; (no rows, no
+    providers) where it has none. A queue: more tasks wait than there
+    are free providers on anybody's list, so some task waits whatever
+    the plan, and which ones do is the plan's choice (the solve's own
+    reading of the regime, ``ops/sparse.py:_queue_reserve``, less its
+    margin). A handful of waiting tasks beside as many free providers
+    (a full pool's unseatable tail) is none, and the certificate stays
+    the one over the assigned task set. ``covered``: (rows, the [P]
+    mask of the providers those rows list) where the caller has them
+    (:func:`duality_gap`'s reachable providers), so only the other
+    rows' lists are read: O(T) where nobody waits, O(waiting x K) in a
+    full pool, one O(T*K) scatter without ``covered``."""
+    cand_p = np.asarray(cand_p)
+    cand_c = np.asarray(cand_c)
+    p4t = np.asarray(p4t)
+    none = np.zeros(0, np.intp), np.zeros(n_p, bool)
+    open_rows = np.flatnonzero(p4t < 0)
+    if open_rows.size == 0:
+        return none
+    waiting = open_rows[(
+        (cand_p[open_rows] >= 0) & (cand_c[open_rows] < _INFEASIBLE * 0.5)
+    ).any(axis=1)]
+    if waiting.size == 0:
+        return none
+    if covered is None:
+        listed = np.zeros(n_p, bool)
+        rest_p, rest_c = cand_p, cand_c
+    else:
+        rest = np.ones(p4t.shape[0], bool)
+        rest[covered[0]] = False
+        listed = covered[1].copy()
+        rest_p, rest_c = cand_p[rest], cand_c[rest]
+    listed[rest_p[(rest_p >= 0) & (rest_c < _INFEASIBLE * 0.5)]] = True
+    used = np.zeros(n_p, bool)
+    used[p4t[p4t >= 0]] = True
+    if waiting.size > int((listed & ~used).sum()):
+        return waiting, listed
+    return none
+
+
+def _waiting_excess(seated: np.ndarray, waiting: np.ndarray) -> float:
+    """min over theta of sum(max(0, theta - seated)) + sum(max(0,
+    waiting - theta)), over what its seat is worth to each seated task
+    and its best candidate to each waiting one, at the certificate's
+    prices: the addend for the tasks that wait (the dual of "seat this
+    many tasks, whichever they are" has one multiplier theta, the value
+    of waiting; a seat worth less than theta and a waiting task's best
+    worth more each loosen the bound by the difference). 0 with nobody
+    waiting, and whenever no waiting task's best is worth more than the
+    least seat. The sum is convex and piecewise linear in theta, so its
+    minimum lies at one of the values."""
+    if waiting.size == 0 or seated.size == 0:
+        return 0.0
+    if waiting.max() <= seated.min():
+        return 0.0
+    values = np.concatenate([seated, waiting])
+    order = np.argsort(values, kind="stable")
+    v = values[order]
+    is_seated = order < seated.size
+    # the slope right of v[i]: seated at or under it, less waiting above
+    slope = np.cumsum(is_seated) - (waiting.size - np.cumsum(~is_seated))
+    theta = v[min(int(np.argmax(slope >= 0)), v.size - 1)]
+    return float(
+        np.maximum(theta - seated, 0.0).sum()
+        + np.maximum(waiting - theta, 0.0).sum()
+    )
 
 
 def plan_churn(
@@ -240,16 +334,19 @@ def gap_from_certificate(
     plan_cost: float,
     cs_slack: float,
     idle_price: float,
+    waiting_excess: float = 0.0,
 ) -> dict:
     """Assemble the certified duality gap from the scalars the ENGINE's
     margin pass accumulated (plan cost, eps-CS slack, reachable-idle
-    price — capped-price dual point) — O(1) here instead of re-scanning
+    price, and the waiting tasks' excess where the engine counts a
+    queue — capped-price dual point) — O(1) here instead of re-scanning
     the [T, K] candidate structure. Numerically equal to
     :func:`duality_gap` up to f32 rounding (the tests cross-check the
     two)."""
     p4t = np.asarray(p4t)
     cs_slack = float(cs_slack)
-    gap_total = cs_slack + float(idle_price)
+    waiting_excess = float(waiting_excess)
+    gap_total = cs_slack + float(idle_price) + waiting_excess
     n = int((p4t >= 0).sum())
     return {
         "plan_cost": round(float(plan_cost), 4),
@@ -258,6 +355,7 @@ def gap_from_certificate(
         "gap_per_task": round(gap_total / max(n, 1), 6),
         "cs_slack": round(cs_slack, 6),
         "idle_price": round(float(idle_price), 6),
+        "waiting_excess": round(waiting_excess, 6),
     }
 
 
@@ -280,7 +378,7 @@ def tick_quality(
     When the engine's certificate scalars (``plan_cost`` /
     ``cs_slack`` / ``idle_price`` in ``eng``) are in hand the gap is
     assembled in O(1) from them; otherwise the O(T*K) reference
-    :func:`duality_gap` scan runs (the jax replay path, tests).
+    :func:`duality_gap` scan runs (the jax path, tests).
     """
     stats: dict = {}
     have_cert = (
@@ -292,7 +390,18 @@ def tick_quality(
     if have_cert:
         stats.update(gap_from_certificate(
             p4t, eng["plan_cost"], eng["cs_slack"], eng["idle_price"],
+            eng.get("waiting_excess", 0.0),
         ))
+        # an engine whose margin pass does not count a queue: where the
+        # plan has one, the scan's certificate takes the place of the
+        # engine's (O(T*K) there, O(waiting x K) to find out)
+        if (
+            price is not None and "waiting_excess" not in eng
+            and queue_rows(
+                cand_p, cand_c, p4t, np.asarray(price).shape[0]
+            )[0].size
+        ):
+            stats.update(duality_gap(cand_p, cand_c, p4t, price))
     elif price is not None:
         stats.update(duality_gap(cand_p, cand_c, p4t, price))
     if prev_p4t is not None and np.asarray(prev_p4t).shape == np.asarray(

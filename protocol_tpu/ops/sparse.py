@@ -584,10 +584,15 @@ def _sparse_auction_phase(
 
 @jax.jit
 @jax.named_scope("auction.unassign_unhappy")
-def _unassign_unhappy(cand_provider, cand_cost, price, owner, p4t, eps_next):
+def _unassign_unhappy(
+    cand_provider, cand_cost, price, owner, p4t, eps_next, reserve=None
+):
     """eps-CS repair between phases: holders whose assignment violates the
     tighter eps re-enter the auction; happy holders stay seated (avoids both
     full-reset cost and the mass-retirement pathology of pumped prices).
+    In a pool with a queue (``reserve``, see :func:`_queue_phase`) waiting
+    is an option like any other: a holder whose seat is worth less to it
+    than the reserve leaves it too.
 
     The comparison carries a float-dust tolerance: a winning bid lands a
     task EXACTLY at the eps-CS boundary (its new value is v2 - eps, and v2
@@ -601,6 +606,8 @@ def _unassign_unhappy(cand_provider, cand_cost, price, owner, p4t, eps_next):
     cand_safe = jnp.where(cand_valid, cand_provider, 0)
     value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)  # [T,K]
     v1 = jnp.max(value, axis=1)
+    if reserve is not None:
+        v1 = jnp.maximum(v1, reserve)
     held = p4t  # [T]
     vcur = jnp.max(
         jnp.where(cand_safe == jnp.maximum(held, 0)[:, None], value, _NEG), axis=1
@@ -687,6 +694,19 @@ def _stranded(cand_provider, price, owner, p4t):
     ])
 
 
+def _task_values(cand_provider, cand_cost, price, p4t):
+    """(what each candidate is worth to its task at ``price`` [T, K],
+    what its seat is [T]; ``_NEG`` for an empty slot and for a task
+    without a seat on its list)."""
+    cand_valid = cand_provider >= 0
+    cand_safe = jnp.where(cand_valid, cand_provider, 0)
+    value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)
+    seat = jnp.max(
+        jnp.where(cand_safe == jnp.maximum(p4t, 0)[:, None], value, _NEG), axis=1
+    )
+    return value, seat
+
+
 @jax.jit
 @jax.named_scope("auction.reverse")
 def _reverse_seed(cand_provider, cand_cost, price, owner, p4t):
@@ -697,12 +717,7 @@ def _reverse_seed(cand_provider, cand_cost, price, owner, p4t):
     ``-cost - profit``, is the price at which that task would just take
     it. Free providers at the floor do not bid (no seated task prefers
     one by more than eps, or the forward phase would not have ended)."""
-    cand_valid = cand_provider >= 0
-    cand_safe = jnp.where(cand_valid, cand_provider, 0)
-    value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)
-    seat = jnp.max(
-        jnp.where(cand_safe == jnp.maximum(p4t, 0)[:, None], value, _NEG), axis=1
-    )
+    value, seat = _task_values(cand_provider, cand_cost, price, p4t)
     profit = jnp.where(p4t >= 0, seat, jnp.max(value, axis=1))
     floor, stranded, _ = _stranded(cand_provider, price, owner, p4t)
     return (jnp.int32(0), profit, p4t, owner, (owner < 0) & ~stranded), floor
@@ -743,9 +758,11 @@ def _reverse_finish(cand_provider, cand_cost, price, profit0, rstate, floor):
 def _forward_reverse(
     cand_provider, cand_cost, num_providers: int, state, eps,
     max_iters: int, frontier: int, stall_limit: int,
-    stats_out: dict | None, transposed: list,
+    stats_out: dict | None, transposed: list, reserve: float | None = None,
 ):
-    """One eps phase to the condition a pool with free providers needs.
+    """One eps phase to the condition the pool's regime needs: with
+    ``reserve`` (a pool with a queue) :func:`_queue_phase`'s; without,
+    the condition a pool with free providers needs.
 
     With fewer tasks than providers a forward auction is eps-optimal
     only if no provider it leaves free is priced above a seated one
@@ -772,6 +789,11 @@ def _forward_reverse(
     ``frontier_rows`` (the widths every round of the phase ran at, both
     directions, summed).
     Returns (state, stall, rounds of the forward phase)."""
+    if reserve is not None:
+        return _queue_phase(
+            cand_provider, cand_cost, num_providers, state, eps, reserve,
+            max_iters, frontier, stall_limit, stats_out, transposed,
+        )
     state, stall, rows = _phase_adaptive(
         cand_provider, cand_cost, num_providers, state,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
@@ -820,6 +842,179 @@ def _forward_reverse(
             ("reverse_rounds", reverse_rounds),
             ("reverse_ms", (time.perf_counter() - t0) * 1e3),
             ("frontier_rows", rows + reverse_rows),
+        ):
+            stats_out[key] = round(stats_out.get(key, 0) + value, 3)
+    return state, stall, rounds
+
+
+# A pool has a queue where the tasks that list a provider outnumber the
+# providers any task lists by at least one in ``_QUEUE_SHARE`` of those
+# providers. Under that the queue pass does no better than the forward
+# auction's give-up level, which decides there as it always has: with a
+# handful waiting, only the seats within their reach are priced against
+# the reserve, and the chains that would carry it to the rest outlast
+# the stall breaker (1,024 providers on the CPU: 10 waiting, 0.034-0.046
+# a seated task off the optimum with the pass and 0.027-0.041 without;
+# 21 waiting, 0.0002-0.018; 51, under 0.004). A full pool's unseatable
+# tail is no queue by this count at all: the served one lists every
+# provider and every task, 8,192 of each, on every tick.
+_QUEUE_SHARE = _SLACK_SHARE
+
+
+def _queue_reserve(cand_provider, cand_cost, num_providers: int,
+                   reserve0: float | None, stats_out: dict | None):
+    """The reserve of a pool with a queue, or None where the pool has
+    none. The regime is the candidate graph's, counted on the host
+    (tasks that list a provider against providers some task lists: the
+    arena and the matcher hold the lists as NumPy, so no program runs
+    and nothing is read back; a device array is copied over first), an
+    ``auction.queue`` span.
+
+    The reserve is the value of waiting: a task bids for a seat only
+    while the seat is worth more to it, and a provider takes a waiting
+    task at any price that leaves the task that much. It is a level,
+    not a quantity the solve searches for: prices find their place
+    against it, so what a solve anchors (at the forward auction's own
+    give-up level, ``-(2 max cost + 10)``, which keeps every price at
+    or above 0) the next one carries (``reserve0``), unless the costs
+    have moved so far that the anchor no longer holds them."""
+    t0 = time.perf_counter()
+    P = num_providers
+    with _tracer.span("auction.queue", step="regime") as sp:
+        ids = np.asarray(cand_provider)
+        listed = ids >= 0
+        seatable = int(np.count_nonzero(listed.any(axis=1)))
+        reach = np.zeros(P + 1, bool)  # an empty slot's -1 lands on [P]
+        reach[ids.ravel()] = True
+        reach = int(np.count_nonzero(reach[:P]))
+        queued = seatable - reach
+        if sp is not None:
+            sp["attrs"].update(seatable=seatable, listed=reach)
+    if stats_out is not None:
+        stats_out["queue_ms"] = round(
+            stats_out.get("queue_ms", 0.0)
+            + (time.perf_counter() - t0) * 1e3, 3
+        )
+        stats_out.setdefault("queue_rounds", 0)
+    if queued <= 0 or queued * _QUEUE_SHARE < reach:
+        return None
+    finite_max = float(np.max(np.asarray(cand_cost), where=listed, initial=0.0))
+    if reserve0 is not None and (
+        1.5 * finite_max + 5.0 <= -reserve0 <= 4.0 * finite_max + 20.0
+    ):
+        return float(reserve0)
+    return -(2.0 * finite_max + 10.0)
+
+
+@partial(jax.jit, static_argnames=("num_providers",))
+@jax.named_scope("auction.queue")
+def _queue_free(cand_provider, owner, num_providers: int):
+    """Free providers that some task lists: the ones a phase's queue
+    pass has to seat."""
+    P = num_providers
+    reach = jnp.zeros(P + 1, jnp.int32).at[
+        jnp.where(cand_provider >= 0, cand_provider, P).ravel()
+    ].add(1)[:P] > 0
+    return jnp.sum(reach & (owner < 0), dtype=jnp.int32)
+
+
+@jax.jit
+@jax.named_scope("auction.queue")
+def _queue_seed(cand_provider, cand_cost, price, owner, p4t, reserve):
+    """State of the queue pass, in :func:`_sparse_auction_phase`'s
+    layout with the roles swapped as in :func:`_reverse_seed`: a seated
+    task's "price" is the value of its seat, a waiting task's the
+    reserve (or its best candidate's value, where that is more: such a
+    task bids for itself in the forward phase that follows). Every free
+    provider bids."""
+    value, seat = _task_values(cand_provider, cand_cost, price, p4t)
+    profit = jnp.where(
+        p4t >= 0, seat, jnp.maximum(jnp.max(value, axis=1), reserve)
+    )
+    return (jnp.int32(0), profit, p4t, owner, jnp.zeros(owner.shape, bool))
+
+
+def _queue_phase(
+    cand_provider, cand_cost, num_providers: int, state, eps, reserve: float,
+    max_iters: int, frontier: int, stall_limit: int,
+    stats_out: dict | None, transposed: list,
+):
+    """One eps phase of a pool with a queue: more tasks than seats.
+
+    A forward auction cannot end there: the tasks that must wait keep
+    bidding until every price has climbed to the give-up level, eps at
+    a time. The asymmetric problem's condition (Bertsekas and Castanon
+    1992; the mirror of :func:`_forward_reverse`'s, with the providers
+    the short side) is: every provider a task can use is seated, the
+    seated are eps-optimal among themselves, and no waiting task values
+    any seat above ``reserve``, which every seated task's seat is worth
+    to it. Two steps reach it, both the one phase kernel:
+
+    1. *The queue pass.* The free providers bid for tasks over the
+       transposed graph, each lowering its price to where its
+       second-best taker would just take it and taking the best one; a
+       waiting task takes any price that leaves it ``reserve``. A seated
+       task that is bid for changes provider and the old one bids in
+       turn; no task loses a seat, and since tasks outnumber providers
+       every chain ends at the queue. A provider nobody takes at price
+       0 stays free there.
+    2. *The forward phase under the reserve.* The open tasks bid as
+       ever, but none pays more than leaves it ``reserve``, and one
+       whose best seat is not worth eps more than that waits. Bids evict
+       and never vacate, so step 1 need not run again; prices are
+       bounded by the reserve, so the phase ends by itself, and the
+       tasks it leaves open are the ones that wait.
+
+    Step 1 is ``auction.queue`` spans (``check``, closed at the pass's
+    one read of a scalar, then ``seed``, the segments and ``finish``);
+    ``stats_out`` gains ``queue_rounds`` and ``queue_ms``. Returns
+    (state, stall, rounds of the forward phase), as
+    :func:`_forward_reverse` does."""
+    it, price, owner, p4t, _retired = state
+    T = p4t.shape[0]
+    t0 = time.perf_counter()
+    with _tracer.span("auction.queue", eps=eps, step="check") as sp:
+        n_free = int(_queue_free(cand_provider, owner, num_providers))
+        if sp is not None:
+            sp["attrs"].update(free=n_free)
+    queue_rounds = queue_rows = 0
+    if n_free > 0:
+        floor = jnp.float32(0.0)
+        with _tracer.span("auction.queue", step="seed", dispatch_only=True):
+            if not transposed:
+                transposed.extend(_transpose_candidates(
+                    cand_provider, cand_cost, num_providers, _REVERSE_WIDTH
+                ))
+            rstate = _queue_seed(
+                cand_provider, cand_cost, price, owner, p4t,
+                jnp.float32(reserve),
+            )
+        profit0 = rstate[1]
+        rstate, _, queue_rows = _phase_adaptive(
+            transposed[0], transposed[1], T, rstate,
+            eps=eps, max_iters=20000, frontier=num_providers, retire=True,
+            stall_limit=0, reserve=floor, span="auction.queue",
+        )
+        queue_rounds = int(rstate[0])
+        with _tracer.span("auction.queue", step="finish", dispatch_only=True):
+            price, owner, p4t, _ = _reverse_finish(
+                cand_provider, cand_cost, price, profit0, rstate, floor
+            )
+    queue_ms = (time.perf_counter() - t0) * 1e3
+    # who waits is decided anew under this phase's prices
+    state = (it, price, owner, p4t, jnp.zeros(T, bool))
+    state, stall, rows = _phase_adaptive(
+        cand_provider, cand_cost, num_providers, state,
+        eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
+        stall_limit=stall_limit, stats_out=stats_out,
+        reserve=jnp.float32(reserve),
+    )
+    rounds = int(state[0]) if stats_out is not None else 0
+    if stats_out is not None:
+        for key, value in (
+            ("free_repriced", 0), ("reverse_rounds", 0), ("reverse_ms", 0.0),
+            ("queue_rounds", queue_rounds), ("queue_ms", queue_ms),
+            ("frontier_rows", rows + queue_rows),
         ):
             stats_out[key] = round(stats_out.get(key, 0) + value, 3)
     return state, stall, rounds
@@ -936,6 +1131,23 @@ def assign_auction_sparse_scaled(
     tail-heavy 2048).
     """
     state = None
+    # (lists held on the host go up while the host counts them)
+    lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    reserve = _queue_reserve(
+        cand_provider, cand_cost, num_providers, None, stats_out
+    )
+    cand_provider, cand_cost = lists
+    if reserve is not None:
+        # a pool with a queue opens with every seat priced out of
+        # everyone's reach, and the providers bid their way down
+        T = cand_cost.shape[0]
+        state = (
+            jnp.int32(0),
+            jnp.full(num_providers, -reserve, jnp.float32),
+            jnp.full(num_providers, -1, jnp.int32),
+            jnp.full(T, -1, jnp.int32),
+            jnp.zeros(T, bool),
+        )
     eps = eps_start
     rounds_total = 0
     transposed: list = []
@@ -949,7 +1161,7 @@ def assign_auction_sparse_scaled(
             # make no net progress for long stretches — give it 8x the
             # circuit-breaker budget of the disposable coarse phases
             stall_limit=stall_limit * (8 if final else 1),
-            stats_out=stats_out, transposed=transposed,
+            stats_out=stats_out, transposed=transposed, reserve=reserve,
         )
         # per-phase round count (read back only when asked for)
         rounds_total += rounds
@@ -965,7 +1177,7 @@ def assign_auction_sparse_scaled(
         it, price, owner, p4t, retired = state
         with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
             owner, p4t = _unassign_unhappy(
-                cand_provider, cand_cost, price, owner, p4t, eps
+                cand_provider, cand_cost, price, owner, p4t, eps, reserve
             )
             # un-retire: coarse-phase retirement was only the circuit
             # breaker
@@ -974,12 +1186,15 @@ def assign_auction_sparse_scaled(
 
     _, price, owner, p4t, retired = state
     with _tracer.span("auction.cleanup"):
-        p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+        # (a pool with a queue has nobody to sweep: a provider its
+        # queue pass leaves free is one no waiting task lists)
+        if reserve is None:
+            p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
         # a retired task the greedy cleanup managed to seat is assigned,
         # not priced out — clear its flag in the carried state
-        return res, price, retired & (p4t < 0)
+        return res, price, retired & (p4t < 0), reserve
     if with_prices:
         return res, price
     return res
@@ -1119,6 +1334,7 @@ def assign_auction_sparse_warm(
     stats_out: dict | None = None,
     retired0: jax.Array | None = None,
     with_state: bool = False,
+    reserve0: float | None = None,
 ) -> tuple[AssignResult, jax.Array]:
     """Incremental (delta-frontier) auction solve: SURVEY §7 hard part 4.
 
@@ -1150,9 +1366,39 @@ def assign_auction_sparse_warm(
     rebuild does this wholesale). Retired-but-now-seatable pairs are still
     caught by the greedy cleanup, which ignores the mask.
 
+    ``reserve0`` carries the previous solve's reserve, where that pool
+    had a queue (fourth element of a ``with_state=True`` return, else
+    None). The regime is read off the candidate graph at every solve
+    (:func:`_queue_reserve`). A pool that has a queue now and carried
+    none (it had no queue a tick ago, or its costs have left the
+    anchor) has duals of another regime: the solve re-grounds them with
+    the cold ladder, once. In a pool with a queue prices are held
+    between 0 and the reserve's level by the bidding itself, so the
+    uniform shift below is left out, and "retired" means "waits at
+    these prices", which every solve decides anew; a mask carried out of
+    a queue is dropped where the pool has none any more.
+
     Returns (AssignResult, final prices [P]), plus the final retirement
-    mask [T] when ``with_state=True``.
+    mask [T] and the reserve (None without a queue) when
+    ``with_state=True``.
     """
+    # (lists held on the host go up while the host counts them)
+    lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    reserve = _queue_reserve(
+        cand_provider, cand_cost, num_providers, reserve0, stats_out
+    )
+    if reserve is not None and reserve != reserve0:
+        # (the ladder counts the lists again, on the host: once, at the
+        # tick a pool enters the regime)
+        out = assign_auction_sparse_scaled(
+            cand_provider, cand_cost, num_providers, eps_end=eps,
+            frontier=frontier, stall_limit=stall_limit,
+            stats_out=stats_out, with_state=True,
+        )
+        return out if with_state else out[:2]
+    if reserve is None and reserve0 is not None:
+        retired0 = None
+    cand_provider, cand_cost = lists
     # a seed for a task with NO candidates would sail through the eps-CS
     # repair (vcur == v1 == -inf is not "unhappy") and emerge as an
     # infeasible pair in the final matching — drop such seeds outright
@@ -1175,11 +1421,12 @@ def assign_auction_sparse_warm(
     with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
         finite_max = jnp.max(jnp.where(cand_provider >= 0, cand_cost, 0.0))
         price0 = jnp.asarray(price0, jnp.float32)
-        shift = jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
-        price0 = price0 - shift
+        if reserve is None:
+            shift = jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
+            price0 = price0 - shift
         owner0 = _invert(p4t0, num_providers)
         owner0, p4t0 = _unassign_unhappy(
-            cand_provider, cand_cost, price0, owner0, p4t0, eps
+            cand_provider, cand_cost, price0, owner0, p4t0, eps, reserve
         )
         if retired0 is None:
             retired_seed = jnp.zeros(cand_cost.shape[0], bool)
@@ -1201,7 +1448,7 @@ def assign_auction_sparse_warm(
         # assign_auction_sparse_scaled); stall_limit=0 opts out (run to
         # max_iters)
         stall_limit=stall_limit * 8,
-        stats_out=stats_out, transposed=[],
+        stats_out=stats_out, transposed=[], reserve=reserve,
     )
     _report_stall("warm", stall, stall_limit * 8, stats_out)
     if stats_out is not None:
@@ -1210,10 +1457,13 @@ def assign_auction_sparse_warm(
         stats_out["rounds_total"] = rounds
     _, price, owner, p4t, retired = state
     with _tracer.span("auction.cleanup"):
-        p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+        # (a pool with a queue has nobody to sweep: a provider its
+        # queue pass leaves free is one no waiting task lists)
+        if reserve is None:
+            p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
-        return res, price, retired & (p4t < 0)
+        return res, price, retired & (p4t < 0), reserve
     return res, price
 
 

@@ -206,6 +206,7 @@ class JaxSolveArena:
         self._warm_solves = 0
         self._dual_age = 0
         self._starve_age: Optional[np.ndarray] = None
+        self._reserve: Optional[float] = None
         self._last_quality: dict = {}
         self.last_repair_mask: Optional[np.ndarray] = None
         self._owned_cols: set = set()
@@ -215,7 +216,7 @@ class JaxSolveArena:
     # the exported arrays a solve writes; every other entry of
     # :meth:`export_state` is final for the tick once its
     # ``arena.candidates`` span has closed
-    SOLVE_STATE = ("price", "retired", "p4t", "starve_age")
+    SOLVE_STATE = ("price", "retired", "p4t", "starve_age", "queue_reserve")
 
     def live_state(self) -> dict:
         """:meth:`export_state`'s entries as the LIVE objects, no
@@ -237,6 +238,13 @@ class JaxSolveArena:
             "retired": self._retired,
             "p4t": self._p4t,
             "starve_age": self._starve_age,
+            # the reserve of a pool with a queue (ops/sparse.py:
+            # _queue_reserve), NaN where the pool has none: one f32
+            # whatever the regime, so the journal's layout never moves
+            "queue_reserve": np.array(
+                [np.nan if self._reserve is None else self._reserve],
+                np.float32,
+            ),
             "warm_solves": int(self._warm_solves),
             "dual_age": int(self._dual_age),
             "weights_key": tuple(self._weights_key),
@@ -341,6 +349,11 @@ class JaxSolveArena:
                 self, f"_{name}",
                 None if v is None else np.array(v, copy=True),
             )
+        reserve = state.get("queue_reserve")
+        self._reserve = (
+            None if reserve is None or np.isnan(reserve[0])
+            else float(reserve[0])
+        )
         self._warm_solves = int(state["warm_solves"])
         self._dual_age = int(state["dual_age"])
         self._weights_key = tuple(state["weights_key"])
@@ -574,8 +587,8 @@ class JaxSolveArena:
     def _ladder(self, P: int, eng: Optional[dict]):
         """Cold/refresh solve stage: the eps-annealed auction ladder
         from scratch duals over the CURRENT candidate structure."""
-        res, price, retired = assign_auction_sparse_scaled(
-            jnp.asarray(self._cand_p), jnp.asarray(self._cand_c),
+        res, price, retired, self._reserve = assign_auction_sparse_scaled(
+            self._cand_p, self._cand_c,
             num_providers=P, eps_start=self.eps_start,
             eps_end=self.eps_end, stats_out=eng, with_state=True,
         )
@@ -610,28 +623,35 @@ class JaxSolveArena:
         warm kernel's documented caller contract; the kernel itself
         applies the uniform price downshift that keeps carried prices
         sound."""
-        res, price, retired = assign_auction_sparse_warm(
-            jnp.asarray(self._cand_p), jnp.asarray(self._cand_c),
+        res, price, retired, self._reserve = assign_auction_sparse_warm(
+            self._cand_p, self._cand_c,
             num_providers=P,
             price0=jnp.asarray(self._price),
             p4t0=jnp.asarray(p4t0),
             eps=self.eps_end,
             retired0=jnp.asarray(self._retired & ~changed),
-            stats_out=eng, with_state=True,
+            stats_out=eng, with_state=True, reserve0=self._reserve,
         )
         return self._readback(res, price, retired, eng)
 
     @staticmethod
-    def _count_free(pf: dict, p4t: np.ndarray, eng: Optional[dict]) -> None:
+    def _count_free(
+        pf: dict, rf: dict, p4t: np.ndarray, eng: Optional[dict]
+    ) -> None:
         """``eng["free_providers"]``: live providers the plan leaves
         free, the ones the solve's reverse pass answers for (its
         ``free_repriced`` / ``reverse_rounds`` / ``reverse_ms`` ride
-        ``eng`` beside it)."""
+        ``eng`` beside it). ``eng["waiting_tasks"]``: live tasks it
+        leaves without a provider, the queue pass's (``queue_rounds``,
+        ``queue_ms``)."""
         if eng is not None:
             used = np.zeros(pf["valid"].shape[0], bool)
             used[p4t[p4t >= 0]] = True
             eng["free_providers"] = int(
                 (pf["valid"].astype(bool) & ~used).sum()
+            )
+            eng["waiting_tasks"] = int(
+                (rf["valid"].astype(bool) & (p4t < 0)).sum()
             )
 
     def _quality_pass(
@@ -680,7 +700,7 @@ class JaxSolveArena:
         with _tracer.span("arena.engine", engine="jax", cold=True):
             p4t, price, retired = self._ladder(P, eng)
         t_solve = time.perf_counter()
-        self._count_free(pf, p4t, eng)
+        self._count_free(pf, rf, p4t, eng)
         self._p_fields, self._r_fields = pf, rf
         self._owned_cols = set()
         self._weights_key = self._wkey(weights)
@@ -978,7 +998,7 @@ class JaxSolveArena:
                 )
                 self._dual_age += 1
         t_solve = time.perf_counter()
-        self._count_free(pf, p4t, eng)
+        self._count_free(pf, rf, p4t, eng)
         self._price, self._retired, self._p4t = price, retired, p4t
         self._warm_solves += 1
         qual = (

@@ -233,6 +233,7 @@ class TpuBatchMatcher:
         # layout it was computed under (see _solve_slots_cached)
         self._warm_retired: np.ndarray | None = None
         self._warm_retired_fp: tuple | None = None
+        self._warm_reserve: float | None = None
         # claim-masked slot rows (anti-affinity/colocation) of the current
         # and previous solve: both dirty the carried retirement mask
         self._claim_rows_now: np.ndarray | None = None
@@ -582,23 +583,25 @@ class TpuBatchMatcher:
             gen is not None and self._cand_memo.misses > misses_before
         )
         num_providers = int(np.asarray(ep.gpu_count).shape[0])
-        res, price, _retired = self._sparse_solve(
+        res, price, _retired, self._warm_reserve = self._sparse_solve(
             cand_p, cand_c, num_providers, warm,
             jnp.asarray(price0), jnp.asarray(p4s0),
+            reserve0=self._warm_reserve if warm else None,
         )
         return np.asarray(res.task_for_provider), np.asarray(price)
 
     def _sparse_solve(self, cand_p, cand_c, num_providers, warm, price0, p4t0,
-                      stats_out=None, retired0=None):
+                      stats_out=None, retired0=None, reserve0=None):
         """Phase 1's solve dispatch: warm solve vs cold ladder. Always
-        returns (result, prices, retired) — the full dual state, so
-        chained warm solves can skip re-fighting priced-out slots
-        (ops/sparse.py: retirement carry)."""
+        returns (result, prices, retired, reserve) — the full dual
+        state, so chained warm solves can skip re-fighting priced-out
+        slots (ops/sparse.py: retirement carry) and keep the anchor of
+        a pool with a queue."""
         if warm:
             return assign_auction_sparse_warm(
                 cand_p, cand_c, num_providers,
                 price0=price0, p4t0=p4t0, stats_out=stats_out,
-                retired0=retired0, with_state=True,
+                retired0=retired0, with_state=True, reserve0=reserve0,
             )
         return assign_auction_sparse_scaled(
             cand_p, cand_c, num_providers, stats_out=stats_out,
@@ -1046,8 +1049,9 @@ class TpuBatchMatcher:
             p4s0, prepared.row_of_addr, tasks, bounded, slot_range
         )
         warm = self._warm_gate(seeded, rebuilt=prepared.rebuilt)
-        cand_p = jnp.asarray(prepared.cand_p)
-        cand_c = jnp.asarray(prepared.cand_c)
+        # (as NumPy: the solve reads the pool's regime off the lists on
+        # the host before it uploads them)
+        cand_p, cand_c = prepared.cand_p, prepared.cand_c
         # retirement carry: valid only while the slot layout (task ids ->
         # slot ranges) and the cached candidate structure are unchanged —
         # any rebuild or task churn invalidates the mask (slots renumber)
@@ -1089,10 +1093,11 @@ class TpuBatchMatcher:
                 retired0 = jnp.asarray(carried)
         self._claim_rows_prev = self._claim_rows_now
         stall_stats: dict = {}
-        res, price, retired = self._sparse_solve(
+        res, price, retired, self._warm_reserve = self._sparse_solve(
             cand_p, cand_c, prepared.p_bucket, warm,
             jnp.asarray(prepared.price0), jnp.asarray(p4s0),
             stats_out=stall_stats, retired0=retired0,
+            reserve0=self._warm_reserve if warm else None,
         )
         self._cache.store_prices(np.asarray(price))
         self._warm_retired = np.asarray(retired)

@@ -44,7 +44,7 @@ from typing import Optional
 
 import numpy as np
 
-from protocol_tpu.obs.quality import _INFEASIBLE
+from protocol_tpu.obs.quality import _INFEASIBLE, _waiting_excess, queue_rows
 
 
 class GapTracker:
@@ -58,6 +58,7 @@ class GapTracker:
         self._slack: Optional[np.ndarray] = None  # f64 [T]
         self._p4t: Optional[np.ndarray] = None  # i32 [T] copy
         self._price: Optional[np.ndarray] = None  # f64 [P] capped copy
+        self._waiting: Optional[np.ndarray] = None  # queue rows, or none
 
     @property
     def primed(self) -> bool:
@@ -122,6 +123,9 @@ class GapTracker:
         # certificate covers exactly the assigned task set
         self._slack[self._seat_adj == 0.0] = 0.0
         self._p4t = p4t.copy()
+        self._waiting = queue_rows(
+            cand_p, cand_c, p4t, self._price.shape[0]
+        )[0]
         return self._report()
 
     def update(
@@ -167,6 +171,7 @@ class GapTracker:
             self._slack[rows] = slack
         self._p4t = p4t.copy()
         self._price = price_c
+        self._waiting = queue_rows(cand_p, cand_c, p4t, price_c.shape[0])[0]
         return self._report()
 
     def _report(self) -> dict:
@@ -177,7 +182,16 @@ class GapTracker:
         idle_price = float(self._price[~used & (self._price > 0)].sum())
         cs_slack = float(self._slack.sum())
         plan_cost = float(self._seat_c.sum())
-        gap_total = cs_slack + idle_price
+        # a plan with a queue (obs.quality.queue_rows): the waiting
+        # tasks' addend, over every seat's exact value and the waiting
+        # rows' bests (stale ones are from lower prices, so no smaller)
+        waiting_excess = 0.0
+        if self._waiting.size:
+            seats = self._seat_adj[self._seat_adj != 0.0]
+            waiting_excess = _waiting_excess(
+                -seats, -self._best[self._waiting]
+            )
+        gap_total = cs_slack + idle_price + waiting_excess
         n = int((p4t >= 0).sum())
         return {
             "plan_cost": round(plan_cost, 4),
@@ -186,5 +200,6 @@ class GapTracker:
             "gap_per_task": round(gap_total / max(n, 1), 6),
             "cs_slack": round(cs_slack, 6),
             "idle_price": round(idle_price, 6),
+            "waiting_excess": round(waiting_excess, 6),
             "incremental": True,
         }

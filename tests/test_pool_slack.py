@@ -115,7 +115,7 @@ def _chain(n_providers: int, n_tasks: int, blocked: int = 0,
             "dup": int(seated.size - np.unique(plan[seated]).size),
             "infeasible": int((pair >= UNSEATABLE).sum()),
             "gap": (float(pair.sum()) - best) / max(seated.size, 1),
-            "stats": stats,
+            "stats": stats, "price": np.array(arena.price),
         })
     return out
 
@@ -136,7 +136,7 @@ def chains():
 
                 def forward_only(cand_p, cand_c, n_providers, state, eps,
                                  max_iters, frontier, stall_limit,
-                                 stats_out, transposed):
+                                 stats_out, transposed, reserve=None):
                     state, stall, _rows = sparse._phase_adaptive(
                         cand_p, cand_c, n_providers, state, eps=eps,
                         max_iters=max_iters, frontier=frontier, retire=True,

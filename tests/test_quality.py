@@ -272,9 +272,12 @@ class TestGapCertificate:
             outcomes=outs, stats=stats,
         )
         ref = quality.duality_gap(cand_p, cand_c, p4t, price)
+        # the engine's margin pass counts no queue (tasks that have to
+        # wait: this instance has a few); the scan's addend for them is
+        # handed over, as ``tick_quality`` does
         cert = quality.gap_from_certificate(
             p4t, stats["plan_cost"], stats["cs_slack"],
-            stats["idle_price"],
+            stats["idle_price"], ref["waiting_excess"],
         )
         assert cert["plan_cost"] == pytest.approx(
             ref["plan_cost"], rel=1e-5
@@ -327,6 +330,169 @@ class TestGapCertificate:
         assert int((p4t >= 0).sum()) == 512
         gap = quality.duality_gap(cand_p, cand_c, p4t, price)
         assert gap["gap_per_task"] <= 2 * 0.02
+
+
+class TestWaitingExcess:
+    """The certificate's third addend (ISSUE 33): where tasks have to
+    wait, the bound covers every plan that seats as many tasks,
+    whichever they are. Hand-built pools with every task listing every
+    provider, so the optimum is plain to see."""
+
+    @staticmethod
+    def _pool(task_cost, provider_cost):
+        """cost[t, p] = task_cost[t] + provider_cost[p]: every plan that
+        seats all providers costs their sum plus the seated tasks'."""
+        a = np.asarray(task_cost, np.float32)
+        q = np.asarray(provider_cost, np.float32)
+        cand_p = np.tile(np.arange(q.size, dtype=np.int32), (a.size, 1))
+        cand_c = a[:, None] + q[None, :]
+        # duals at which every provider is worth the same to a task
+        price = (q.max() - q).astype(np.float32)
+        return cand_p, cand_c, price
+
+    @pytest.mark.parametrize("task_cost, provider_cost, right, wrong", [
+        # 3 providers x 5 tasks: tasks 0, 1, 2 are the cheapest
+        ([1.0, 2.0, 3.0, 4.5, 7.0], [0.0, 0.0, 0.0],
+         [0, 1, 2, -1, -1], [0, 1, -1, 2, -1]),
+        # 4 providers x 6 tasks, providers that differ: tasks 1, 2, 4, 5
+        ([5.0, 1.0, 2.0, 9.0, 2.5, 4.0], [0.5, 1.5, 0.0, 3.0],
+         [-1, 0, 1, -1, 2, 3], [3, 0, 1, -1, 2, -1]),
+    ], ids=["3x5", "4x6"])
+    def test_a_wrong_task_waiting_reads_the_exact_gap(
+        self, task_cost, provider_cost, right, wrong
+    ):
+        from scipy.optimize import linear_sum_assignment
+
+        cand_p, cand_c, price = self._pool(task_cost, provider_cost)
+        rows, cols = linear_sum_assignment(cand_c)
+        best = float(cand_c[rows, cols].sum())
+
+        def plan_cost(p4t):
+            p4t = np.asarray(p4t)
+            t = np.flatnonzero(p4t >= 0)
+            return float(cand_c[t, p4t[t]].sum())
+
+        assert plan_cost(right) == pytest.approx(best)
+        true_gap = plan_cost(wrong) - best
+        assert true_gap > 0.4
+        good = quality.duality_gap(cand_p, cand_c, np.asarray(right), price)
+        bad = quality.duality_gap(cand_p, cand_c, np.asarray(wrong), price)
+        # the right tasks wait: nothing to add, the plan is certified
+        assert good["waiting_excess"] == 0.0
+        assert good["gap_total"] == pytest.approx(0.0, abs=1e-5)
+        # a wrong one waits: the addend is what the plan is off by
+        assert bad["waiting_excess"] == pytest.approx(true_gap, abs=1e-5)
+        assert bad["cs_slack"] == pytest.approx(0.0, abs=1e-5)
+        assert bad["idle_price"] == 0.0
+        assert bad["gap_total"] == pytest.approx(true_gap, abs=1e-5)
+        n = int((np.asarray(wrong) >= 0).sum())
+        assert bad["gap_per_task"] == pytest.approx(true_gap / n, abs=1e-5)
+        # the O(1) assembly follows
+        cert = quality.gap_from_certificate(
+            np.asarray(wrong), bad["plan_cost"], bad["cs_slack"],
+            bad["idle_price"], bad["waiting_excess"],
+        )
+        assert cert["gap_total"] == bad["gap_total"]
+        assert cert["waiting_excess"] == bad["waiting_excess"]
+
+    def test_nobody_waiting_reads_todays_values(self):
+        """3 x 3, everyone seated, one seat an eps off its best, and
+        4 x 3 with a free provider at a stranded price: slack and idle
+        price as they always were, the addend 0; so too for two waiting
+        tasks beside two free providers (a full pool's unseatable tail:
+        no queue)."""
+        cand_p = np.tile(np.arange(3, dtype=np.int32), (3, 1))
+        cand_c = np.array(
+            [[1.0, 2.0, 3.0], [2.0, 1.0, 3.0], [3.0, 3.0, 1.5]], np.float32
+        )
+        price = np.array([0.0, 0.5, 0.0], np.float32)
+        out = quality.duality_gap(
+            cand_p, cand_c, np.array([0, 1, 2]), price
+        )
+        assert out == {
+            "plan_cost": 3.5, "dual_bound": 3.5, "gap_total": 0.0,
+            "gap_per_task": 0.0, "cs_slack": 0.0, "idle_price": 0.0,
+            "waiting_excess": 0.0,
+        }
+        out = quality.duality_gap(
+            cand_p, cand_c, np.array([1, 0, 2]), price
+        )
+        # task 0 holds provider 1 at 2.5 against a best of 1.0, task 1
+        # provider 0 at 2.0 against 1.5
+        assert out["cs_slack"] == pytest.approx(2.0)
+        assert out["gap_total"] == pytest.approx(2.0)
+        assert out["waiting_excess"] == 0.0
+
+        cand_p4 = np.tile(np.arange(4, dtype=np.int32), (3, 1))
+        cand_c4 = np.concatenate(
+            [cand_c, np.full((3, 1), 2.0, np.float32)], axis=1
+        )
+        price4 = np.array([0.0, 0.0, 0.0, 0.75], np.float32)
+        out = quality.duality_gap(
+            cand_p4, cand_c4, np.array([0, 1, 2]), price4
+        )
+        assert out["idle_price"] == 0.75 and out["cs_slack"] == 0.0
+        assert out["gap_total"] == 0.75 and out["waiting_excess"] == 0.0
+        assert quality.gap_from_certificate(
+            np.array([0, 1, 2]), 3.5, 0.0, 0.75
+        )["gap_total"] == 0.75
+
+        # an unseatable tail: tasks 3 and 4 list providers 0 and 1 only,
+        # which are taken, and providers 3 and 4 stay free beside them
+        tail_p = np.array(
+            [[0, 1, 2, 3, 4]] * 3 + [[0, 1, -1, -1, -1]] * 2, np.int32
+        )
+        tail_c = np.where(tail_p >= 0, 1.0, 1e9).astype(np.float32)
+        out = quality.duality_gap(
+            tail_p, tail_c, np.array([0, 1, 2, -1, -1]),
+            np.zeros(5, np.float32),
+        )
+        assert out["waiting_excess"] == 0.0 and out["gap_total"] == 0.0
+        assert quality.queue_rows(
+            tail_p, tail_c, np.array([0, 1, 2, -1, -1]), 5
+        )[0].size == 0
+
+    @pytest.mark.parametrize("n_tasks,n_seated", [(40, 24), (24, 22), (24, 24)])
+    def test_the_providers_a_caller_covered_are_not_read_again(
+        self, n_tasks, n_seated
+    ):
+        """``queue_rows`` with ``covered`` (some rows and the providers
+        they list, as ``duality_gap`` has them) returns what it returns
+        reading every list itself: a queue, a full pool's tail, nobody
+        waiting."""
+        cand_p, cand_c = _unique_candidates(7, n_tasks, 24, 6)
+        cand_p[:, 5] = -1                      # an empty slot a row
+        cand_c[::3, 0] = quality._INFEASIBLE   # an infeasible candidate
+        p4t = np.full(n_tasks, -1, np.int32)
+        p4t[:n_seated] = np.arange(n_seated)
+        rows = np.arange(0, n_seated, 2)
+        feas = (cand_p[rows] >= 0) & (cand_c[rows] < quality._INFEASIBLE * 0.5)
+        mask = np.zeros(24, bool)
+        mask[cand_p[rows][feas]] = True
+        whole = quality.queue_rows(cand_p, cand_c, p4t, 24)
+        given = quality.queue_rows(cand_p, cand_c, p4t, 24, covered=(rows, mask))
+        np.testing.assert_array_equal(whole[0], given[0])
+        np.testing.assert_array_equal(whole[1], given[1])
+        assert (whole[0].size > 0) == (n_tasks == 40)
+
+    def test_the_addend_is_least_over_the_value_of_waiting(self):
+        """``_waiting_excess`` against a brute-force search of theta."""
+        rng = np.random.default_rng(33)
+        for _ in range(20):
+            seated = rng.normal(size=rng.integers(1, 12))
+            waiting = rng.normal(size=rng.integers(1, 12))
+            values = np.concatenate([seated, waiting])
+            brute = min(
+                np.maximum(th - seated, 0).sum()
+                + np.maximum(waiting - th, 0).sum()
+                for th in values
+            )
+            assert quality._waiting_excess(seated, waiting) == (
+                pytest.approx(brute, abs=1e-12)
+            )
+        assert quality._waiting_excess(np.array([1.0, 2.0]),
+                                       np.array([0.5, 1.0])) == 0.0
+        assert quality._waiting_excess(np.array([1.0]), np.zeros(0)) == 0.0
 
 
 class TestQualitySignals:

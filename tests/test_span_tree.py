@@ -28,7 +28,8 @@ ROWS = 256
 TEST_SECONDS = 240
 
 # name -> parent's name, the tree of ISSUE 26 §1 (a warm repair tick),
-# with ISSUE 27's ``ckpt.prefix`` and ISSUE 29's ``auction.reverse``
+# with ISSUE 27's ``ckpt.prefix``, ISSUE 29's ``auction.reverse`` and
+# ISSUE 33's ``auction.queue``
 TREE = {
     "rpc.AssignDelta": None,
     "session.lookup": "rpc.AssignDelta",
@@ -48,6 +49,7 @@ TREE = {
     "auction.seed": "arena.engine",
     "auction.segment": "arena.engine",
     "auction.reverse": "arena.engine",
+    "auction.queue": "arena.engine",
     "auction.cleanup": "arena.engine",
     "arena.readback": "arena.engine",
     "arena.quality": "arena.solve",
@@ -332,6 +334,70 @@ class TestSpanTree:
         plan = served.plan
         assert stats["eng_free_providers"] == ROWS - int((plan >= 0).sum())
 
+    def test_queue_spans_count_the_pass(self, served):
+        """Every solve reads whether its pool has a queue (one
+        ``auction.queue`` span, step ``regime``, closed at its one
+        read); a full pool, as here, has none, so nothing else of the
+        pass runs, and its four counters ride ``last_stats`` all the
+        same."""
+        stats = served.stats()
+        queue = [s for s in served.spans if s["name"] == "auction.queue"]
+        assert [s["attrs"].get("step") for s in queue] == ["regime"]
+        assert queue[0]["attrs"]["seatable"] <= ROWS
+        assert queue[0]["attrs"]["listed"] <= ROWS
+        assert stats["eng_queue_rounds"] == 0
+        assert 0 < stats["eng_queue_ms"] <= stats["solve_ms"]
+        assert stats["eng_queue_ms"] >= queue[0]["dur_ns"] / 1e6 - 0.5
+        assert stats["eng_waiting_tasks"] == ROWS - int(
+            (served.plan >= 0).sum()
+        )
+        assert stats["waiting_excess"] == 0.0
+
+    def test_queue_spans_in_a_pool_with_a_queue(self):
+        """Where tasks outnumber providers the pass runs: ``check``
+        (closed at its read of the free providers), and with any free
+        ``seed``, the pass's segments (the segment attrs) and
+        ``finish``, all under ``arena.engine``; the counters add up."""
+        from tests.test_pool_slack import open_session
+
+        gen, arena, session = open_session(256, 320)
+        for tick in range(3):
+            mark = TRACER.mark()
+            with session.lock:
+                if tick:
+                    session.apply_delta(*gen.next_delta())
+                session.solve()
+            spans = TRACER.since(mark)
+            stats = dict(arena.last_stats)
+            by_id = {s["span"]: s for s in spans}
+            queue = [s for s in spans if s["name"] == "auction.queue"]
+            assert all(
+                by_id[s["parent"]]["name"] == "arena.engine" for s in queue
+            )
+            steps = [s["attrs"].get("step") for s in queue]
+            assert steps[0] == "regime" and steps.count("regime") == 1
+            assert steps.count("check") == (5 if tick == 0 else 1)
+            assert steps.count("seed") == steps.count("finish") >= 1
+            segs = [s["attrs"] for s in queue if "rounds" in s["attrs"]]
+            assert sum(a["rounds"] for a in segs) == stats["eng_queue_rounds"]
+            assert stats["eng_queue_rounds"] > 0
+            forward = [
+                s["attrs"] for s in spans if s["name"] == "auction.segment"
+            ]
+            assert sum(a["rounds"] for a in forward) == (
+                stats["eng_rounds_total"]
+            )
+            assert stats["eng_frontier_rows"] == sum(
+                a["rows"] for a in segs + forward
+            )
+            assert stats["eng_queue_ms"] >= sum(
+                s["dur_ns"] for s in queue
+            ) / 1e6 - 0.5
+            assert stats["eng_queue_ms"] <= stats["solve_ms"]
+            assert stats["eng_waiting_tasks"] == 320 - 256
+            assert 0.0 <= stats["waiting_excess"] <= 0.02 * 64
+            assert stats["eng_reverse_rounds"] == 0
+
 
 class TestCounters:
     def test_last_stats_split_the_stage_walls(self, served):
@@ -493,6 +559,15 @@ class TestScopeNames:
                 jnp.float32(0.0)),
         ):
             assert "auction.reverse" in lowered.as_text(debug_info=True)
+        for lowered in (
+            sparse._queue_free.lower(cp, owner, num_providers=self.P),
+            sparse._queue_seed.lower(
+                cp, cc, jnp.zeros(self.P), owner, p4t, jnp.float32(-30.0)),
+        ):
+            assert "auction.queue" in lowered.as_text(debug_info=True)
+        # the names queue_roofline finds the queue pass's programs by
+        assert sparse._queue_free.__name__ == "_queue_free"
+        assert sparse._queue_seed.__name__ == "_queue_seed"
         # the names reverse_roofline finds the pass's own programs by
         assert sparse._transpose_candidates.__name__ == "_transpose_candidates"
         assert sparse._reverse_seed.__name__ == "_reverse_seed"
@@ -561,7 +636,7 @@ class TestScopeNames:
             assert scope in lowered.as_text(debug_info=True), scope
 
 
-# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one and ISSUE 32's two that read counters,
+# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one, ISSUE 32's two and ISSUE 33's five that read counters,
 # read through the benchmark's own generic reader from canned contexts (data files only: no reader code)
 
 _ACKS = [
@@ -572,7 +647,9 @@ _ACKS = [
      "gap_per_task": 0.010, "idle_price": 0.0, "eng_free_providers": 3277,
      "eng_free_repriced": 100, "eng_reverse_rounds": 40,
      "eng_reverse_ms": 30.0, "eng_frontier_rows": 300000,
-     "rep_readback_bytes": 6000000, "rep_syncs": 4},
+     "rep_readback_bytes": 6000000, "rep_syncs": 4,
+     "eng_waiting_tasks": 1638, "eng_queue_rounds": 30,
+     "eng_queue_ms": 60.0, "waiting_excess": 4.0},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
@@ -580,7 +657,9 @@ _ACKS = [
      "gap_per_task": 0.012, "idle_price": 1.0, "eng_free_providers": 3277,
      "eng_free_repriced": 140, "eng_reverse_rounds": 60,
      "eng_reverse_ms": 50.0, "eng_frontier_rows": 340000,
-     "rep_readback_bytes": 7000000, "rep_syncs": 3},
+     "rep_readback_bytes": 7000000, "rep_syncs": 3,
+     "eng_waiting_tasks": 1640, "eng_queue_rounds": 50,
+     "eng_queue_ms": 80.0, "waiting_excess": 6.0},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
@@ -657,6 +736,19 @@ METRICS = {
         "rep_readback_bytes", 6500000.0),
     "repair_syncs_per_ack": (
         "candidate repair", "reads", "program_counter", "rep_syncs", 3.5),
+    "waiting_tasks_per_ack": (
+        "auction solve", "tasks", "program_counter", "eng_waiting_tasks",
+        1639.0),
+    "queue_rounds_per_ack": (
+        "auction solve", "rounds", "program_counter", "eng_queue_rounds",
+        40.0),
+    "queue_ms_per_ack": (
+        "auction solve", "ms", "program_span", "eng_queue_ms", 70.0),
+    "waiting_excess_per_ack": (
+        "quality pass", "cost", "program_counter", "waiting_excess", 5.0),
+    "queued_gap_per_task": (
+        "quality pass", "cost/task", "program_counter", "gap_per_task",
+        0.011),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -670,6 +762,11 @@ CELLS.update(dict.fromkeys(
     ("frontier_rows_per_ack", "repair_readback_bytes_per_ack",
      "repair_syncs_per_ack"),
     ["pool-large.ticks", "pool-slack.ticks"],
+))
+CELLS.update(dict.fromkeys(
+    ("waiting_tasks_per_ack", "queue_rounds_per_ack", "queue_ms_per_ack",
+     "waiting_excess_per_ack", "queued_gap_per_task"),
+    ["pool-queued.ticks"],
 ))
 
 
@@ -702,6 +799,44 @@ def test_a_new_metric_reads_its_counter_through_the_generic_reader(name):
     # the parent commit has no such counter: nothing is read, nothing
     # is raised, and the line leaves the metric out
     assert readers.read_metric(spec, _without(key)) is None
+
+
+def test_queue_roofline_reads_the_pass_programs_from_a_canned_trace():
+    """``queue_roofline`` through the generic reader: the device time
+    of the queue pass's four programs in a slice, against the least
+    bytes they must move at the cell's shapes; a trace without them
+    (the parent commit: the pass never runs) reads nothing."""
+    from benchmarks.lib import readers
+
+    path = os.path.join(REPO, "benchmarks", "metrics", "queue_roofline.json")
+    with open(path) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "queue_roofline"]
+    assert entry == {
+        "name": "queue_roofline", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels", "moves": "ack_p50_ms",
+        "workloads": ["pool-queued.ticks"],
+    }
+    shape = {"n_tasks": 8192, "n_providers": 6554, "k_eff": 80}
+    trace = {
+        "busy_s": 1.0, "window_s": 5.0, "acks_in_slice": 5,
+        "module_s": {
+            "jit__queue_free": 0.004, "jit__queue_seed": 0.006,
+            "jit__transpose_candidates": 0.170, "jit__reverse_finish": 0.020,
+            "jit__sparse_auction_phase": 0.5, "jit__reverse_seed": 0.3,
+        },
+    }
+    ctx = {"trace": trace, "shape": shape, "peaks": {"hbm_bytes_per_s": 819e9}}
+    need = (36 * 8192 * 80 + 2068 * 6554) * 5
+    assert readers.read_metric(spec, ctx) == pytest.approx(
+        100.0 * need / 819e9 / 0.2
+    )
+    assert readers.read_metric(spec, ctx) < 100.0
+    trace["module_s"] = {"jit__sparse_auction_phase": 0.5}
+    assert readers.read_metric(spec, ctx) is None
+    assert readers.read_metric(spec, {**ctx, "trace": None}) is None
 
 
 def test_the_stage_metrics_add_up_to_the_outside_ones():

@@ -528,8 +528,9 @@ class TestAdaptiveFrontierLadder:
         assert set(stats) == {
             "rounds_total", "segments", "wait_ms", "frontier_rows",
             "stall_exit", "stall_rounds", "reverse_rounds", "reverse_ms",
-            "free_repriced",
+            "free_repriced", "queue_rounds", "queue_ms",
         }
+        assert stats["queue_rounds"] == 0  # (no queue in this pool)
         assert stats["segments"] >= 1 and stats["rounds_total"] >= 1
         # every round runs at one of the kernel's widths, 32 the least
         assert stats["frontier_rows"] >= 32 * (
@@ -804,7 +805,7 @@ class TestWarmColdRegression:
         bp, bc = self._contended_instance()
         T = bc.shape[0]
         stats_cold: dict = {}
-        res, price, retired = assign_auction_sparse_scaled(
+        res, price, retired, _reserve = assign_auction_sparse_scaled(
             bp, bc, num_providers=T, with_state=True, stats_out=stats_cold
         )
         cold_assigned = int(np.asarray(res.provider_for_task >= 0).sum())

@@ -135,6 +135,44 @@ class TestWarmStart:
         assert m.last_solve_stats["assigned"] == 17
         assert "0x" + "f" * 40 in m._assignment
 
+    @pytest.mark.parametrize("cache", [True, False])
+    def test_a_pool_with_a_queue_stays_warm(self, cache, monkeypatch):
+        """More slots than usable nodes is a pool with a queue: the warm
+        solve needs the reserve the previous one anchored, or it
+        re-grounds with the whole cold ladder on every refresh. Both
+        sparse paths carry it (the candidate cache's and the stateless
+        one)."""
+        from protocol_tpu.ops import sparse
+
+        ctx = StoreContext.new_test()
+        for i in range(16):
+            ctx.node_store.add_node(mk_node(f"0xa{i:039x}", gpu_model="H100"))
+        for i in range(8):
+            ctx.node_store.add_node(mk_node(f"0xb{i:039x}", gpu_model="RTX4090"))
+        ctx.task_store.add_task(mk_bounded_task(
+            "h100only", 100, replicas=24, requirements="gpu:model=H100"
+        ))
+        m = TpuBatchMatcher(ctx, dense_cell_budget=0, min_solve_interval=0)
+        m.use_candidate_cache = cache
+        m.refresh()
+        # 24 slots bid for the 16 nodes they can use: 8 wait
+        assert m.last_solve_stats["assigned"] == 16
+        reserve = m._warm_reserve
+        assert reserve is not None and reserve < -10.0
+        ladders = []
+        cold = sparse.assign_auction_sparse_scaled
+        monkeypatch.setattr(
+            sparse, "assign_auction_sparse_scaled",
+            lambda *a, **k: ladders.append(1) or cold(*a, **k),
+        )
+        first = dict(m._assignment)
+        m.mark_dirty()
+        m.refresh()
+        assert m.last_solve_stats["warm"] is True
+        assert m._warm_reserve == reserve
+        assert ladders == []
+        assert m._assignment == first
+
     def test_warm_disabled(self):
         ctx = StoreContext.new_test()
         populate(ctx, 24, [mk_bounded_task("t", 100, replicas=16)])
