@@ -46,6 +46,19 @@ takes the prefix only if it was built for this tick from the very
 objects the session and arena hold now — anything else (no prefix, a
 stale one, a raised one) writes as before, counted.
 
+DEFLATE level (ISSUE 34): a checkpoint's frames are written at
+``CKPT_COMPRESSLEVEL`` = 1, a workload trace's at
+``trace/format.COMPRESSLEVEL`` = 6. The two files want opposite things
+of the same container: a trace is written once, archived and replayed,
+and pays for its bytes at rest; a journal is replaced every tick, read
+once after a crash, and its DEFLATE stands between a tick's solve and
+its ack. The writer of each knows which it writes; nothing in a payload
+could tell it. Readers (``read_frames``, the fleet's loaders, the
+benchmark's journal walk) call ``zlib.decompress``, which is blind to
+the level: a journal written at 6 by an older process loads here and
+one written here loads there, so a rolling restart and a ``handoff``
+between processes of both versions keep working.
+
 Cadence: ``every=1`` (the default, and what the chaos gate runs)
 checkpoints every tick — the zero-reopen guarantee. ``every=N`` trades
 durability for throughput: a crash loses up to N-1 ticks and the
@@ -100,6 +113,29 @@ log = logging.getLogger(__name__)
 _META_KIND = "session-checkpoint"
 _SUFFIX = ".ckpt"
 FENCE_NAME = "FENCE.json"
+
+# The DEFLATE level of every frame of a session checkpoint, on the
+# worker (``_PrefixJob``) and in the flush (``_write_locked``) alike:
+# ``TraceWriter._frame_deflated`` refuses a deflater of another level
+# than its writer's, so the two paths cannot drift apart. One warm
+# tick's SNAPSHOT + ARENA payloads of the benchmark's marketplace,
+# 13,004,932 B raw at every shape (P and T pad to 8,192), read by
+# ``scripts/ckpt_deflate_levels.py`` on the host of a TPU v5e (PR 34's
+# chip run; zlib 1.2.13, one core, best of two; ms, MB out):
+#
+#   shape          level 6       level 1       Z_RLE         stored
+#   8,192 x 8,192  809   6.950   225   7.274   130   7.468   5  13.006
+#   8,192 x 4,915  511   4.338   146   4.560   101   5.266   6  13.006
+#   6,554 x 8,192  754   6.749   217   7.082   125   7.317   6  13.006
+#
+# Level 6's lazy matching walks long hash chains through int32 index
+# lists that hold almost no repeats (``cand_p`` alone: 275 ms against
+# 37): 3.5 times level 1's time for 4.7-5.1% of the bytes. Levels 2-4
+# cost 266-347 ms at 8,192 x 8,192 for 1.3-2.8% of the bytes back;
+# Z_RLE is faster still but misses the padded rows' repeats, four bytes
+# apart (+21% at 8,192 x 4,915). Why a workload trace keeps
+# ``tfmt.COMPRESSLEVEL`` (6): the module docstring.
+CKPT_COMPRESSLEVEL = 1
 
 
 def fence_path(root: str, proc_id: str) -> str:
@@ -242,8 +278,8 @@ class _PrefixJob:
         _pop_arena_meta(self.live)
         self.last = last
         self.parent = parent
-        self.snapshot = tfmt.FrameDeflater()
-        self.arena = tfmt.FrameDeflater()
+        self.snapshot = tfmt.FrameDeflater(CKPT_COMPRESSLEVEL)
+        self.arena = tfmt.FrameDeflater(CKPT_COMPRESSLEVEL)
         self.head = b""
         self.overlap_ms = 0.0
         self.dropped = False
@@ -530,7 +566,9 @@ class SessionCheckpointer:
             snapshot, arena = job.snapshot, job.arena
         final = self.path_for(session.session_id)
         tmp = final + ".tmp"
-        writer = tfmt.TraceWriter(tmp, meta=meta)
+        writer = tfmt.TraceWriter(
+            tmp, meta=meta, compresslevel=CKPT_COMPRESSLEVEL
+        )
         try:
             with _frame_span(writer, "snapshot"):
                 if job is None:

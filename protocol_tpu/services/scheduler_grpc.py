@@ -796,8 +796,10 @@ class SchedulerBackendServicer:
     def _flush_locked(self, session) -> bool:
         """Checkpoint ``session`` (caller holds ``session.lock``) and
         record what the flush cost on the seam — phases ``ckpt_flush``,
-        ``ckpt_export``, ``ckpt_deflate`` (zlib time inside the flush)
-        and the journal's bytes on disk; a flush that used the tick's
+        ``ckpt_export``, ``ckpt_deflate`` (zlib time inside the flush),
+        ``ckpt_join`` (the flush's wait for the tick's prefix job on the
+        worker; 0.0 where there was none to wait for) and the journal's
+        bytes on disk; a flush that used the tick's
         prefix job counts ``ckpt_prefix_hit`` and the worker's zlib
         time as phase ``ckpt_overlap``, any other ``ckpt_prefix_miss``
         — so Health carries them with no field of its own."""
@@ -807,6 +809,7 @@ class SchedulerBackendServicer:
         self.seam.observe_ms("ckpt_flush", took["flush_ms"])
         self.seam.observe_ms("ckpt_export", took["export_ms"])
         self.seam.observe_ms("ckpt_deflate", took["deflate_ms"])
+        self.seam.observe_ms("ckpt_join", took["join_ms"])
         self.seam.add_bytes("ckpt", took["bytes_out"])
         if took["prefix"] == "hit":
             self.seam.observe_ms("ckpt_overlap", took["overlap_ms"])

@@ -10,6 +10,7 @@ and counted. CPU, 256 rows, through the loopback servicer of
 import os
 import sys
 import threading
+import zlib
 
 import numpy as np
 import pytest
@@ -64,8 +65,42 @@ def _kinds(journal: bytes, tmp_path) -> list:
     return [kind for kind, _ in tfmt.read_frames(path)]
 
 
+def _raw_frames(data: bytes) -> list:
+    """``(kind, flags, body)`` of every frame of a file's bytes, the
+    bodies as they lie on disk."""
+    at, out = len(tfmt.MAGIC), []
+    assert data[:at] == tfmt.MAGIC
+    while at < len(data):
+        kind, flags, length, crc = tfmt._HEADER.unpack_from(data, at)
+        at += tfmt._HEADER.size
+        body = data[at:at + length]
+        assert len(body) == length and zlib.crc32(body) == crc
+        out.append((kind, flags, body))
+        at += length
+    return out
+
+
+def _payloads_at(data: bytes, level: int) -> list:
+    """``(kind, payload)`` of every frame, each DEFLATEd body being
+    what ``zlib.compress`` gives for its payload at ``level``."""
+    out = []
+    for kind, flags, body in _raw_frames(data):
+        payload = body
+        if flags & tfmt._FLAG_DEFLATE:
+            payload = zlib.decompress(body)
+            assert body == zlib.compress(payload, level), (kind, level)
+        out.append((kind, payload))
+    return out
+
+
 WHOLE = [tfmt.KIND_META, tfmt.KIND_SNAPSHOT, tfmt.KIND_ARENA,
          tfmt.KIND_OUTCOME]
+# the two levels a PTTRACE1 file is written at (ISSUE 34), by who
+# writes it
+LEVELS = {
+    "trace": tfmt.COMPRESSLEVEL,
+    "checkpoint": ckpt_mod.CKPT_COMPRESSLEVEL,
+}
 
 
 @pytest.fixture(scope="module")
@@ -238,9 +273,8 @@ class TestFallbacks:
             s.close()
 
 
-def test_a_stream_fed_in_pieces_is_the_stream_of_one_call():
-    import zlib
-
+@pytest.mark.parametrize("level", LEVELS.values(), ids=LEVELS.keys())
+def test_a_stream_fed_in_pieces_is_the_stream_of_one_call(level):
     rng = np.random.default_rng(27)
     named = {
         "cand_p": rng.integers(0, ROWS, (ROWS, 80)).astype(np.int32),
@@ -253,20 +287,139 @@ def test_a_stream_fed_in_pieces_is_the_stream_of_one_call():
     last = ("price", "retired")
     whole = tfmt.pack_arrays(named, last)
     head, arrays = tfmt.pack_plan(named, last)
-    d = tfmt.FrameDeflater()
+    d = tfmt.FrameDeflater(level)
     d.feed(head)
     for _name, a in arrays:
         d.feed(tfmt.raw_bytes(a))
     assert d.bytes_raw == len(whole)
-    assert d.finish() == (1, zlib.compress(whole, tfmt.COMPRESSLEVEL))
+    assert d.finish() == (1, zlib.compress(whole, level))
     assert d.finish() is d.finish()
     assert d.take_ms() > 0 and d.take_ms() == 0
     # a payload DEFLATE cannot shorten is stored as it is, as _frame does
     noise = rng.bytes(4096)
-    d = tfmt.FrameDeflater()
+    d = tfmt.FrameDeflater(level)
     d.feed(noise[:100])
     d.feed(noise[100:])
     assert d.finish() == (0, noise)
+
+
+def test_the_levels_are_the_checkpoints_and_the_traces():
+    assert LEVELS == {"trace": 6, "checkpoint": 1}
+    assert tfmt.FrameDeflater().compresslevel == tfmt.COMPRESSLEVEL
+
+
+def test_a_writer_refuses_a_frame_deflated_at_another_level(tmp_path):
+    """What keeps the worker's frames and the flush's at one level: a
+    journal is byte for byte the same whichever path wrote it."""
+    d = tfmt.FrameDeflater(LEVELS["trace"])
+    d.feed(b"payload " * 64)
+    path = str(tmp_path / "w.ckpt")
+    with tfmt.TraceWriter(path, compresslevel=LEVELS["checkpoint"]) as w:
+        with pytest.raises(ValueError, match="another level"):
+            w.write_snapshot("s", "fp", None, deflated=d)
+        size = w.bytes_out
+    assert os.path.getsize(path) == size  # nothing of the frame landed
+
+
+def test_a_journal_of_either_level_restores_and_continues_the_chain(
+    served, tmp_path, monkeypatch
+):
+    """A journal as the commit before ISSUE 34 wrote it (every frame
+    ``zlib.compress(payload, 6)``) and one at the checkpoint's level,
+    from one session state: the same payloads frame for frame, and
+    each, loaded, serves the chain's next tick to the same plan, prices
+    and next journal. Readers are blind to the level, so a rolling
+    restart and a handoff between processes of both versions work."""
+    served.tick()
+    session = _session(served)
+    ours = _journal(served.server.servicer.ckpt, served.sid)
+    assert ours == _sequential(session, tmp_path / "ours")
+    with monkeypatch.context() as m:
+        m.setattr(ckpt_mod, "CKPT_COMPRESSLEVEL", LEVELS["trace"])
+        theirs = _sequential(session, tmp_path / "theirs")
+    assert theirs != ours
+    payloads = _payloads_at(ours, LEVELS["checkpoint"])
+    assert payloads == _payloads_at(theirs, LEVELS["trace"])
+    assert [kind for kind, _ in payloads] == WHOLE
+    served.tick()
+    rows = served.rows
+    p_delta = {k: v[rows] for k, v in served.p_cols.items()}
+    none = np.zeros(0, np.int32)
+    nexts = []
+    for name, journal in (("ours", ours), ("theirs", theirs)):
+        loaded = _loads(journal, served.sid, tmp_path / f"load-{name}")
+        assert loaded.tick == session.tick - 1
+        loaded.apply_delta(rows, p_delta, none, {})
+        p4t, _t4p, price = loaded.solve()
+        assert loaded.arena.last_stats["cold"] is False
+        np.testing.assert_array_equal(p4t, served.plan)
+        with loaded.lock:
+            loaded.tick += 1
+            loaded.last_p4t = p4t
+        nexts.append(
+            (price, _sequential(loaded, tmp_path / f"next-{name}"))
+        )
+    (price_a, next_a), (price_b, next_b) = nexts
+    np.testing.assert_array_equal(price_a, price_b)
+    assert next_a == next_b
+    # and the next journal is the served session's, column for column
+    # and array for array (its META holds the servicer's dedup cursor,
+    # which a session driven by hand does not advance)
+    served_next = _payloads_at(
+        _journal(served.server.servicer.ckpt, served.sid),
+        LEVELS["checkpoint"],
+    )
+    assert _payloads_at(next_a, LEVELS["checkpoint"])[1:] == served_next[1:]
+
+
+def test_a_recorded_workload_trace_is_still_written_at_the_traces_level(
+    tmp_path,
+):
+    """Traces are written once, archived and replayed: bytes at rest
+    are what they pay for, so the recorder and the synthesiser keep
+    ``trace/format.COMPRESSLEVEL`` whatever a checkpoint is written
+    at."""
+    from protocol_tpu.ops.cost import CostWeights
+    from protocol_tpu.trace.recorder import TraceRecorder
+    from protocol_tpu.trace.synth import (
+        synth_providers,
+        synth_requirements,
+        synth_trace,
+    )
+
+    rng = np.random.default_rng(34)
+    ep, er = synth_providers(rng, ROWS), synth_requirements(rng, ROWS)
+    recorded = str(tmp_path / "recorded.trace")
+    recorder = TraceRecorder(recorded)
+    p4t = rng.permutation(ROWS).astype(np.int32)
+    price = rng.random(ROWS).astype(np.float32)
+    for n in range(3):
+        ep.price[n] += 1.0
+        recorder.record_solve(
+            ep, er, CostWeights(), "native-mt", 64, 0.02, 0, p4t,
+            price=price,
+        )
+    recorder.close()
+    synthetic = synth_trace(
+        str(tmp_path / "synthetic.trace"), n_providers=ROWS, n_tasks=ROWS,
+        ticks=3, churn=0.03, seed=34,
+    )
+    for path, kinds in (
+        (recorded, {tfmt.KIND_META, tfmt.KIND_SNAPSHOT, tfmt.KIND_DELTA,
+                    tfmt.KIND_OUTCOME}),
+        (synthetic, {tfmt.KIND_META, tfmt.KIND_SNAPSHOT, tfmt.KIND_DELTA}),
+    ):
+        with open(path, "rb") as fh:
+            data = fh.read()
+        frames = _raw_frames(data)
+        assert kinds <= {kind for kind, _f, _b in frames}
+        assert any(flags & tfmt._FLAG_DEFLATE for _k, flags, _b in frames)
+        _payloads_at(data, LEVELS["trace"])
+        # and not by accident of a payload both levels pack alike
+        big = max(frames, key=lambda f: len(f[2]))
+        assert big[2] != zlib.compress(
+            zlib.decompress(big[2]), LEVELS["checkpoint"]
+        )
 
 
 def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):

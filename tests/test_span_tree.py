@@ -72,7 +72,7 @@ STAT_KEYS = (
 REPAIR_COUNTERS = ("rep_readback_bytes", "rep_syncs")
 SEAM_PHASES = (
     "lock_wait", "apply", "ckpt_flush", "ckpt_export", "ckpt_deflate",
-    "ckpt_overlap",
+    "ckpt_overlap", "ckpt_join",
 )
 
 
@@ -445,6 +445,12 @@ class TestCounters:
         # the warm tick's flush used its prefix job: most of the zlib
         # time lay beside the solve, not in the flush
         assert took["ckpt_overlap"] > took["ckpt_deflate"]
+        # the flush's wait for the worker is the span's own attribute
+        flush = next(s for s in served.spans if s["name"] == "ckpt.flush")
+        assert took["ckpt_join"] == pytest.approx(
+            flush["attrs"]["join_ms"], abs=1e-6
+        )
+        assert took["ckpt_join"] <= took["ckpt_flush"] + 0.01
         assert (
             after["session_ckpt_prefix_hit"]
             == before["session_ckpt_prefix_hit"] + 1
@@ -665,11 +671,13 @@ _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
     "ckpt_deflate_ms_sum": 80.0, "bytes_ckpt": 1000.0,
     "ckpt_overlap_ms_sum": 700.0, "session_ckpt_prefix_hit": 7.0,
+    "ckpt_join_ms_sum": 30.0,
 }
 _SEAM_AFTER = {
     "apply_ms_sum": 5.0, "ckpt_flush_ms_sum": 1300.0,
     "ckpt_deflate_ms_sum": 1080.0, "bytes_ckpt": 7001000.0,
     "ckpt_overlap_ms_sum": 1600.0, "session_ckpt_prefix_hit": 9.0,
+    "ckpt_join_ms_sum": 54.0,
 }
 # metric -> (layer, unit, source, the key whose absence silences it,
 #            expected value on the canned context[, better])
@@ -712,6 +720,9 @@ METRICS = {
     "ckpt_prefix_hits_per_ack": (
         "session, arena bookkeeping and checkpoint", "hits",
         "program_counter", "session_ckpt_prefix_hit", 1.0, "higher"),
+    "ckpt_join_ms_per_ack": (
+        "session, arena bookkeeping and checkpoint", "ms", "program_span",
+        "ckpt_join_ms_sum", 12.0),
     "certified_gap_per_task": (
         "quality pass", "cost/task", "program_counter", "gap_per_task",
         0.011),
@@ -768,6 +779,9 @@ CELLS.update(dict.fromkeys(
      "waiting_excess_per_ack", "queued_gap_per_task"),
     ["pool-queued.ticks"],
 ))
+CELLS["ckpt_join_ms_per_ack"] = [
+    "pool-large.ticks", "pool-slack.ticks", "pool-queued.ticks",
+]
 
 
 def _without(key: str) -> dict:
