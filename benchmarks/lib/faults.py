@@ -22,6 +22,11 @@ one attribute of the program for as long as the context lasts:
                     whoever is still open (a solve that cuts its rounds)
 ``tail_left_open``  the same cap, and the greedy clean-up left out: the
                     tasks the auction had not seated stay open
+``queue_pass_skipped`` no pool is ever said to have a queue, so the
+                    queue pass and its reserve are left out and the
+                    stall breaker decides which tasks wait (the solve
+                    before PR 33; a fault only where tasks outnumber
+                    providers, elsewhere the sound path)
 """
 
 from __future__ import annotations
@@ -32,7 +37,8 @@ import copy
 import numpy as np
 
 FAULTS = ("control", "journal_body_dropped", "state_unchanged",
-          "answer_altered", "rounds_capped", "tail_left_open")
+          "answer_altered", "rounds_capped", "tail_left_open",
+          "queue_pass_skipped")
 TASKS_PER_ROUND = 64
 
 
@@ -107,6 +113,16 @@ def tail_left_open(cell: dict):
         yield
 
 
+def queue_pass_skipped(_cell=None):
+    from protocol_tpu.ops import sparse
+
+    def make(_original):
+        def _queue_reserve(*args, **kwargs):
+            return None
+        return _queue_reserve
+    return _swapped(sparse, "_queue_reserve", make)
+
+
 @contextlib.contextmanager
 def journal_body_dropped(_cell=None):
     from protocol_tpu.trace.format import TraceWriter
@@ -126,6 +142,7 @@ _PLANTED = {
     "rounds_capped": rounds_capped,
     "tail_left_open": tail_left_open,
     "journal_body_dropped": journal_body_dropped,
+    "queue_pass_skipped": queue_pass_skipped,
 }
 
 

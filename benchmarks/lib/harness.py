@@ -36,6 +36,11 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # builds lazily behind it before the window opens (see ``_run``).
 WARMUP_BLOCKED_TASKS = 4    # tasks made unassignable for one tick
 WARMUP_OVERSIZE = 2.0       # churn of the one oversized tick a session
+# Where the mix has life events the list crosses from a pool with a
+# queue to one with idle nodes and back; set-up takes pool 0 into each
+# regime for one tick, this share of its live providers deep: twice the
+# 1 in 64 under which the solve counts a pool as neither (PERF.md s7).
+WARMUP_REGIME_MARGIN = 1 / 32
 # Rounds of the window's own loop, one tick a pool, before the window
 # opens. A fixed number: the list starts at the same tick on every run
 # and every commit, whatever the program builds when. One would do for
@@ -289,7 +294,9 @@ def _stale_columns(ack: dict, p_cols: dict, r_cols: dict) -> int:
 
 def _judge(cell, pools, good, picks, rng):
     """The reference's numbers over the sampled acks' plans and kept
-    journals, each the worst over the sample."""
+    journals, each the worst over the sample (of ``queued_tasks`` and
+    ``idle_providers``, the pool's regime at an ack, the number of
+    sampled acks at which it was not 0)."""
     cfg = cell["config"]
     worst: dict = {}
     for i in picks:
@@ -307,6 +314,9 @@ def _judge(cell, pools, good, picks, rng):
             int(cfg["check"]["subpool_tasks"]),
         )
         got["journal_stale_columns"] = _stale_columns(ack, p_cols, r_cols)
+        for regime, count in (("queued_tasks", "judged_queue_acks"),
+                              ("idle_providers", "judged_slack_acks")):
+            worst[count] = worst.get(count, 0) + (got.pop(regime, 0) > 0)
         for name, value in got.items():
             worst[name] = max(worst.get(name, 0), value)
     return worst
@@ -418,6 +428,10 @@ def _run(cell, seed, seconds, trace, require_chip, t0) -> dict:
             # flight, who holds which decides who waits for whom);
             # --seed draws which acks and which sub-pool the reference
             # judges, and nothing that is sent.
+            # Rows that come and go (the mix's life events, and which
+            # rows are live at the open) draw from a generator of their
+            # own, so the churn's draws are the same with them or not.
+            life = population.life_of(traffic)
             pools = []
             for i in range(n_pools):
                 gen = population.Pool(
@@ -427,6 +441,13 @@ def _run(cell, seed, seconds, trace, require_chip, t0) -> dict:
                     int(cfg["n_providers"]), int(cfg["n_tasks"]),
                     float(traffic["provider_churn"]),
                     float(traffic["task_churn"]),
+                    life=life,
+                    life_rng=np.random.default_rng(
+                        [int(cfg["population_seed"]), i,
+                         population.LIFE_STREAM]
+                    ),
+                    providers_live=cfg.get("providers_live"),
+                    tasks_live=cfg.get("tasks_live"),
                 )
                 pool = {
                     "index": i, "gen": gen, "sid": f"bench@pool{i}",
@@ -478,15 +499,23 @@ def _run(cell, seed, seconds, trace, require_chip, t0) -> dict:
             # be behind it before the window opens. The sweep of tasks
             # left open: four tasks made unassignable for one tick. The
             # padded shapes a session's repair ratchets up to: an
-            # oversized first tick on every session. Whatever the
-            # mix's own concurrency brings: rounds of the window's own
-            # loop, one tick a pool.
+            # oversized first tick on every session. Where the mix has
+            # life events, the regimes its list will visit (the reverse
+            # pass's and the queue pass's programs, the cold re-ground
+            # on entering the queue): pool 0 with idle nodes for one
+            # tick, with a queue for one, and back. Whatever the mix's
+            # own concurrency brings: rounds of the window's own loop,
+            # one tick a pool.
             none = np.zeros(0, np.int32)
             for rows, vals in pools[0]["gen"].block_tasks(
                     WARMUP_BLOCKED_TASKS):
                 warm(pools[0], (none, {}, rows, vals))
             for pool in pools:
                 warm(pool, pool["gen"].next_delta(WARMUP_OVERSIZE))
+            if life:
+                for rows, vals in pools[0]["gen"].regime_visits(
+                        WARMUP_REGIME_MARGIN):
+                    warm(pools[0], (none, {}, rows, vals))
             rs["tracer"] = _Tracer(False, "", 0)
             for _ in range(WARMUP_ROUNDS):
                 rs["open_s"] = time.perf_counter()
@@ -594,6 +623,7 @@ def _run(cell, seed, seconds, trace, require_chip, t0) -> dict:
         ),
         "unflushed_acks": sum(1 for a in good if not a["journal_ok"]),
         "flush_failures": seam_after.get("ckpt_flush_failures", 1.0),
+        "arrivals_dropped": sum(p["gen"].arrivals_dropped for p in pools),
     }
     for a in good:
         if a["stats"].get("jit_compiles_delta") != {}:
@@ -610,6 +640,9 @@ def _run(cell, seed, seconds, trace, require_chip, t0) -> dict:
         c["value"] is not None and c["value"] <= c["limit"]
         for c in checks.values()
     )
+    # which regimes the judged plans were of: counts, held to no limit
+    for name in ("judged_queue_acks", "judged_slack_acks"):
+        checks[name] = {"value": judged.get(name, 0), "limit": None}
 
     dev = {
         "platform": platform, "kind": device["device_kind"],

@@ -14,7 +14,8 @@ The semantics are the published ones (``ops/cost.py`` and
 
 A plan maps each task to a provider or -1. It is judged on: no provider
 used twice, no infeasible pair, the share of live tasks left without a
-provider, and how much dearer it is than the optimum of a seeded
+provider (of all of them, and beyond those that outnumber the live
+providers), and how much dearer it is than the optimum of a seeded
 sub-pool re-solved exactly (``subpool_gap``).
 """
 
@@ -23,6 +24,7 @@ from __future__ import annotations
 import numpy as np
 
 EARTH_RADIUS_KM = 6371.0
+UNSEATABLE = 1e6     # an infeasible pair's cost in a re-solve
 
 
 def _ge_min(have, need):
@@ -113,10 +115,18 @@ def block_costs(p_cols, r_cols, providers, tasks, weights):
 def judge_plan(p_cols, r_cols, plan, weights, rng, subpool_tasks):
     """The numbers one acknowledged plan is held to, as a dict:
     ``dup_providers``, ``out_of_range``, ``infeasible_pairs`` (counts),
-    ``unassigned_frac`` (of live tasks) and ``subpool_gap`` (cost per
-    task above the exact optimum of a sub-pool: ``subpool_tasks``
-    assigned tasks drawn by ``rng``, over the providers the plan gave
-    them and every live provider it left free)."""
+    ``unassigned_frac`` (of live tasks), ``unseated_excess_frac`` (live
+    tasks left unseated beyond those that outnumber the live providers,
+    of live tasks), ``queued_tasks`` and ``idle_providers`` (live tasks
+    over live providers and the reverse, whichever is not 0: the
+    pool's regime at this tick) and ``subpool_gap``: cost per seated
+    task above the exact optimum of a sub-pool of ``subpool_tasks``
+    tasks drawn by ``rng``, over the providers the plan gave them and
+    every live provider it left free. In a pool with idle providers or
+    none to spare the sub-pool is drawn from the tasks the plan seated.
+    Where live tasks outnumber live providers it is drawn from ALL live
+    tasks, seated or waiting, so that the re-solve may seat a waiting
+    task in a seated one's place: a wrong task waiting shows."""
     from scipy.optimize import linear_sum_assignment
 
     plan = np.asarray(plan)
@@ -133,23 +143,40 @@ def judge_plan(p_cols, r_cols, plan, weights, rng, subpool_tasks):
     cost, ok = pair_costs(p_cols, r_cols, plan, weights)
     out["infeasible_pairs"] = int((seated & ~ok).sum())
     live = r_cols["valid"].astype(bool)
-    out["unassigned_frac"] = float((live & ~seated).sum()) / max(
-        int(live.sum()), 1
-    )
+    here = p_cols["valid"].astype(bool)
+    n_live, n_here = int(live.sum()), int(here.sum())
+    unseated = int((live & ~seated).sum())
+    out["queued_tasks"] = max(n_live - n_here, 0)
+    out["idle_providers"] = max(n_here - n_live, 0)
+    out["unassigned_frac"] = float(unseated) / max(n_live, 1)
+    out["unseated_excess_frac"] = float(
+        max(unseated - out["queued_tasks"], 0)
+    ) / max(n_live, 1)
     good = np.flatnonzero(seated & ok)
     if out["dup_providers"] or good.size == 0:
         return out
+    drawn_from = np.flatnonzero(live) if out["queued_tasks"] else good
     tasks = (
-        good if good.size <= subpool_tasks
-        else np.sort(rng.choice(good, subpool_tasks, replace=False))
+        drawn_from if drawn_from.size <= subpool_tasks
+        else np.sort(rng.choice(drawn_from, subpool_tasks, replace=False))
     )
+    mine = tasks[seated[tasks] & ok[tasks]]
+    if mine.size == 0:
+        return out
     free = np.ones(n_p, bool)
     free[used] = False
-    free &= p_cols["valid"].astype(bool)
-    cols = np.concatenate([plan[tasks], np.flatnonzero(free)])
+    free &= here
+    cols = np.concatenate([plan[mine], np.flatnonzero(free)])
     c, feas = block_costs(p_cols, r_cols, cols, tasks, weights)
-    c = np.where(feas, c, 1e6)
+    c = np.where(feas, c, UNSEATABLE)
     rows, picks = linear_sum_assignment(c)
-    best = c[rows, picks].sum()
-    out["subpool_gap"] = float(cost[tasks].sum() - best) / tasks.size
+    # at equal cardinality: an optimum that seats more of the sub-pool
+    # than the plan did is compared without its dearest pairs beyond
+    # the plan's count, which can only flatter the plan (the seats it
+    # is short are unseated_excess_frac's to hold). The plan's own
+    # pairs are feasible, so the pairs kept are.
+    best = c[rows, picks]
+    if best.size > mine.size:
+        best = np.sort(best)[: mine.size]
+    out["subpool_gap"] = float(cost[mine].sum() - best.sum()) / mine.size
     return out

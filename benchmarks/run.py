@@ -49,8 +49,9 @@ def main(argv=None) -> int:
         print(f"benchmark FAILED: {e}", file=sys.stderr)
         return 1
     for name, check in result["checks"].items():
-        print(f"check {name}: {check['value']} (limit {check['limit']})",
-              file=sys.stderr)
+        held = ("a count, no limit" if check["limit"] is None
+                else f"limit {check['limit']}")
+        print(f"check {name}: {check['value']} ({held})", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
