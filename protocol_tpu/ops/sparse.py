@@ -418,7 +418,7 @@ def assign_auction_sparse(
     to the give-up level in eps-sized steps (millions of bid events at 32k);
     eps-scaling covers the same price range geometrically.
     """
-    state, _stall, _rows = _sparse_auction_phase(
+    state, _stall, _rows, _scans = _sparse_auction_phase(
         cand_provider, cand_cost, num_providers, None,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=retire,
     )
@@ -429,14 +429,23 @@ def assign_auction_sparse(
 # Frontier widths a round can run at, below the phase's own
 # ``min(frontier, T)``. Most rounds of a solve are eviction chains with
 # a few dozen open tasks, and a round's price gather is [width, K]: on a
-# v5e at T = P = 8,192, K = 80 a round takes 0.21 / 0.23 / 0.27 / 0.76 /
-# 2.34 ms at 32 / 64 / 128 / 1,024 / 4,096 rows, 0.18 of it whatever
-# the width, so each round takes the narrowest rung that holds every
-# open task. A warm tick's rounds there: 63% with at most 32 open,
-# 34% with 33-64, 2% with 65-128, under 1% with more. Five branches
-# cost the round no more than two do; a sixth added 0.013 ms to every
-# round (PERF.md section 5).
+# v5e at T = P = 8,192, K = 80 a round takes 0.051 / 0.071 / 0.119 /
+# 0.656 ms at 32 / 64 / 128 / 1,024 rows, bidding from the carried open
+# list, and 2.40 at 4,096 rows, scanning [T] (before the list, 0.204 /
+# 0.220 / 0.264 / 0.752 / 2.33); at the transposed shape (K = 256)
+# 0.095 / 0.147 / 0.262 / 1.71 (0.246 / 0.297 / 0.410 / 1.81). So each
+# round takes the narrowest rung that holds every open task. A warm
+# tick's rounds there: 63% with at most 32 open, 34% with 33-64, 2%
+# with 65-128, under 1% with more. Five branches cost the round no more
+# than two did; a sixth added 0.013 ms to every round (PERF.md section
+# 5, scripts/round_cost.py).
 _FRONTIER_RUNGS = (32, 64, 128, 1024)
+
+# A round of at most this many rows resolves its bids row against row
+# ([w, w]), not over [P]: 1.5-10 us a round less at 32-128 rows at both
+# shapes; a [1024, 1024] comparison is a million elements (PERF.md
+# section 5).
+_ROW_RESOLVE = 128
 
 
 @partial(
@@ -457,17 +466,34 @@ def _sparse_auction_phase(
 ):
     """One eps phase of the frontier auction; ``state`` carries
     (it, price, owner, p4t, retired) across phases for warm starts.
-    Returns (state, trailing no-progress rounds, frontier rows).
+    Returns (state, trailing no-progress rounds, frontier rows, scan
+    rounds).
 
     Every round counts its open tasks and bids at the narrowest width
     that holds them: a rung of ``_FRONTIER_RUNGS``, or ``min(frontier,
     T)`` when more are open than the widest rung below it holds (then
     the first ``frontier`` of them bid, in index order). A width that
-    holds every open task is invisible in the state: the open tasks are
-    listed in index order, the fill rows are dropped from every scatter,
-    the top-2 reductions are per row, and scatter-max / scatter-min take
-    no notice of order. The third result is the sum of the widths the
-    rounds ran at.
+    holds every open task is invisible in the state: the fill rows are
+    dropped from every scatter, the top-2 reductions are per row, and
+    the winner's max and min (over [P], or row against row at most
+    ``_ROW_RESOLVE`` rows) take no notice of order. The third result
+    is the sum of the widths the rounds ran at.
+
+    A round's work is indexed by its frontier, not by the pool. The
+    loop carries the open tasks as a list of the widest rung's size
+    ``C``, its length and the seated count, built by one pass over
+    ``[T]`` at entry. A round whose open tasks fit the list bids from
+    its front, commits by its rows (a winner writes its provider's
+    owner and price and its own seat, and clears the seat of the owner
+    it evicts) and leaves the next round's list in the same rows: each
+    row's task if it is still open, else the owner it evicted if that
+    one is open now; the list is in no order, which the round cannot
+    see. A round with more open tasks than ``C`` scans ``[T]`` for
+    them, commits over ``[P]`` and lists the open set again: the fourth
+    result counts those rounds. The list path reads the state's seats
+    through ``owner``, so it needs ``owner[p] = t >= 0`` only where
+    ``p4t[t] >= 0`` (what every caller's state holds: the kernel's own,
+    ``_invert``'s, ``_unassign_unhappy``'s and the passes' seeds).
 
     ``reserve`` (a scalar) is the reverse pass's floor, where the
     bidders are providers (see :func:`_forward_reverse`): a bidder whose
@@ -485,6 +511,7 @@ def _sparse_auction_phase(
     P = num_providers
     B = min(frontier, T)
     widths = tuple(w for w in _FRONTIER_RUNGS if w < B) + (B,)
+    C = widths[-2] if len(widths) > 1 else 0  # the open list's size
 
     cand_valid = cand_provider >= 0
     value_base = jnp.where(cand_valid, -cand_cost, _NEG)  # [T, K]
@@ -495,24 +522,28 @@ def _sparse_auction_phase(
     if reserve is not None:
         give_up = reserve + eps
 
+    def listed(p4t, retired):
+        """(open list, open count, seated count): one pass over [T]."""
+        open_mask = (p4t < 0) & task_feasible & ~retired
+        return (
+            jnp.flatnonzero(open_mask, size=C, fill_value=T).astype(jnp.int32),
+            jnp.sum(open_mask, dtype=jnp.int32),
+            jnp.sum(p4t >= 0, dtype=jnp.int32),
+        )
+
     def cond(loop):
-        (it, price, owner, p4t, retired), best, stall, rows = loop
-        go = (it < max_iters) & jnp.any((p4t < 0) & task_feasible & ~retired)
+        state, _best, stall, _rows, _scans, _lst, n_open, _n_seated = loop
+        go = (state[0] < max_iters) & (n_open > 0)
         if stall_limit > 0:
             go &= stall < stall_limit
         return go
 
-    def bid_and_resolve(width, price, retired, open_mask):
-        """The part of a round whose shapes follow the frontier width;
-        what it hands back ([P] winners, [T] retirements) does not."""
-        # ---- frontier selection: up to width open tasks (fill = T -> dropped)
+    def bid(f_idx, price):
+        """A frontier's bids (fill = T): (row ok, provider, newly
+        retired, bidding, amount)."""
         with jax.named_scope("auction.bid"):
-            f_idx = jnp.flatnonzero(
-                open_mask, size=width, fill_value=T
-            ).astype(jnp.int32)
             f_ok = f_idx < T
             p1, v1, v2 = frontier_bids(cand_safe, value_base, price, f_idx, f_ok, K)
-
             newly_retired = f_ok & (v1 < give_up)
             bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
             if reserve is None:
@@ -521,37 +552,36 @@ def _sparse_auction_phase(
                 bid_amt = price[p1] + jnp.minimum(
                     (v1 - v2) + eps, v1 - reserve
                 )
-            tgt = jnp.where(bidding, p1, P)
+        return f_ok, p1, newly_retired, bidding, bid_amt
 
+    def resolve(f_idx, p1, bidding, bid_amt):
+        """Per provider [P]: the best bid, and the lowest task index
+        among those that made it."""
         with jax.named_scope("auction.resolve"):
+            tgt = jnp.where(bidding, p1, P)
             win_bid = jnp.full(P, _NEG).at[tgt].max(
                 jnp.where(bidding, bid_amt, _NEG), mode="drop"
             )
-            # among max bidders per provider, lowest task index wins
             is_winner_bid = bidding & (bid_amt >= win_bid[p1])
             win_task = jnp.full(P, T, jnp.int32).at[tgt].min(
                 jnp.where(is_winner_bid, f_idx, T), mode="drop"
             )
+        return win_bid, win_task
 
+    def scan_round(state, lst, n_open, n_seated):
+        """More open tasks than the list holds: the first B in index
+        order bid, the commit runs over [P], the list is built anew."""
+        it, price, owner, p4t, retired = state
+        with jax.named_scope("auction.bid"):
+            f_idx = jnp.flatnonzero(
+                (p4t < 0) & task_feasible & ~retired, size=B, fill_value=T
+            ).astype(jnp.int32)
+        _, p1, newly_retired, bidding, bid_amt = bid(f_idx, price)
+        win_bid, win_task = resolve(f_idx, p1, bidding, bid_amt)
         with jax.named_scope("auction.commit"):
             retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(
                 True, mode="drop"
             )
-        return win_bid, win_task, retired
-
-    def body(loop):
-        state, best, stall, rows = loop
-        it, price, owner, p4t, retired = state
-        open_mask = (p4t < 0) & task_feasible & ~retired  # [T]
-        n_open = jnp.sum(open_mask, dtype=jnp.int32)
-        rung = sum((n_open > w).astype(jnp.int32) for w in widths[:-1])
-        win_bid, win_task, retired = lax.switch(
-            rung, [partial(bid_and_resolve, w) for w in widths],
-            price, retired, open_mask,
-        )
-        rows = rows + jnp.asarray(widths, jnp.int32)[rung]
-
-        with jax.named_scope("auction.commit"):
             got_bid = (win_bid > _NEG * 0.5) & (win_task < T)
             evict_t = jnp.where(got_bid & (owner >= 0), owner, T)
             p4t = p4t.at[evict_t].set(-1, mode="drop")
@@ -560,11 +590,85 @@ def _sparse_auction_phase(
             p4t = p4t.at[win_t_safe].set(jnp.where(got_bid, p_idx, -1), mode="drop")
             owner = jnp.where(got_bid, win_task, owner)
             price = jnp.where(got_bid, win_bid, price)
-            n_now = jnp.sum(p4t >= 0)
-            improved = n_now > best
-            best = jnp.maximum(best, n_now)
-            stall = jnp.where(improved, 0, stall + 1)
-        return (it + 1, price, owner, p4t, retired), best, stall, rows
+            lst, n_open, n_seated = listed(p4t, retired)
+        return (it, price, owner, p4t, retired), lst, n_open, n_seated
+
+    def resolve_rows(f_idx, p1, bidding, bid_amt):
+        """Row against row ([w, w]): per row, the best bid on its
+        provider, and the lowest task index among those that made it."""
+        with jax.named_scope("auction.resolve"):
+            rival = (
+                bidding[:, None] & bidding[None, :]
+                & (p1[:, None] == p1[None, :])
+            )
+            top = jnp.max(jnp.where(rival, bid_amt[None, :], _NEG), axis=1)
+            first = jnp.min(jnp.where(
+                rival & (bid_amt[None, :] >= top[:, None]), f_idx[None, :], T,
+            ), axis=1)
+        return top, first
+
+    def resolve_pool_rows(f_idx, p1, bidding, bid_amt):
+        """:func:`resolve` over [P], read at each row's provider."""
+        win_bid, win_task = resolve(f_idx, p1, bidding, bid_amt)
+        return win_bid[p1], win_task[p1]
+
+    def list_round(width, resolve_at, state, lst, n_open, n_seated):
+        """Every open task bids from the list's first ``width`` rows,
+        and the round commits by those rows."""
+        it, price, owner, p4t, retired = state
+        f_idx = lst[:width]
+        f_ok, p1, newly_retired, bidding, bid_amt = bid(f_idx, price)
+        top, first = resolve_at(f_idx, p1, bidding, bid_amt)
+        with jax.named_scope("auction.commit"):
+            retired = retired.at[jnp.where(newly_retired, f_idx, T)].set(
+                True, mode="drop"
+            )
+            # the row whose task took its provider, and the owner it evicts
+            won = bidding & (first == f_idx) & (top > _NEG * 0.5)
+            held = owner[p1]
+            evict = won & (held >= 0)
+            p4t = p4t.at[jnp.where(evict, held, T)].set(-1, mode="drop")
+            p4t = p4t.at[jnp.where(won, f_idx, T)].set(p1, mode="drop")
+            seat = jnp.where(won, p1, P)
+            owner = owner.at[seat].set(f_idx, mode="drop")
+            price = price.at[seat].set(top, mode="drop")
+            n_seated = n_seated + jnp.sum(won & ~evict, dtype=jnp.int32)
+            # next round's list, a row each: its task, still open, or
+            # the owner it evicted, open now
+            h = jnp.maximum(held, 0)
+            keep = jnp.where(
+                won, evict & task_feasible[h] & ~retired[h], f_ok & ~newly_retired
+            )
+            pos = jnp.cumsum(keep, dtype=jnp.int32) - 1
+            packed = jnp.full(width, T, jnp.int32).at[
+                jnp.where(keep, pos, width)
+            ].set(jnp.where(won, held, f_idx), mode="drop")
+            lst = lax.dynamic_update_slice(lst, packed, (0,))
+            n_open = pos[-1] + 1
+        return (it, price, owner, p4t, retired), lst, n_open, n_seated
+
+    branches = [
+        partial(list_round, w, (
+            resolve_rows if w <= _ROW_RESOLVE else resolve_pool_rows
+        ))
+        for w in widths[:-1]
+    ] + [scan_round]
+
+    def body(loop):
+        state, best, stall, rows, scans, lst, n_open, n_seated = loop
+        rung = sum(
+            ((n_open > w).astype(jnp.int32) for w in widths[:-1]), jnp.int32(0)
+        )
+        state, lst, n_open, n_seated = lax.switch(
+            rung, branches, state, lst, n_open, n_seated
+        )
+        rows = rows + jnp.asarray(widths, jnp.int32)[rung]
+        scans = scans + (rung == len(widths) - 1).astype(jnp.int32)
+        improved = n_seated > best
+        best = jnp.maximum(best, n_seated)
+        stall = jnp.where(improved, 0, stall + 1)
+        state = (state[0] + 1,) + tuple(state[1:])
+        return state, best, stall, rows, scans, lst, n_open, n_seated
 
     if state is None:
         state = (
@@ -577,9 +681,13 @@ def _sparse_auction_phase(
     else:
         # reset the iteration counter for this phase
         state = (jnp.int32(0),) + tuple(state[1:])
-    loop0 = (state, jnp.sum(state[3] >= 0), jnp.int32(0), jnp.int32(0))
-    out, _best, stall, rows = lax.while_loop(cond, body, loop0)
-    return out, stall, rows
+    lst, n_open, n_seated = listed(state[3], state[4])
+    loop0 = (state, n_seated, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+             lst, n_open, n_seated)
+    out, _best, stall, rows, scans, _lst, _n_open, _n_seated = lax.while_loop(
+        cond, body, loop0
+    )
+    return out, stall, rows, scans
 
 
 @jax.jit
@@ -787,14 +895,15 @@ def _forward_reverse(
     an ``auction.reverse`` span. ``stats_out`` gains ``free_repriced``
     (providers the pass lowered), ``reverse_rounds``, ``reverse_ms`` and
     ``frontier_rows`` (the widths every round of the phase ran at, both
-    directions, summed).
+    directions, summed) and ``scan_rounds`` (the rounds that scanned
+    ``[T]`` for their open tasks, both directions).
     Returns (state, stall, rounds of the forward phase)."""
     if reserve is not None:
         return _queue_phase(
             cand_provider, cand_cost, num_providers, state, eps, reserve,
             max_iters, frontier, stall_limit, stats_out, transposed,
         )
-    state, stall, rows = _phase_adaptive(
+    state, stall, rows, scans = _phase_adaptive(
         cand_provider, cand_cost, num_providers, state,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
         stall_limit=stall_limit, stats_out=stats_out,
@@ -810,7 +919,7 @@ def _forward_reverse(
         )
         if sp is not None:
             sp["attrs"].update(stranded=n_stranded, slack=slack)
-    lowered = reverse_rounds = reverse_rows = 0
+    lowered = reverse_rounds = reverse_rows = reverse_scans = 0
     if n_stranded > 0 and slack > 0 and slack * _SLACK_SHARE >= listed:
         with _tracer.span("auction.reverse", step="seed", dispatch_only=True):
             if not transposed:
@@ -824,7 +933,7 @@ def _forward_reverse(
         # every stranded provider may bid at once: the kernel fits each
         # round's width to the ones still open, and one executable serves
         # every count of them
-        rstate, _, reverse_rows = _phase_adaptive(
+        rstate, _, reverse_rows, reverse_scans = _phase_adaptive(
             transposed[0], transposed[1], p4t.shape[0], rstate,
             eps=eps, max_iters=20000, frontier=num_providers, retire=True,
             stall_limit=0, reserve=floor, span="auction.reverse",
@@ -842,6 +951,7 @@ def _forward_reverse(
             ("reverse_rounds", reverse_rounds),
             ("reverse_ms", (time.perf_counter() - t0) * 1e3),
             ("frontier_rows", rows + reverse_rows),
+            ("scan_rounds", scans + reverse_scans),
         ):
             stats_out[key] = round(stats_out.get(key, 0) + value, 3)
     return state, stall, rounds
@@ -977,7 +1087,7 @@ def _queue_phase(
         n_free = int(_queue_free(cand_provider, owner, num_providers))
         if sp is not None:
             sp["attrs"].update(free=n_free)
-    queue_rounds = queue_rows = 0
+    queue_rounds = queue_rows = queue_scans = 0
     if n_free > 0:
         floor = jnp.float32(0.0)
         with _tracer.span("auction.queue", step="seed", dispatch_only=True):
@@ -990,7 +1100,7 @@ def _queue_phase(
                 jnp.float32(reserve),
             )
         profit0 = rstate[1]
-        rstate, _, queue_rows = _phase_adaptive(
+        rstate, _, queue_rows, queue_scans = _phase_adaptive(
             transposed[0], transposed[1], T, rstate,
             eps=eps, max_iters=20000, frontier=num_providers, retire=True,
             stall_limit=0, reserve=floor, span="auction.queue",
@@ -1003,7 +1113,7 @@ def _queue_phase(
     queue_ms = (time.perf_counter() - t0) * 1e3
     # who waits is decided anew under this phase's prices
     state = (it, price, owner, p4t, jnp.zeros(T, bool))
-    state, stall, rows = _phase_adaptive(
+    state, stall, rows, scans = _phase_adaptive(
         cand_provider, cand_cost, num_providers, state,
         eps=eps, max_iters=max_iters, frontier=frontier, retire=True,
         stall_limit=stall_limit, stats_out=stats_out,
@@ -1015,6 +1125,7 @@ def _queue_phase(
             ("free_repriced", 0), ("reverse_rounds", 0), ("reverse_ms", 0.0),
             ("queue_rounds", queue_rounds), ("queue_ms", queue_ms),
             ("frontier_rows", rows + queue_rows),
+            ("scan_rounds", scans + queue_scans),
         ):
             stats_out[key] = round(stats_out.get(key, 0) + value, 3)
     return state, stall, rounds
@@ -1237,11 +1348,14 @@ def _phase_adaptive(
 
     Each segment is one ``span`` span, ``auction.segment`` unless the
     reverse pass names its own (the finest grain the solve is traced
-    at: nothing per round), with the attr ``rows``, the widths its
-    rounds ran at, summed; ``reserve`` goes to the kernel as it is.
+    at: nothing per round), with the attrs ``rows``, the widths its
+    rounds ran at, summed, and ``scans``, its rounds that scanned
+    ``[T]`` for their open tasks; ``reserve`` goes to the kernel as it
+    is. The four scalars a segment ends in are one read.
     ``stats_out`` gains ``segments`` and ``wait_ms``, the time the host
     spent inside the segment's blocking scalar reads — its view of the
-    device's time. Returns (state, accumulated stall, the phase's rows).
+    device's time. Returns (state, accumulated stall, the phase's rows,
+    its scan rounds).
     """
     seg_rounds = 256
     T = cand_cost.shape[0]
@@ -1249,23 +1363,25 @@ def _phase_adaptive(
     iters_left = max_iters
     total_it = 0
     total_rows = 0
+    total_scans = 0
     B = min(frontier, T)
     carried_stall = 0
     segments = 0
     wait_s = 0.0
     while iters_left > 0:
         with _tracer.span(span, frontier=B) as seg:
-            state, stall, rows = _sparse_auction_phase(
+            state, stall, rows, scans = _sparse_auction_phase(
                 cand_provider, cand_cost, num_providers, state,
                 eps=eps, max_iters=seg_rounds, frontier=B, retire=retire,
                 stall_limit=0, reserve=reserve,
             )
             t_wait = time.perf_counter()
-            it = int(state[0])
-            s = int(stall)
-            seg_rows = int(rows)
+            it, s, seg_rows, seg_scans = (
+                int(n) for n in jax.device_get((state[0], stall, rows, scans))
+            )
             total_it += it
             total_rows += seg_rows
+            total_scans += seg_scans
             iters_left -= it
             carried_stall = carried_stall + it if s >= it else s
             # the phase goes on only after a full segment under the
@@ -1285,8 +1401,8 @@ def _phase_adaptive(
             wait_s += waited
             if seg is not None:
                 seg["attrs"].update(
-                    rounds=it, rows=seg_rows, open_count=open_count,
-                    wait_ms=round(waited * 1e3, 3),
+                    rounds=it, rows=seg_rows, scans=seg_scans,
+                    open_count=open_count, wait_ms=round(waited * 1e3, 3),
                 )
         if open_count == 0:
             break
@@ -1299,7 +1415,7 @@ def _phase_adaptive(
     # segment alone can never reach a limit > seg_rounds). Host scalars:
     # the callers' ``int()`` of them costs no trip to the device
     state = (np.int32(total_it),) + tuple(state[1:])
-    return state, np.int32(carried_stall), total_rows
+    return state, np.int32(carried_stall), total_rows, total_scans
 
 
 def _report_stall(kind: str, stall, limit: int, stats_out: dict | None) -> None:

@@ -292,7 +292,8 @@ class TestRealTree:
         (``_phase_adaptive``) and by the one-phase ``assign_auction_sparse``
         and nowhere else in the program, the scripts or the entry
         points: the eps ladder, the warm solve and the reverse pass all
-        go through the one loop."""
+        go through the one loop. (The round-cost microbench times the
+        kernel itself and drives no solve.)"""
         import ast
 
         from scripts.analysis.callgraph import Index
@@ -310,6 +311,7 @@ class TestRealTree:
         assert users == {
             "protocol_tpu/ops/sparse.py::_phase_adaptive",
             "protocol_tpu/ops/sparse.py::assign_auction_sparse",
+            "scripts/round_cost.py::main",
         }
 
     def test_cli_clean_and_exit_codes(self):

@@ -299,10 +299,29 @@ class TestSpanTree:
             a = s["attrs"]
             low = min(_FRONTIER_RUNGS[0], a["frontier"])
             assert a["rounds"] * low <= a["rows"] <= a["rounds"] * a["frontier"]
+            # a round that scanned [T] ran at the top width
+            assert 0 <= a["scans"] <= a["rounds"]
+            assert a["rows"] >= a["scans"] * a["frontier"]
         assert isinstance(stats["eng_frontier_rows"], int)
         assert stats["eng_frontier_rows"] == sum(
             s["attrs"]["rows"] for s in segs
         ) > 0
+
+    def test_segment_spans_count_the_scans(self, served):
+        """Every segment span says how many of its rounds scanned
+        ``[T]`` for their open tasks (more of them than the open list
+        holds), and the solve's sum rides ``last_stats``."""
+        stats = served.stats()
+        segs = [
+            s["attrs"] for s in served.spans
+            if s["name"] in ("auction.segment", "auction.reverse",
+                             "auction.queue")
+            and "rounds" in s["attrs"]
+        ]
+        assert segs and all("scans" in a for a in segs)
+        assert isinstance(stats["eng_scan_rounds"], int)
+        assert stats["eng_scan_rounds"] == sum(a["scans"] for a in segs)
+        assert stats["eng_scan_rounds"] < sum(a["rounds"] for a in segs)
 
     def test_reverse_spans_count_the_pass(self, served):
         """Every solve checks for stranded providers and for slack (one
@@ -389,6 +408,9 @@ class TestSpanTree:
             )
             assert stats["eng_frontier_rows"] == sum(
                 a["rows"] for a in segs + forward
+            )
+            assert stats["eng_scan_rounds"] == sum(
+                a["scans"] for a in segs + forward
             )
             assert stats["eng_queue_ms"] >= sum(
                 s["dur_ns"] for s in queue
@@ -642,7 +664,7 @@ class TestScopeNames:
             assert scope in lowered.as_text(debug_info=True), scope
 
 
-# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one, ISSUE 32's two and ISSUE 33's five that read counters,
+# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one, ISSUE 32's two, ISSUE 33's five and ISSUE 39's one that read counters,
 # read through the benchmark's own generic reader from canned contexts (data files only: no reader code)
 
 _ACKS = [
@@ -655,7 +677,7 @@ _ACKS = [
      "eng_reverse_ms": 30.0, "eng_frontier_rows": 300000,
      "rep_readback_bytes": 6000000, "rep_syncs": 4,
      "eng_waiting_tasks": 1638, "eng_queue_rounds": 30,
-     "eng_queue_ms": 60.0, "waiting_excess": 4.0},
+     "eng_queue_ms": 60.0, "waiting_excess": 4.0, "eng_scan_rounds": 20},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
@@ -665,7 +687,7 @@ _ACKS = [
      "eng_reverse_ms": 50.0, "eng_frontier_rows": 340000,
      "rep_readback_bytes": 7000000, "rep_syncs": 3,
      "eng_waiting_tasks": 1640, "eng_queue_rounds": 50,
-     "eng_queue_ms": 80.0, "waiting_excess": 6.0},
+     "eng_queue_ms": 80.0, "waiting_excess": 6.0, "eng_scan_rounds": 40},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
@@ -760,6 +782,9 @@ METRICS = {
     "queued_gap_per_task": (
         "quality pass", "cost/task", "program_counter", "gap_per_task",
         0.011),
+    "scan_rounds_per_ack": (
+        "auction solve", "rounds", "program_counter", "eng_scan_rounds",
+        30.0),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -779,9 +804,10 @@ CELLS.update(dict.fromkeys(
      "waiting_excess_per_ack", "queued_gap_per_task"),
     ["pool-queued.ticks"],
 ))
-CELLS["ckpt_join_ms_per_ack"] = [
-    "pool-large.ticks", "pool-slack.ticks", "pool-queued.ticks",
-]
+CELLS.update(dict.fromkeys(
+    ("ckpt_join_ms_per_ack", "scan_rounds_per_ack"),
+    ["pool-large.ticks", "pool-slack.ticks", "pool-queued.ticks"],
+))
 
 
 def _without(key: str) -> dict:

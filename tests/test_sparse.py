@@ -274,7 +274,7 @@ class TestStallDetection:
         # 3 tasks fighting over 2 providers: one permanent hole
         cand_p = jnp.asarray([[0, 1], [0, 1], [0, 1]], jnp.int32)
         cand_c = jnp.asarray([[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32)
-        state, stall, _rows = _sparse_auction_phase(
+        state, stall, _rows, _scans = _sparse_auction_phase(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=5000,
             frontier=4, retire=False, stall_limit=16,
         )
@@ -291,7 +291,7 @@ class TestStallDetection:
 
         cand_p = jnp.asarray([[0, 1], [0, 1], [0, 1]], jnp.int32)
         cand_c = jnp.asarray([[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32)
-        state, _stall, _rows = _sparse_auction_phase(
+        state, _stall, _rows, _scans = _sparse_auction_phase(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=300,
             frontier=4, retire=False, stall_limit=0,
         )
@@ -467,7 +467,7 @@ class TestAdaptiveFrontierLadder:
         cand_c = jnp.asarray(
             [[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32
         )
-        state, stall, _rows = _phase_adaptive(
+        state, stall, _rows, _scans = _phase_adaptive(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=100_000,
             frontier=4, retire=False, stall_limit=600,
         )
@@ -528,7 +528,7 @@ class TestAdaptiveFrontierLadder:
         assert set(stats) == {
             "rounds_total", "segments", "wait_ms", "frontier_rows",
             "stall_exit", "stall_rounds", "reverse_rounds", "reverse_ms",
-            "free_repriced", "queue_rounds", "queue_ms",
+            "free_repriced", "queue_rounds", "queue_ms", "scan_rounds",
         }
         assert stats["queue_rounds"] == 0  # (no queue in this pool)
         assert stats["segments"] >= 1 and stats["rounds_total"] >= 1
@@ -537,6 +537,9 @@ class TestAdaptiveFrontierLadder:
             stats["rounds_total"] + stats["reverse_rounds"]
         )
         assert stats["stall_exit"] is False
+        assert 0 <= stats["scan_rounds"] <= (
+            stats["rounds_total"] + stats["reverse_rounds"]
+        )
 
     def test_phase_stops_within_a_segment_of_max_iters(self):
         """The budget is honoured at segment granularity: a phase that
@@ -549,13 +552,15 @@ class TestAdaptiveFrontierLadder:
             [[1.0, 2.0], [1.1, 2.1], [1.2, 2.2]], jnp.float32
         )
         stats: dict = {}
-        state, _stall, rows = _phase_adaptive(
+        state, _stall, rows, scans = _phase_adaptive(
             cand_p, cand_c, 2, None, eps=0.5, max_iters=300,
             frontier=4, retire=False, stall_limit=0, stats_out=stats,
         )
         assert 300 <= int(state[0]) < 300 + 256
         assert stats["segments"] == 2
         assert rows == 3 * int(state[0])  # T = 3 is the only width
+        # no rung below it, so no open list: every round scans [T]
+        assert scans == int(state[0])
 
 
 @partial(jax.jit, static_argnames=("num_providers", "width", "retire"))
@@ -687,7 +692,7 @@ def _rung_case(name):
                     cp, cc, price, owner, p4t, eps
                 )
                 state = (jnp.int32(0), price, owner, p4t, jnp.zeros(T, bool))
-            state, _stall, _rows = sparse._sparse_auction_phase(
+            state, _stall, _rows, _scans = sparse._sparse_auction_phase(
                 cp, cc, P, state, eps=eps, max_iters=4000, frontier=4096,
             )
             _, price, owner, p4t, _ = state
@@ -707,13 +712,62 @@ def _rung_case(name):
             stall_limit=0,
         )
     # a converged phase, then a handful of tasks unseated on its prices
-    state, _stall, _rows = sparse._sparse_auction_phase(
+    state, _stall, _rows, _scans = sparse._sparse_auction_phase(
         cp, cc, P, None, eps=0.02, max_iters=20000, frontier=4096,
     )
     _, price, _owner, p4t, retired = state
     p4t = p4t.at[jnp.asarray([3, 77, 500, 1200, 2000])].set(-1)
     state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t, retired)
     if name == "warm":
+        return cp, cc, P, state, dict(
+            eps=0.02, max_iters=600, frontier=4096, retire=True,
+            stall_limit=0,
+        )
+    if name == "wide":
+        # 1,500 tasks unseated on converged prices, the list's size
+        # (1,024 at B = 2,048) and more: the first rounds scan [T], and
+        # the list is built from the state a scan leaves
+        p4t = p4t.at[jnp.arange(0, 2048, 4)].set(-1).at[1::3].set(-1)
+        state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t,
+                 jnp.zeros(T, bool))
+        return cp, cc, P, state, dict(
+            eps=0.02, max_iters=600, frontier=4096, retire=True,
+            stall_limit=0,
+        )
+    if name == "retired_seat":
+        # every third seated task flagged retired, as a carried mask may
+        # be: one that is evicted is not open, so not listed
+        flags = jnp.zeros(T, bool).at[::3].set(True) & (p4t >= 0)
+        p4t = p4t.at[1::16].set(-1)
+        state = (jnp.int32(0), price + 0.0, sparse._invert(p4t, P), p4t,
+                 flags & (p4t >= 0))
+        return cp, cc, P, state, dict(
+            eps=0.02, max_iters=600, frontier=4096, retire=True,
+            stall_limit=0,
+        )
+    if name == "reserve":
+        # the forward phase of a pool with a queue: bids capped by the
+        # reserve, a task whose best seat is not worth eps more waits;
+        # the seats the unseated tasks left are priced up, so that they
+        # bid for seats that are held
+        gone = jnp.arange(0, T, 8)
+        price = price.at[jnp.maximum(p4t[gone], 0)].add(3.0)
+        p4t = p4t.at[gone].set(-1)
+        level = float(jnp.median(price))
+        state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t,
+                 jnp.zeros(T, bool))
+        return cp, cc, P, state, dict(
+            eps=0.02, max_iters=600, frontier=4096, retire=True,
+            stall_limit=0, reserve=jnp.float32(-(level + 0.1)),
+        )
+    if name == "no_candidates":
+        # 96 rows list no provider (a session's padded rows): never
+        # open, so never listed, beside the open ones
+        empty = jnp.arange(7, 2048, 21)[:96]
+        cp = cp.at[empty].set(-1)
+        p4t = p4t.at[empty].set(-1).at[::8].set(-1)
+        state = (jnp.int32(0), price, sparse._invert(p4t, P), p4t,
+                 jnp.zeros(T, bool))
         return cp, cc, P, state, dict(
             eps=0.02, max_iters=600, frontier=4096, retire=True,
             stall_limit=0,
@@ -736,13 +790,16 @@ class TestFrontierRungs:
     (``_FRONTIER_RUNGS``); a width that holds every open task must be
     invisible in the state."""
 
-    @pytest.mark.parametrize("name", ["cold", "warm", "stall", "reverse"])
+    @pytest.mark.parametrize("name", [
+        "cold", "warm", "stall", "reverse", "wide", "retired_seat",
+        "reserve", "no_candidates",
+    ])
     def test_state_equals_the_single_width_round(self, name):
         from protocol_tpu.ops import sparse
 
         cp, cc, P, state, kw = _rung_case(name)
         want, want_stall, opens = _plain_phase(cp, cc, P, state, **kw)
-        got, got_stall, rows = sparse._sparse_auction_phase(
+        got, got_stall, rows, scans = sparse._sparse_auction_phase(
             cp, cc, P, state, **kw
         )
         assert len(opens) == int(got[0]) > 0
@@ -756,6 +813,31 @@ class TestFrontierRungs:
         widths = [w for w in sparse._FRONTIER_RUNGS if w < top] + [top]
         ran_at = [next((w for w in widths if n <= w), top) for n in opens]
         assert int(rows) == sum(ran_at)
+        # the rounds whose open tasks the list could not hold scanned [T]
+        cap = widths[-2] if len(widths) > 1 else 0
+        assert int(scans) == sum(n > cap for n in opens)
+        p4t1 = np.asarray(got[3])
+        p4t0 = p4t1 if state is None else np.asarray(state[3])
+        if name in ("cold", "wide"):
+            # a scan first, then the list it built
+            assert opens[0] > cap >= min(opens), (opens[0], cap)
+            assert 0 < int(scans) < len(opens)
+        if name == "wide":
+            assert len([n for n in opens if n <= cap]) >= 3
+        if name == "retired_seat":
+            # some flagged seat was taken, and its task stays shut out
+            flags = np.asarray(state[4])
+            evicted = flags & (p4t0 >= 0) & (p4t1 < 0)
+            assert evicted.any() and np.asarray(got[4])[evicted].all()
+        if name == "reserve":
+            # bids evicted seated tasks, and some tasks wait
+            assert ((p4t0 >= 0) & (p4t1 != p4t0)).any()
+            assert (p4t1 < 0).sum() > 0
+        if name == "no_candidates":
+            empty = ~np.asarray(jnp.any(cp >= 0, axis=1))
+            assert empty.sum() == 96 and (p4t1[empty] < 0).all()
+            assert not np.asarray(got[4])[empty].any()
+            assert opens[0] == int(((p4t0 < 0) & ~empty).sum())
         # the case is the shape it says it is, and crosses rungs (but
         # the warm one: a handful open, the narrowest rung all along)
         assert len(set(ran_at)) >= (1 if name == "warm" else 2), set(ran_at)
