@@ -282,20 +282,25 @@ class _PrefixJob:
         self.arena = tfmt.FrameDeflater(CKPT_COMPRESSLEVEL)
         self.head = b""
         self.overlap_ms = 0.0
+        # the job's wall on the worker, and of it the SNAPSHOT message
+        # built and serialised (``encode_ms``): set when it has run
+        self.took: dict = {}
         self.dropped = False
         self.future = None
 
     def run(self) -> None:
-        with _tracer.span(
-            "ckpt.prefix", remote_parent=self.parent or None,
-            tick=self.tick,
+        with _tracer.stage(
+            "ckpt.prefix", self.took, "worker_ms",
+            remote_parent=self.parent or None, tick=self.tick,
         ) as span:
-            self.snapshot.feed(tfmt.snapshot_payload(
-                self.session_id, self.fingerprint,
-                _snapshot_request(
-                    self.p_cols, self.r_cols, self.kernel, self.top_k
-                ),
-            ))
+            with _tracer.stage("ckpt.encode", self.took, "encode_ms"):
+                payload = tfmt.snapshot_payload(
+                    self.session_id, self.fingerprint,
+                    _snapshot_request(
+                        self.p_cols, self.r_cols, self.kernel, self.top_k
+                    ),
+                )
+            self.snapshot.feed(payload)
             self.snapshot.finish()
             self.head, arrays = tfmt.pack_plan(self.live, self.last)
             self.arena.feed(self.head)
@@ -359,7 +364,9 @@ class SessionCheckpointer:
         # deflate_ms, bytes_raw, bytes_out = the journal's size on
         # disk; prefix = hit / miss / stale / error, join_ms = its wait
         # for the worker, overlap_ms = the zlib time a hit took off the
-        # flush); the servicer, which owns the seam, records it
+        # flush; worker_ms / encode_ms = the wall of the job it found
+        # run, hit or stale, and of its SNAPSHOT message); the
+        # servicer, which owns the seam, records it
         self.last_flush: dict = {}
         # prefix jobs: at most one a session, all on one worker thread
         # (started with the first job). No lock: every access is one
@@ -460,7 +467,8 @@ class SessionCheckpointer:
         or it had not begun to run: sessions share the one worker);
         ``stale`` (built for another tick, or from objects the session
         no longer holds); ``error`` (it raised). ``took["join_ms"]`` is
-        the wait for a running job."""
+        the wait for a running job; a job that ran to its end, hit or
+        stale, also gives its ``worker_ms`` and ``encode_ms``."""
         took.update(prefix="miss", join_ms=0.0, overlap_ms=0.0)
         if hasattr(session.arena, "structure_hook"):
             session.arena.structure_hook = None
@@ -479,6 +487,7 @@ class SessionCheckpointer:
             return None
         finally:
             took["join_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+        took.update(job.took)
         head, _arrays = tfmt.pack_plan(state, last)
         if not job.fits_locked(session, session.arena.live_state(), head):
             took["prefix"] = "stale"

@@ -60,6 +60,8 @@ from typing import Optional
 
 import numpy as np
 
+from protocol_tpu.obs.spans import TRACER as _tracer
+
 # mirrors ops/cost.py INFEASIBLE without importing the jax-backed module
 # (the quality pass runs in control-plane processes with no backend)
 _INFEASIBLE = 1e9
@@ -369,6 +371,7 @@ def tick_quality(
     starve_age: Optional[np.ndarray] = None,
     outcomes: Optional[dict] = None,
     eng: Optional[dict] = None,
+    took: Optional[dict] = None,
 ) -> tuple[dict, np.ndarray]:
     """One tick's full quality record: (flat stats dict, new starvation
     ages). The arena calls this once per solve with the obs plane on;
@@ -378,7 +381,9 @@ def tick_quality(
     When the engine's certificate scalars (``plan_cost`` /
     ``cs_slack`` / ``idle_price`` in ``eng``) are in hand the gap is
     assembled in O(1) from them; otherwise the O(T*K) reference
-    :func:`duality_gap` scan runs (the jax path, tests).
+    :func:`duality_gap` scan runs (the jax path, tests). Either is a
+    ``quality.gap`` span whose wall lands in ``took["q_gap_ms"]``
+    (a dict of the caller's: the record itself holds no clock).
     """
     stats: dict = {}
     have_cert = (
@@ -387,23 +392,25 @@ def tick_quality(
         and "idle_price" in eng
         and "cs_slack" in eng
     )
-    if have_cert:
-        stats.update(gap_from_certificate(
-            p4t, eng["plan_cost"], eng["cs_slack"], eng["idle_price"],
-            eng.get("waiting_excess", 0.0),
-        ))
-        # an engine whose margin pass does not count a queue: where the
-        # plan has one, the scan's certificate takes the place of the
-        # engine's (O(T*K) there, O(waiting x K) to find out)
-        if (
-            price is not None and "waiting_excess" not in eng
-            and queue_rows(
-                cand_p, cand_c, p4t, np.asarray(price).shape[0]
-            )[0].size
-        ):
+    with _tracer.stage("quality.gap", {} if took is None else took,
+                       "q_gap_ms"):
+        if have_cert:
+            stats.update(gap_from_certificate(
+                p4t, eng["plan_cost"], eng["cs_slack"], eng["idle_price"],
+                eng.get("waiting_excess", 0.0),
+            ))
+            # an engine whose margin pass does not count a queue: where
+            # the plan has one, the scan's certificate takes the place
+            # of the engine's (O(T*K) there, O(waiting x K) to find out)
+            if (
+                price is not None and "waiting_excess" not in eng
+                and queue_rows(
+                    cand_p, cand_c, p4t, np.asarray(price).shape[0]
+                )[0].size
+            ):
+                stats.update(duality_gap(cand_p, cand_c, p4t, price))
+        elif price is not None:
             stats.update(duality_gap(cand_p, cand_c, p4t, price))
-    elif price is not None:
-        stats.update(duality_gap(cand_p, cand_c, p4t, price))
     if prev_p4t is not None and np.asarray(prev_p4t).shape == np.asarray(
         p4t
     ).shape:

@@ -1243,7 +1243,8 @@ def assign_auction_sparse_scaled(
     """
     state = None
     # (lists held on the host go up while the host counts them)
-    lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    with _tracer.span("auction.upload", dispatch_only=True):
+        lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
     reserve = _queue_reserve(
         cand_provider, cand_cost, num_providers, None, stats_out
     )
@@ -1351,10 +1352,13 @@ def _phase_adaptive(
     at: nothing per round), with the attrs ``rows``, the widths its
     rounds ran at, summed, and ``scans``, its rounds that scanned
     ``[T]`` for their open tasks; ``reserve`` goes to the kernel as it
-    is. The four scalars a segment ends in are one read.
+    is. The four scalars a segment ends in are one read; after a full
+    segment the host counts the tasks still open, an eager ``[T]`` sum
+    and its read, under an ``auction.open_count`` span.
     ``stats_out`` gains ``segments`` and ``wait_ms``, the time the host
     spent inside the segment's blocking scalar reads — its view of the
-    device's time. Returns (state, accumulated stall, the phase's rows,
+    device's time — and ``open_read_ms``, the open counts' share of it.
+    Returns (state, accumulated stall, the phase's rows,
     its scan rounds).
     """
     seg_rounds = 256
@@ -1368,6 +1372,7 @@ def _phase_adaptive(
     carried_stall = 0
     segments = 0
     wait_s = 0.0
+    open_s = 0.0
     while iters_left > 0:
         with _tracer.span(span, frontier=B) as seg:
             state, stall, rows, scans = _sparse_auction_phase(
@@ -1393,9 +1398,12 @@ def _phase_adaptive(
             if it == seg_rounds and not (
                 stall_limit > 0 and carried_stall >= stall_limit
             ):
-                open_count = int(
-                    jnp.sum((state[3] < 0) & ~state[4] & task_feasible)
-                )
+                t_open = time.perf_counter()
+                with _tracer.span("auction.open_count"):
+                    open_count = int(
+                        jnp.sum((state[3] < 0) & ~state[4] & task_feasible)
+                    )
+                open_s += time.perf_counter() - t_open
             waited = time.perf_counter() - t_wait
             segments += 1
             wait_s += waited
@@ -1409,6 +1417,9 @@ def _phase_adaptive(
     if stats_out is not None:
         stats_out["segments"] = stats_out.get("segments", 0) + segments
         stats_out["wait_ms"] = stats_out.get("wait_ms", 0.0) + wait_s * 1e3
+        stats_out["open_read_ms"] = (
+            stats_out.get("open_read_ms", 0.0) + open_s * 1e3
+        )
     # report the PHASE's total rounds in the state's counter slot (each
     # segment resets it; the ladder's rounds_total sums these) and the
     # ACCUMULATED stall so _report_stall sees breaker trips (the last
@@ -1499,7 +1510,8 @@ def assign_auction_sparse_warm(
     ``with_state=True``.
     """
     # (lists held on the host go up while the host counts them)
-    lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    with _tracer.span("auction.upload", dispatch_only=True):
+        lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
     reserve = _queue_reserve(
         cand_provider, cand_cost, num_providers, reserve0, stats_out
     )
@@ -1515,26 +1527,26 @@ def assign_auction_sparse_warm(
     if reserve is None and reserve0 is not None:
         retired0 = None
     cand_provider, cand_cost = lists
-    # a seed for a task with NO candidates would sail through the eps-CS
-    # repair (vcur == v1 == -inf is not "unhappy") and emerge as an
-    # infeasible pair in the final matching — drop such seeds outright
-    task_has_cand = jnp.any(cand_provider >= 0, axis=1)
-    p4t0 = jnp.where(task_has_cand, p4t0, -1)
-    # Forward auctions only raise prices, and carried prices compound
-    # across warm solves. The retirement floor is give_up =
-    # -(2*max_cost + 10); keep the worst seeded value -max_cost - price
-    # ABOVE the floor by SHIFTING all prices down uniformly until
-    # max(price) <= max_cost + 5. A constant shift changes no value
-    # difference, so it preserves the entire price landscape (who
-    # outbids whom, who is unhappy) — unlike a clamp, which flattens the
-    # top of the distribution, i.e. exactly the contended providers:
-    # measured at 65k, min-clamping capped 65,535/65,536 prices and the
-    # eps-CS repair then evicted 59,997 seeds for 655 churned tasks,
-    # making "warm" a from-scratch fine-eps solve (the r4 0.2x
-    # regression). Negative prices are fine: the auction only ever
-    # compares price DIFFERENCES (values -cost - price and bid
-    # increments), never absolute levels.
     with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
+        # a seed for a task with NO candidates would sail through the eps-CS
+        # repair (vcur == v1 == -inf is not "unhappy") and emerge as an
+        # infeasible pair in the final matching — drop such seeds outright
+        task_has_cand = jnp.any(cand_provider >= 0, axis=1)
+        p4t0 = jnp.where(task_has_cand, p4t0, -1)
+        # Forward auctions only raise prices, and carried prices compound
+        # across warm solves. The retirement floor is give_up =
+        # -(2*max_cost + 10); keep the worst seeded value -max_cost - price
+        # ABOVE the floor by SHIFTING all prices down uniformly until
+        # max(price) <= max_cost + 5. A constant shift changes no value
+        # difference, so it preserves the entire price landscape (who
+        # outbids whom, who is unhappy) — unlike a clamp, which flattens the
+        # top of the distribution, i.e. exactly the contended providers:
+        # measured at 65k, min-clamping capped 65,535/65,536 prices and the
+        # eps-CS repair then evicted 59,997 seeds for 655 churned tasks,
+        # making "warm" a from-scratch fine-eps solve (the r4 0.2x
+        # regression). Negative prices are fine: the auction only ever
+        # compares price DIFFERENCES (values -cost - price and bid
+        # increments), never absolute levels.
         finite_max = jnp.max(jnp.where(cand_provider >= 0, cand_cost, 0.0))
         price0 = jnp.asarray(price0, jnp.float32)
         if reserve is None:

@@ -666,6 +666,7 @@ class JaxSolveArena:
                 starve_age=self._starve_age,
                 outcomes=None,
                 eng=eng,
+                took=took,
             )
         stats.update(took)
         self._last_quality = stats
@@ -947,6 +948,8 @@ class JaxSolveArena:
                     rf["valid"].astype(bool),
                 )
                 qual = dict(self._last_quality)
+                # the carried certificate: no gap was computed
+                qual["q_gap_ms"] = 0.0
                 qual["churn_rows"] = 0
                 qual["churn_ratio"] = 0.0
                 qual["starve_max"] = (

@@ -798,8 +798,10 @@ class SchedulerBackendServicer:
         record what the flush cost on the seam — phases ``ckpt_flush``,
         ``ckpt_export``, ``ckpt_deflate`` (zlib time inside the flush),
         ``ckpt_join`` (the flush's wait for the tick's prefix job on the
-        worker; 0.0 where there was none to wait for) and the journal's
-        bytes on disk; a flush that used the tick's
+        worker; 0.0 where there was none to wait for), for a flush that
+        found a job run (hit or stale) ``ckpt_worker`` and
+        ``ckpt_encode`` (the job's wall, and of it the SNAPSHOT
+        message), and the journal's bytes on disk; a flush that used the tick's
         prefix job counts ``ckpt_prefix_hit`` and the worker's zlib
         time as phase ``ckpt_overlap``, any other ``ckpt_prefix_miss``
         — so Health carries them with no field of its own."""
@@ -810,6 +812,9 @@ class SchedulerBackendServicer:
         self.seam.observe_ms("ckpt_export", took["export_ms"])
         self.seam.observe_ms("ckpt_deflate", took["deflate_ms"])
         self.seam.observe_ms("ckpt_join", took["join_ms"])
+        if "worker_ms" in took:
+            self.seam.observe_ms("ckpt_worker", took["worker_ms"])
+            self.seam.observe_ms("ckpt_encode", took["encode_ms"])
         self.seam.add_bytes("ckpt", took["bytes_out"])
         if took["prefix"] == "hit":
             self.seam.observe_ms("ckpt_overlap", took["overlap_ms"])
@@ -1380,17 +1385,10 @@ class SchedulerBackendServicer:
         with _tracer.span(
             "engine.solve", kernel=session.kernel,
             delta_rows=int(prow.size + trow.size),
-        ) as solve_span, session.lock:
-            t_held = time.perf_counter()
-            self.seam.observe_ms("lock_wait", (t_held - t_dec) * 1e3)
-            if solve_span is not None:
-                # stamped after the fact (the lock is taken by the
-                # `with` the lock analyses read), so the one span of
-                # the tree that never reaches the profiler's host plane
-                _tracer.record_span(
-                    "session.lock_wait", solve_span["t0_ns"],
-                    int(t_held * 1e9) - solve_span["t0_ns"],
-                )
+        ), session.lock:
+            self.seam.observe_ms(
+                "lock_wait", (time.perf_counter() - t_dec) * 1e3
+            )
             if session.evicted:
                 # lost the race with LRU/TTL eviction (or a same-id
                 # re-open) between the store lookup and this lock: refuse

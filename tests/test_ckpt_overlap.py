@@ -147,6 +147,9 @@ def test_every_acks_journal_is_the_sequential_one_and_continues_the_chain(
     assert seam["session_ckpt_prefix_hit"] == 5
     assert seam["session_ckpt_prefix_miss"] == 1
     assert seam["ckpt_overlap_count"] == 5
+    # the worker's wall and its SNAPSHOT message: one reading a hit
+    assert seam["ckpt_worker_count"] == seam["ckpt_encode_count"] == 5
+    assert seam["ckpt_worker_ms_sum"] > seam["ckpt_encode_ms_sum"] > 0
     # the journal of tick 2, loaded, continues the chain bit for bit
     loaded = _loads(journals[1], served.sid, tmp_path / "load")
     assert loaded.tick == 2
@@ -167,6 +170,9 @@ def _miss(served, before: dict, tmp_path, why: str) -> None:
     session = _session(served)
     assert servicer.ckpt.last_flush["prefix"] == why
     assert servicer.ckpt.last_flush["overlap_ms"] == 0.0
+    # a job that ran to its end gives its wall, stale or not; a flush
+    # that found none run makes none up
+    assert ("worker_ms" in servicer.ckpt.last_flush) == (why == "stale")
     journal = _journal(servicer.ckpt, served.sid)
     assert journal == _sequential(session, tmp_path / "ref")
     assert _kinds(journal, tmp_path) == WHOLE

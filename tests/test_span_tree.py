@@ -28,14 +28,16 @@ ROWS = 256
 TEST_SECONDS = 240
 
 # name -> parent's name, the tree of ISSUE 26 §1 (a warm repair tick),
-# with ISSUE 27's ``ckpt.prefix``, ISSUE 29's ``auction.reverse`` and
-# ISSUE 33's ``auction.queue``
+# with ISSUE 27's ``ckpt.prefix``, ISSUE 29's ``auction.reverse``,
+# ISSUE 33's ``auction.queue`` and ISSUE 40's ``auction.open_count``,
+# ``quality.gap``, ``ckpt.encode`` and ``auction.upload`` (the candidate
+# lists going up, in ``arena.engine``'s self time on the chip)
+# (``session.lock_wait`` went: its seam phase stays)
 TREE = {
     "rpc.AssignDelta": None,
     "session.lookup": "rpc.AssignDelta",
     "wire.decode": "rpc.AssignDelta",
     "engine.solve": "rpc.AssignDelta",
-    "session.lock_wait": "engine.solve",
     "session.apply_delta": "engine.solve",
     "arena.solve": "engine.solve",
     "arena.dirty": "arena.solve",
@@ -46,14 +48,18 @@ TREE = {
     "repair.merge": "arena.candidates",
     "arena.diff": "arena.candidates",
     "arena.engine": "arena.solve",
+    "auction.upload": "arena.engine",
     "auction.seed": "arena.engine",
     "auction.segment": "arena.engine",
+    "auction.open_count": "auction.segment",
     "auction.reverse": "arena.engine",
     "auction.queue": "arena.engine",
     "auction.cleanup": "arena.engine",
     "arena.readback": "arena.engine",
     "arena.quality": "arena.solve",
+    "quality.gap": "arena.quality",
     "ckpt.prefix": "arena.solve",
+    "ckpt.encode": "ckpt.prefix",
     "ckpt.flush": "engine.solve",
     "ckpt.export": "ckpt.flush",
     "ckpt.frame": "ckpt.flush",
@@ -72,7 +78,7 @@ STAT_KEYS = (
 REPAIR_COUNTERS = ("rep_readback_bytes", "rep_syncs")
 SEAM_PHASES = (
     "lock_wait", "apply", "ckpt_flush", "ckpt_export", "ckpt_deflate",
-    "ckpt_overlap", "ckpt_join",
+    "ckpt_overlap", "ckpt_join", "ckpt_worker", "ckpt_encode",
 )
 
 
@@ -229,7 +235,7 @@ class TestSpanTree:
         assert 0 < prefix["attrs"]["bytes_raw"] < flush["attrs"]["bytes_raw"]
         assert prefix["attrs"]["deflate_ms"] > 0
         for s in served.spans:
-            if s["name"] in ("auction.seed",):
+            if s["name"] in ("auction.seed", "auction.upload"):
                 assert s["attrs"]["dispatch_only"] is True
 
     def test_children_lie_inside_their_parents(self, served):
@@ -449,6 +455,26 @@ class TestCounters:
         merged = 2 * 4 * ROWS * 80
         assert merged <= stats["rep_readback_bytes"] <= 4 * (lists + merged)
 
+    def test_last_stats_name_the_open_count_and_the_gap(self, served):
+        """ISSUE 40: the open counts' reads after full segments are a
+        part of the solve's wait, the gap a part of the quality pass;
+        each counter is the wall of its spans."""
+        stats = served.stats()
+        by_id = {s["span"]: s for s in served.spans}
+        reads = [
+            s for s in served.spans if s["name"] == "auction.open_count"
+            and by_id[s["parent"]]["name"] == "auction.segment"
+        ]
+        assert reads
+        assert isinstance(stats["eng_open_read_ms"], float)
+        assert stats["eng_open_read_ms"] == pytest.approx(
+            sum(s["dur_ns"] for s in reads) / 1e6, abs=0.5
+        )
+        assert 0 < stats["eng_open_read_ms"] <= stats["eng_wait_ms"]
+        (gap,) = [s for s in served.spans if s["name"] == "quality.gap"]
+        assert stats["q_gap_ms"] == pytest.approx(gap["dur_ns"] / 1e6, abs=0.5)
+        assert 0 < stats["q_gap_ms"] <= stats["quality_ms"]
+
     def test_health_carries_the_new_phases(self, served):
         before, after = served.seam_before, served.seam_after
         for phase in SEAM_PHASES:
@@ -480,25 +506,48 @@ class TestCounters:
         # the cold open's flush had no solve to hide behind
         assert after["session_ckpt_prefix_miss"] == 1
         assert before["session_ckpt_prefix_miss"] == 1
+        # ISSUE 40: the worker's whole wall and its SNAPSHOT message, for
+        # every flush that found its job run and for no other (the
+        # miss observes nothing, never a made-up 0)
+        prefix = next(s for s in served.spans if s["name"] == "ckpt.prefix")
+        encode = next(s for s in served.spans if s["name"] == "ckpt.encode")
+        assert took["ckpt_worker"] == pytest.approx(
+            prefix["dur_ns"] / 1e6, abs=0.5
+        )
+        assert took["ckpt_encode"] == pytest.approx(
+            encode["dur_ns"] / 1e6, abs=0.5
+        )
+        assert took["ckpt_encode"] + took["ckpt_overlap"] <= (
+            took["ckpt_worker"] + 0.01
+        )
+        assert after["ckpt_worker_count"] == after["session_ckpt_prefix_hit"]
+
+
+@pytest.fixture(scope="module")
+def captured(served, tmp_path_factory):
+    """One more warm tick under a CPU profiler capture, as the
+    benchmark's traced run takes it: the trace, read back."""
+    import jax
+
+    if not hasattr(jax.profiler, "ProfileData"):
+        pytest.skip("this jax has no ProfileData")
+    where = str(tmp_path_factory.mktemp("xplane"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(where, profiler_options=options)
+    try:
+        served.tick()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(where, "**", "*.xplane.pb"), recursive=True
+    )
+    return jax.profiler.ProfileData.from_file(path)
 
 
 class TestProfilerClock:
-    def test_spans_land_on_the_profilers_host_plane(self, served, tmp_path):
-        import jax
-
-        if not hasattr(jax.profiler, "ProfileData"):
-            pytest.skip("this jax has no ProfileData")
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
-        try:
-            served.tick()
-        finally:
-            jax.profiler.stop_trace()
-        (path,) = glob.glob(
-            os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True
-        )
-        profile = jax.profiler.ProfileData.from_file(path)
+    def test_spans_land_on_the_profilers_host_plane(self, captured):
+        profile = captured
         found: dict = {}
         for plane in profile.planes:
             if plane.name != "/host:CPU":
@@ -510,10 +559,12 @@ class TestProfilerClock:
                             (e.start_ns, e.start_ns + e.duration_ns)
                         )
         for name in ("rpc.AssignDelta", "engine.solve", "arena.solve",
-                     "auction.segment", "ckpt.flush"):
+                     "auction.segment", "ckpt.flush", "auction.open_count",
+                     "arena.quality", "quality.gap", "ckpt.prefix",
+                     "ckpt.encode"):
             assert name in found, sorted(found)
-        # recorded after the fact, so never mirrored
-        assert "session.lock_wait" not in found
+        # every span of the tree is a `with` block, so every one is here
+        assert set(TREE) <= set(found), sorted(set(TREE) - set(found))
         (outer,) = found["engine.solve"]
         (solve,) = found["arena.solve"]
         (flush,) = found["ckpt.flush"]
@@ -521,6 +572,14 @@ class TestProfilerClock:
         assert flush[1] <= outer[1]
         for seg in found["auction.segment"]:
             assert solve[0] <= seg[0] and seg[1] <= solve[1]
+        # ISSUE 40's three, each inside its parent
+        for child, parent in (("auction.open_count", "auction.segment"),
+                              ("quality.gap", "arena.quality"),
+                              ("ckpt.encode", "ckpt.prefix")):
+            for c in found[child]:
+                assert any(
+                    p[0] <= c[0] and c[1] <= p[1] for p in found[parent]
+                ), (child, c)
 
     def test_the_tracer_module_does_not_import_jax(self):
         import subprocess
@@ -664,7 +723,7 @@ class TestScopeNames:
             assert scope in lowered.as_text(debug_info=True), scope
 
 
-# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one, ISSUE 32's two, ISSUE 33's five and ISSUE 39's one that read counters,
+# ---- the thirteen per-layer metrics of ISSUE 26, ISSUE 27's two, ISSUE 29's six, ISSUE 30's one, ISSUE 32's two, ISSUE 33's five, ISSUE 39's one and ISSUE 40's four that read counters,
 # read through the benchmark's own generic reader from canned contexts (data files only: no reader code)
 
 _ACKS = [
@@ -677,7 +736,8 @@ _ACKS = [
      "eng_reverse_ms": 30.0, "eng_frontier_rows": 300000,
      "rep_readback_bytes": 6000000, "rep_syncs": 4,
      "eng_waiting_tasks": 1638, "eng_queue_rounds": 30,
-     "eng_queue_ms": 60.0, "waiting_excess": 4.0, "eng_scan_rounds": 20},
+     "eng_queue_ms": 60.0, "waiting_excess": 4.0, "eng_scan_rounds": 20,
+     "eng_open_read_ms": 30.0, "q_gap_ms": 8.0},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
@@ -687,19 +747,22 @@ _ACKS = [
      "eng_reverse_ms": 50.0, "eng_frontier_rows": 340000,
      "rep_readback_bytes": 7000000, "rep_syncs": 3,
      "eng_waiting_tasks": 1640, "eng_queue_rounds": 50,
-     "eng_queue_ms": 80.0, "waiting_excess": 6.0, "eng_scan_rounds": 40},
+     "eng_queue_ms": 80.0, "waiting_excess": 6.0, "eng_scan_rounds": 40,
+     "eng_open_read_ms": 50.0, "q_gap_ms": 12.0},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
     "ckpt_deflate_ms_sum": 80.0, "bytes_ckpt": 1000.0,
     "ckpt_overlap_ms_sum": 700.0, "session_ckpt_prefix_hit": 7.0,
-    "ckpt_join_ms_sum": 30.0,
+    "ckpt_join_ms_sum": 30.0, "ckpt_worker_ms_sum": 100.0,
+    "ckpt_encode_ms_sum": 20.0,
 }
 _SEAM_AFTER = {
     "apply_ms_sum": 5.0, "ckpt_flush_ms_sum": 1300.0,
     "ckpt_deflate_ms_sum": 1080.0, "bytes_ckpt": 7001000.0,
     "ckpt_overlap_ms_sum": 1600.0, "session_ckpt_prefix_hit": 9.0,
-    "ckpt_join_ms_sum": 54.0,
+    "ckpt_join_ms_sum": 54.0, "ckpt_worker_ms_sum": 500.0,
+    "ckpt_encode_ms_sum": 120.0,
 }
 # metric -> (layer, unit, source, the key whose absence silences it,
 #            expected value on the canned context[, better])
@@ -785,6 +848,16 @@ METRICS = {
     "scan_rounds_per_ack": (
         "auction solve", "rounds", "program_counter", "eng_scan_rounds",
         30.0),
+    "solve_open_read_ms_per_ack": (
+        "auction solve", "ms", "program_span", "eng_open_read_ms", 40.0),
+    "quality_gap_ms_per_ack": (
+        "quality pass", "ms", "program_span", "q_gap_ms", 10.0),
+    "ckpt_worker_ms_per_ack": (
+        "session, arena bookkeeping and checkpoint", "ms", "program_span",
+        "ckpt_worker_ms_sum", 200.0),
+    "ckpt_encode_ms_per_ack": (
+        "session, arena bookkeeping and checkpoint", "ms", "program_span",
+        "ckpt_encode_ms_sum", 50.0),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -805,7 +878,9 @@ CELLS.update(dict.fromkeys(
     ["pool-queued.ticks"],
 ))
 CELLS.update(dict.fromkeys(
-    ("ckpt_join_ms_per_ack", "scan_rounds_per_ack"),
+    ("ckpt_join_ms_per_ack", "scan_rounds_per_ack",
+     "solve_open_read_ms_per_ack", "quality_gap_ms_per_ack",
+     "ckpt_worker_ms_per_ack", "ckpt_encode_ms_per_ack"),
     ["pool-large.ticks", "pool-slack.ticks", "pool-queued.ticks"],
 ))
 
@@ -954,3 +1029,237 @@ class TestFrontierRows:
         rungs = sparse._FRONTIER_RUNGS
         assert any(w not in rungs + (P,) for w in widths), widths
         assert min(widths) == rungs[0], widths
+
+
+# ---- ISSUE 40: the device's idle time split by the program span the
+# host was in (``benchmarks/lib/idle_split.py``; wired into the trace
+# reduction and the generic reader by a later ``benchmark`` PR)
+
+def _idle_split():
+    with open(os.path.join(REPO, "benchmarks", "lib", "idle_split.json")) as fh:
+        return json.load(fh)
+
+
+def _layout() -> dict:
+    path = os.path.join(REPO, "benchmarks", "lib", "trace_layout.json")
+    with open(path) as fh:
+        return {**json.load(fh), **_idle_split()["layout"]}
+
+
+def _ev(name, start, end):
+    from types import SimpleNamespace as NS
+
+    return NS(name=name, start_ns=float(start), duration_ns=float(end - start))
+
+
+def _canned(host_lines, ops=()):
+    """A profile as ``ProfileData`` reads: one device plane (``ops``,
+    ``(start, end)`` of XLA ops) and the host plane's thread lines, each
+    a list of ``(name, start, end)``."""
+    from types import SimpleNamespace as NS
+
+    device = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=[_ev("fusion", s, e) for s, e in ops]),
+        NS(name="XLA Modules", events=[
+            _ev("jit__sparse_auction_phase(1)", s, e) for s, e in ops]),
+    ])
+    host = NS(name="/host:CPU", lines=[
+        NS(name=f"thread {i}", events=[_ev(*e) for e in line])
+        for i, line in enumerate(host_lines)
+    ])
+    return NS(planes=[device, host])
+
+
+# one request thread: the root 0-100, arena.solve 10-90 and in it
+# arena.candidates 10-30, arena.engine 30-80 > auction.segment 40-60 >
+# auction.open_count 55-60; the checkpoint's worker on a line of its own
+# with no root, 20-95; a line of the harness's own, not the program's
+_REQUEST = [
+    ("rpc.AssignDelta", 0, 100), ("arena.solve", 10, 90),
+    ("arena.candidates", 10, 30), ("arena.engine", 30, 80),
+    ("auction.segment", 40, 60), ("auction.open_count", 55, 60),
+    ("bench.request", 0, 120),
+]
+_WORKER = [("ckpt.prefix", 20, 95), ("ckpt.encode", 20, 50)]
+
+IDLE_CASES = {
+    # the innermost open span takes the piece, not its parents
+    "nested": ([(56, 59)], {"auction.open_count": 3}),
+    # a stretch across span boundaries is split piecewise, both sides
+    # (and every span between) counted
+    "split": ([(50, 70)], {"auction.segment": 5, "auction.open_count": 5,
+                           "arena.engine": 10}),
+    # no root open: the transport and the client's loop
+    "outside": ([(100, 130)], {"(outside)": 30}),
+    "straddles_the_root": ([(-10, 5)], {"(outside)": 10,
+                                        "rpc.AssignDelta": 5}),
+    # the worker's spans never take the request thread's idle time
+    "worker_line": ([(25, 35), (85, 95)], {
+        "arena.candidates": 5, "arena.engine": 5, "rpc.AssignDelta": 5,
+        "arena.solve": 5}),
+    "many": ([(0, 10), (30, 40), (60, 80)], {
+        "rpc.AssignDelta": 10, "arena.engine": 30}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IDLE_CASES))
+def test_idle_by_span_on_a_canned_trace(case):
+    from benchmarks.lib import idle_split
+
+    stretches, want = IDLE_CASES[case]
+    got = idle_split.idle_by_span(
+        _canned([_REQUEST, _WORKER]), _layout(), stretches
+    )
+    assert got == pytest.approx({k: v * 1e-9 for k, v in want.items()})
+    assert sum(got.values()) == pytest.approx(
+        sum(e - s for s, e in stretches) * 1e-9
+    )
+
+
+@pytest.mark.parametrize("order", ["deeper_second", "deeper_first"])
+def test_two_request_threads_at_once_the_deepest_span_wins(order):
+    """Two root lines open at once: the deeper span takes the piece,
+    and at equal depth the line that comes first in the trace."""
+    from benchmarks.lib import idle_split
+
+    shallow = [("rpc.AssignDelta", 0, 100), ("engine.solve", 0, 100)]
+    deep = [("rpc.AssignDelta", 50, 150), ("wire.decode", 50, 150),
+            ("arena.solve", 60, 90)]
+    lines = [shallow, deep] if order == "deeper_second" else [deep, shallow]
+    got = idle_split.idle_by_span(_canned(lines), _layout(), [(0, 160)])
+    # 0-50 the shallow line alone, 50-60 and 90-100 a tie at equal
+    # depth (the first line's), 60-90 the deeper span, 100-150 the deep
+    # line alone, 150-160 no root
+    tie = "engine.solve" if order == "deeper_second" else "wire.decode"
+    want = {"engine.solve": 50, "wire.decode": 50, "arena.solve": 30,
+            "(outside)": 10}
+    want[tie] += 20
+    assert got == pytest.approx({k: v * 1e-9 for k, v in want.items()})
+
+
+def _reduced(monkeypatch, host_lines, ops):
+    """``trace_reduce.reduce_trace`` of a canned trace with its
+    ``idle_by_span`` from the very stretches it found (as a traced run
+    would give it once the reduction holds it), and the accepted keys
+    with and without the split."""
+    from benchmarks.lib import idle_split, trace_reduce
+
+    profile = _canned(host_lines, ops)
+    layout = _layout()
+    plain = trace_reduce.reduce_trace(profile, layout, 1)
+    seen = []
+    gaps = trace_reduce.gaps
+
+    def keep(merged, lo, hi):
+        seen.append(gaps(merged, lo, hi))
+        return seen[-1]
+
+    monkeypatch.setattr(trace_reduce, "gaps", keep)
+    reduced = trace_reduce.reduce_trace(profile, layout, 1)
+    (stretches,) = seen
+    reduced["idle_by_span"] = idle_split.idle_by_span(
+        profile, layout, stretches
+    )
+    return plain, reduced
+
+
+_OPS = [(5, 12), (20, 52), (58, 75), (101, 110)]
+
+
+def test_the_split_adds_up_to_the_idle_time_and_leaves_the_rest(
+    monkeypatch
+):
+    plain, reduced = _reduced(monkeypatch, [_REQUEST, _WORKER], _OPS)
+    # every idle nanosecond of the slice, once
+    assert sum(reduced["idle_by_span"].values()) == pytest.approx(
+        reduced["window_s"] - reduced["busy_s"]
+    )
+    # the accepted keys are what the reduction gives without the split
+    assert {k: v for k, v in reduced.items() if k != "idle_by_span"} == plain
+    assert set(plain) == {
+        "busy_s", "window_s", "module_s", "device_ops", "idle_gaps"
+    }
+
+
+def test_the_six_metrics_add_up_to_device_idle(monkeypatch):
+    """On one chip the six sum to ``device_idle_pct`` / 100 x
+    ``window_s`` / ``acks_in_slice`` x 1000: the same stretches, the
+    same slice, the same divisor."""
+    from benchmarks.lib import idle_split, readers
+
+    _, reduced = _reduced(monkeypatch, [_REQUEST, _WORKER], _OPS)
+    reduced["acks_in_slice"] = 2
+    six = [
+        idle_split.read_idle_ms_per_ack(m["read"], reduced)
+        for m in _idle_split()["metrics"]
+    ]
+    idle = readers.read_trace({"kind": "idle_pct"}, reduced, {}, {})
+    assert sum(six) == pytest.approx(
+        idle / 100 * reduced["window_s"] / 2 * 1e3
+    )
+    assert all(v >= 0 for v in six)
+
+
+def test_every_span_of_the_tree_is_read_by_exactly_one_idle_metric():
+    import re
+
+    metrics = _idle_split()["metrics"]
+    assert len(metrics) == 6
+    span = re.compile(_idle_split()["layout"]["program_span"])
+    for name in (*TREE, "(outside)"):
+        if name != "(outside)":
+            assert span.search(name), name
+        readers_of = [
+            m["name"] for m in metrics
+            if any(re.fullmatch(p, name) for p in m["read"]["spans"])
+        ]
+        assert len(readers_of) == 1, (name, readers_of)
+
+
+@pytest.mark.parametrize(
+    "name", [m["name"] for m in _idle_split()["metrics"]]
+)
+def test_an_idle_metric_reads_nothing_without_the_split(name):
+    """The parent's reduction has no ``idle_by_span``: nothing is read
+    and nothing is raised; declared as the accepted entries are."""
+    from benchmarks.lib import idle_split
+
+    (spec,) = [m for m in _idle_split()["metrics"] if m["name"] == name]
+    assert spec["source"] == "device_trace" and spec["unit"] == "ms"
+    assert spec["better"] == "lower" and spec["moves"] == "ack_p50_ms"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        layers = {m["layer"] for m in json.load(fh)["per_layer"]}
+    assert spec["layer"] in layers
+    bare = {"busy_s": 1.0, "window_s": 2.0, "module_s": {},
+            "device_ops": [], "idle_gaps": [], "acks_in_slice": 3}
+    assert idle_split.read_idle_ms_per_ack(spec["read"], bare) is None
+    assert idle_split.read_idle_ms_per_ack(spec["read"], None) is None
+    split = {**bare, "idle_by_span": {}}
+    assert idle_split.read_idle_ms_per_ack(spec["read"], split) == 0.0
+
+
+def test_the_split_of_a_real_capture_names_the_request_thread(captured):
+    """A CPU capture of one warm tick: the request thread's innermost
+    spans are the tree's, the worker's never show, and a stretch over
+    the whole tick splits into pieces that add up to it."""
+    import re
+
+    from benchmarks.lib import idle_split
+
+    layout = _layout()
+    pieces = idle_split.timeline(captured, layout)
+    names = {n for _, _, n in pieces}
+    assert {"rpc.AssignDelta", "engine.solve", "auction.segment",
+            "quality.gap", "ckpt.flush"} <= names
+    assert not names & {"ckpt.prefix", "ckpt.encode"}
+    assert all(a[1] <= b[0] for a, b in zip(pieces, pieces[1:]))
+    lo, hi = pieces[0][0] - 1e6, pieces[-1][1] + 1e6
+    got = idle_split.idle_by_span(captured, layout, [(lo, hi)])
+    assert sum(got.values()) == pytest.approx((hi - lo) * 1e-9)
+    assert got["(outside)"] >= 2e-3 * 0.999
+    # whatever the tick opened is read by exactly one of the six
+    for name in got:
+        assert sum(
+            any(re.fullmatch(p, name) for p in m["read"]["spans"])
+            for m in _idle_split()["metrics"]
+        ) == 1, name

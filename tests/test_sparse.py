@@ -529,8 +529,11 @@ class TestAdaptiveFrontierLadder:
             "rounds_total", "segments", "wait_ms", "frontier_rows",
             "stall_exit", "stall_rounds", "reverse_rounds", "reverse_ms",
             "free_repriced", "queue_rounds", "queue_ms", "scan_rounds",
+            "open_read_ms",
         }
         assert stats["queue_rounds"] == 0  # (no queue in this pool)
+        # the open counts' reads after full segments: a part of the wait
+        assert 0 <= stats["open_read_ms"] <= stats["wait_ms"]
         assert stats["segments"] >= 1 and stats["rounds_total"] >= 1
         # every round runs at one of the kernel's widths, 32 the least
         assert stats["frontier_rows"] >= 32 * (
