@@ -40,11 +40,18 @@ the tick (``JaxSolveArena.structure_hook``, armed by :meth:`arm_locked`
 for a tick that is ``due``), a worker thread builds the SNAPSHOT frame
 and DEFLATEs the ARENA payload's manifest and solve-independent buffers
 while the device runs the auction; the flush joins it, feeds what the
-solve wrote, and writes. The journal is byte for byte what the
-sequential path writes, the worker never touches a file, and the flush
-takes the prefix only if it was built for this tick from the very
-objects the session and arena hold now — anything else (no prefix, a
-stale one, a raised one) writes as before, counted.
+solve wrote, and writes. Every frame, on the worker and in the flush
+alike, is DEFLATEd in chunks of ``tfmt.DEFLATE_CHUNK`` (1 MiB) on a
+pool of up to four threads the process shares (``tfmt.FrameDeflater``):
+a warm tick's 13 MB are 16 chunks, and the worker's wall falls from
+one core's level-1 DEFLATE of them to what the pool takes.
+The cuts lie at fixed offsets of each payload, wherever the worker's
+feeding ends and the flush's begins, so the journal is byte for byte
+what the sequential path writes; a frame of one chunk is
+``zlib.compress``'s bytes as before. The worker never touches a file,
+and the flush takes the prefix only if it was built for this tick from
+the very objects the session and arena hold now — anything else (no
+prefix, a stale one, a raised one) writes as before, counted.
 
 DEFLATE level (ISSUE 34): a checkpoint's frames are written at
 ``CKPT_COMPRESSLEVEL`` = 1, a workload trace's at
@@ -134,7 +141,12 @@ FENCE_NAME = "FENCE.json"
 # cost 266-347 ms at 8,192 x 8,192 for 1.3-2.8% of the bytes back;
 # Z_RLE is faster still but misses the padded rows' repeats, four bytes
 # apart (+21% at 8,192 x 4,915). Why a workload trace keeps
-# ``tfmt.COMPRESSLEVEL`` (6): the module docstring.
+# ``tfmt.COMPRESSLEVEL`` (6): the module docstring. At this level every
+# frame is DEFLATEd in 1 MiB chunks on a pool of four threads
+# (``tfmt.DEFLATE_CHUNK``, where the reading that chose the size is):
+# the two payloads at 8,192 x 4,915 take 41.8 ms of wall instead of
+# 145.8, at 150.4 ms inside zlib, and the 12 cuts between their 14
+# chunks cost no bytes (4,556,815 out against one stream's 4,560,016).
 CKPT_COMPRESSLEVEL = 1
 
 
@@ -204,7 +216,7 @@ def journal_session_id(path: str) -> Optional[str]:
 @contextmanager
 def _frame_span(writer, kind: str):
     """One journal frame (encode + DEFLATE + write) as a ``ckpt.frame``
-    span carrying the time the frame spent inside ``zlib.compress``."""
+    span carrying the time the frame spent inside zlib."""
     before = writer.deflate_ms
     with _tracer.span("ckpt.frame", kind=kind) as span:
         yield
@@ -300,8 +312,10 @@ class _PrefixJob:
                         self.p_cols, self.r_cols, self.kernel, self.top_k
                     ),
                 )
+            # both frames' full chunks go to the DEFLATE pool as they
+            # are fed; this thread then takes the SNAPSHOT's last chunk
+            # and the ARENA's open one while the pool runs the rest
             self.snapshot.feed(payload)
-            self.snapshot.finish()
             self.head, arrays = tfmt.pack_plan(self.live, self.last)
             self.arena.feed(self.head)
             for name, a in arrays:
@@ -309,6 +323,8 @@ class _PrefixJob:
                     return
                 if name not in self.last:
                     self.arena.feed(tfmt.raw_bytes(a))
+            self.snapshot.finish()
+            self.arena.settle()
             self.overlap_ms = round(
                 self.snapshot.take_ms() + self.arena.take_ms(), 3
             )
@@ -360,10 +376,14 @@ class SessionCheckpointer:
         self.handoffs = 0
         self.fence_refusals = 0
         self.journals_skipped = 0
+        # DEFLATE chunks of every journal flushed (one a frame whose
+        # payload fits ``tfmt.DEFLATE_CHUNK``)
+        self.chunks = 0
         # what the last successful flush cost (flush_ms, export_ms,
         # deflate_ms, bytes_raw, bytes_out = the journal's size on
-        # disk; prefix = hit / miss / stale / error, join_ms = its wait
-        # for the worker, overlap_ms = the zlib time a hit took off the
+        # disk, chunks = its frames' DEFLATE chunks; prefix = hit /
+        # miss / stale / error, join_ms = its wait for the worker,
+        # overlap_ms = the zlib time a hit took off the
         # flush; worker_ms / encode_ms = the wall of the job it found
         # run, hit or stale, and of its SNAPSHOT message); the
         # servicer, which owns the seam, records it
@@ -522,6 +542,7 @@ class SessionCheckpointer:
                         join_ms=took["join_ms"],
                     )
             self.flushes += 1
+            self.chunks += took["chunks"]
             self.last_flush = took
             return True
         except Exception:
@@ -604,6 +625,7 @@ class SessionCheckpointer:
         took.update(
             deflate_ms=round(writer.deflate_ms, 3),
             bytes_raw=writer.bytes_raw, bytes_out=writer.bytes_out,
+            chunks=writer.deflate_chunks,
         )
 
     # ---------------- migration handoff ----------------

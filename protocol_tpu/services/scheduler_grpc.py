@@ -1755,6 +1755,8 @@ class SchedulerBackendServicer:
             seam["ckpt_journals_skipped"] = float(
                 self.ckpt.journals_skipped
             )
+            # the DEFLATE chunks of every journal flushed
+            seam["ckpt_chunks_sum"] = float(self.ckpt.chunks)
         with self._router_lock:
             seam["sessions_moved_out"] = float(len(self._moved))
         for name in sorted(seam):

@@ -23,7 +23,10 @@ always leaves a valid prefix: the reader stops at a truncated header, a
 short payload, or a CRC mismatch and reports ``truncated=True`` instead
 of raising — the surviving ticks replay normally. Compression is
 per-frame DEFLATE (zlib): deterministic bytes (no gzip mtime header), so
-recording the same workload twice produces byte-identical files.
+recording the same workload twice produces byte-identical files. A
+payload longer than ``DEFLATE_CHUNK`` is DEFLATEd in chunks on a thread
+pool and still lands as one zlib stream (``FrameDeflater``), so readers
+need nothing but ``zlib.decompress``.
 
 Frame payloads reuse the wire-v2 ``TensorBlob`` codecs verbatim
 (``protocol_tpu/proto/wire.py``): columns are C-order little-endian raw
@@ -39,9 +42,11 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import os
 import struct
 import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional
 
 import numpy as np
@@ -68,6 +73,28 @@ KIND_ARENA = 6     # named-ndarray pack (pack_arrays): carried solver
 
 _FLAG_DEFLATE = 1
 COMPRESSLEVEL = 6
+# A frame's payload is DEFLATEd in chunks of this many bytes, cut at
+# fixed offsets from its first byte, each a stream of its own on a
+# shared pool of threads (``FrameDeflater``). A chunk starts with an
+# empty window, so each cut costs what the next chunk's first 32 KiB
+# could have matched behind it. One warm tick's SNAPSHOT + ARENA
+# payloads at 8,192 x 4,915 rows (13,004,932 B raw, level 1), fed as
+# the checkpoint's worker feeds them, on four threads of the host of a
+# TPU v5e (``scripts/ckpt_deflate_levels.py --chunk``; best wall of
+# three; zlib 1.2.13):
+#
+#   chunk     wall ms   zlib ms   bytes out   chunks
+#   256 KiB   45.9      169.4     4,552,835   51
+#   512 KiB   43.0      163.3     4,554,848   26
+#   1 MiB     41.8      150.4     4,556,815   14
+#   2 MiB     43.0      151.8     4,559,404   7
+#   4 MiB     63.7      148.9     4,560,296   4
+#   one stream          145.8     4,560,016   1
+#
+# 1 MiB is the shortest wall; smaller chunks spend more inside zlib,
+# larger ones leave threads idle at the end. The cuts cost no bytes
+# here (-0.07% against one stream).
+DEFLATE_CHUNK = 1 << 20
 _HEADER = struct.Struct("<BBII")
 
 # Canonical trace-frame column dtypes. These MUST match the wire tables
@@ -328,47 +355,181 @@ def snapshot_payload(
     ).SerializeToString()
 
 
+_ADLER_BASE = 65521
+
+
+def adler32_combine(first: int, second: int, second_len: int) -> int:
+    """The Adler-32 of two byte strings joined, from the Adler-32 of
+    each and the second's length (zlib's ``adler32_combine``, which
+    Python's ``zlib`` does not export)."""
+    a1, b1 = first & 0xFFFF, first >> 16
+    a2, b2 = second & 0xFFFF, second >> 16
+    a = (a1 + a2 - 1) % _ADLER_BASE
+    b = (b1 + b2 + second_len * (a1 - 1)) % _ADLER_BASE
+    return (b << 16) | a
+
+
+class _Chunk:
+    """One chunk of a frame's payload: the views of the fed buffers
+    that fall in it, and a DEFLATE stream of its own, zlib-wrapped for
+    the frame's first chunk (it writes the 2-byte header) and raw for
+    every later one. One thread at a time uses it: the feeding thread
+    until the chunk is handed to the pool, then one pool task."""
+
+    __slots__ = ("first", "z", "parts", "pushed", "size", "out", "adler",
+                 "ms")
+
+    def __init__(self, level: int, first: bool):
+        self.first = first
+        wbits = zlib.MAX_WBITS if first else -zlib.MAX_WBITS
+        self.z = zlib.compressobj(level, zlib.DEFLATED, wbits)
+        self.parts: list = []
+        self.pushed = 0  # parts already through the stream
+        self.size = 0
+        self.out: list = []
+        self.adler = 1
+        self.ms = 0.0  # time inside zlib not yet taken by the deflater
+
+    def push(self) -> None:
+        t0 = time.perf_counter()
+        for part in self.parts[self.pushed:]:
+            self.out.append(self.z.compress(part))
+        self.pushed = len(self.parts)
+        self.ms += (time.perf_counter() - t0) * 1e3
+
+    def close(self, last: bool) -> None:
+        """End the stream: ``Z_FINISH`` for the frame's last chunk,
+        else ``Z_SYNC_FLUSH`` (byte-aligned and not final, so the next
+        chunk's blocks follow it). The Adler-32 of a chunk that is not
+        the whole payload is taken here, for the stream's trailer."""
+        self.push()
+        t0 = time.perf_counter()
+        self.out.append(
+            self.z.flush(zlib.Z_FINISH if last else zlib.Z_SYNC_FLUSH)
+        )
+        if not (self.first and last):
+            for part in self.parts:
+                self.adler = zlib.adler32(part, self.adler)
+        self.ms += (time.perf_counter() - t0) * 1e3
+        self.z = None
+
+
+def _deflate_pool() -> ThreadPoolExecutor:
+    # no thread starts before the first chunk is handed over: a process
+    # whose frames all fit one chunk never starts one
+    return ThreadPoolExecutor(
+        max_workers=min(4, os.cpu_count() or 1), thread_name_prefix="deflate"
+    )
+
+
+_POOL = _deflate_pool()
+
+
+def _renew_pool() -> None:
+    # a forked child holds the parent's pool object but none of its
+    # threads: work handed to it would never run
+    global _POOL
+    _POOL = _deflate_pool()
+
+
+os.register_at_fork(after_in_child=_renew_pool)
+
+
 class FrameDeflater:
-    """One frame's payload DEFLATEd piece by piece. A zlib stream does
-    not depend on how its input was cut, so feeding the pieces gives
-    byte for byte what ``zlib.compress`` gives for the joined payload:
-    a frame can be started before its last bytes exist, and on another
-    thread (zlib releases the GIL). Pieces are bytes-like and are kept
-    by reference, not copied, until :meth:`finish`."""
+    """One frame's payload DEFLATEd buffer by buffer, in chunks of
+    ``DEFLATE_CHUNK`` bytes cut at fixed offsets from the payload's
+    first byte, each chunk a DEFLATE stream of its own on a thread pool
+    every deflater of the process shares (zlib releases the GIL). The
+    body is one zlib stream: the first chunk's header and blocks, every
+    chunk ended by a sync flush and the last by ``Z_FINISH``, and the
+    Adler-32 of the whole payload, combined from the chunks'. Any
+    ``zlib.decompress`` reads it. The cuts depend on the payload alone,
+    not on how it was fed, and a chunk's stream does not depend on how
+    its bytes were fed either, so the body is the piecewise stream of
+    the joined payload however it arrived; a payload of one chunk or
+    less is exactly ``zlib.compress(payload, compresslevel)``. A frame
+    can be started before its last bytes exist, and on another thread.
+    Buffers are bytes-like and are kept by reference, not copied, until
+    :meth:`finish`."""
 
     def __init__(self, compresslevel: int = COMPRESSLEVEL):
         self.compresslevel = compresslevel
-        self._z = zlib.compressobj(compresslevel)
-        self._pieces: list = []
-        self._out: list = []
+        self._buffers: list = []
+        self._open = _Chunk(compresslevel, first=True)
+        # chunks handed to the pool, in payload order, and how many of
+        # them have been waited for
+        self._sent: list = []
+        self._waited = 0
         self._done: Optional[tuple] = None
         self._ms = 0.0
         self.bytes_raw = 0
+        self.chunks = 0  # the finished stream's chunks
 
-    def feed(self, piece) -> None:
-        t0 = time.perf_counter()
-        self._out.append(self._z.compress(piece))
-        self._ms += (time.perf_counter() - t0) * 1e3
-        self._pieces.append(piece)
-        self.bytes_raw += len(piece)
+    def feed(self, buf) -> None:
+        view = memoryview(buf).cast("B")
+        self._buffers.append(buf)
+        self.bytes_raw += view.nbytes
+        while view.nbytes:
+            room = DEFLATE_CHUNK - self._open.size
+            if room == 0:
+                # full, and more bytes follow: not the frame's last chunk
+                self._sent.append(
+                    (self._open, _POOL.submit(self._open.close, False))
+                )
+                self._open = _Chunk(self.compresslevel, first=False)
+                continue
+            part = view[:room]
+            self._open.parts.append(part)
+            self._open.size += part.nbytes
+            view = view[room:]
+
+    def _take(self, chunk: _Chunk) -> None:
+        self._ms += chunk.ms
+        chunk.ms = 0.0
+
+    def _wait(self) -> None:
+        for chunk, future in self._sent[self._waited:]:
+            future.result()
+            self._take(chunk)
+        self._waited = len(self._sent)
+
+    def settle(self) -> None:
+        """DEFLATE all that was fed so far, the chunk still open on
+        this thread and the full ones on the pool, and wait for it:
+        :meth:`finish` is left what is fed after this call and the
+        stream's end."""
+        self._open.push()
+        self._take(self._open)
+        self._wait()
 
     def finish(self) -> tuple[int, bytes]:
         """``(flags, body)`` as a frame stores them: the stream where
         it is shorter than the payload, else the payload itself."""
         if self._done is None:
-            t0 = time.perf_counter()
-            self._out.append(self._z.flush())
-            self._ms += (time.perf_counter() - t0) * 1e3
-            z = b"".join(self._out)
+            last = self._open
+            last.close(True)
+            self._take(last)
+            self._wait()
+            chunks = [chunk for chunk, _ in self._sent] + [last]
+            out = [b for chunk in chunks for b in chunk.out]
+            if len(chunks) > 1:
+                adler = chunks[0].adler
+                for chunk in chunks[1:]:
+                    adler = adler32_combine(adler, chunk.adler, chunk.size)
+                out.append(struct.pack(">I", adler))
+            z = b"".join(out)
+            self.chunks = len(chunks)
             if len(z) < self.bytes_raw:
                 self._done = (_FLAG_DEFLATE, z)
             else:
-                self._done = (0, b"".join(self._pieces))
-            self._pieces = self._out = []
+                self._done = (0, b"".join(self._buffers))
+            self._buffers, self._sent, self._open = [], [], None
         return self._done
 
     def take_ms(self) -> float:
-        """Time inside zlib since the last call, in ms."""
+        """Time inside zlib since the last call, in ms: the sum of the
+        chunks' times, whichever threads ran them (waited for by
+        :meth:`settle` or :meth:`finish`)."""
         ms, self._ms = self._ms, 0.0
         return ms
 
@@ -385,10 +546,11 @@ class TraceWriter:
         self.compresslevel = compresslevel
         # what this writer has cost so far (a checkpoint's writer lives
         # for one flush): payload bytes before DEFLATE, bytes in the
-        # file, and the time inside zlib.compress
+        # file, the time inside zlib and the chunks DEFLATEd
         self.bytes_raw = 0
         self.bytes_out = len(MAGIC)
         self.deflate_ms = 0.0
+        self.deflate_chunks = 0
         self._fh = open(path, "wb")
         self._fh.write(MAGIC)
         m = {"version": VERSION}
@@ -396,13 +558,9 @@ class TraceWriter:
         self._frame(KIND_META, json.dumps(m, sort_keys=True).encode())
 
     def _frame(self, kind: int, payload: bytes) -> None:
-        t0 = time.perf_counter()
-        z = zlib.compress(payload, self.compresslevel)
-        self.deflate_ms += (time.perf_counter() - t0) * 1e3
-        flags, body = (
-            (_FLAG_DEFLATE, z) if len(z) < len(payload) else (0, payload)
-        )
-        self._put(kind, flags, body, len(payload))
+        deflated = FrameDeflater(self.compresslevel)
+        deflated.feed(payload)
+        self._frame_deflated(kind, deflated)
 
     def _put(self, kind: int, flags: int, body: bytes, raw_len: int) -> None:
         self.bytes_raw += raw_len
@@ -414,14 +572,16 @@ class TraceWriter:
         self.bytes_out += _HEADER.size + len(body)
 
     def _frame_deflated(self, kind: int, deflated: "FrameDeflater") -> None:
-        """Land a frame whose payload was fed to ``deflated``: the
-        bytes :meth:`_frame` writes for the joined payload. The zlib
-        time the deflater has spent since its last ``take_ms`` counts
-        as this writer's."""
+        """Land a frame whose payload was fed to ``deflated`` (what
+        :meth:`_frame` does for a payload it is handed whole, so a
+        frame's bytes do not depend on which path fed it). The zlib
+        time the deflater has spent since its last ``take_ms``, and
+        its chunks, count as this writer's."""
         if deflated.compresslevel != self.compresslevel:
             raise ValueError("frame deflated at another level")
         flags, body = deflated.finish()
         self.deflate_ms += deflated.take_ms()
+        self.deflate_chunks += deflated.chunks
         self._put(kind, flags, body, deflated.bytes_raw)
 
     def write_snapshot(

@@ -21,11 +21,17 @@ Prints one JSON line a shape: raw bytes, and for every setting the best
 of ``--repeat`` timings (ms inside zlib, one core) and the bytes out;
 with ``--buffers`` the same for each buffer of at least 1% of the
 payload alone (a stream of its own, so their bytes do not sum to the
-frame's). The DEFLATE is host code: the device only serves the ticks
+frame's). With ``--chunk`` the two frames again at the checkpoint's
+level through ``trace/format.FrameDeflater`` with each chunk size given
+in place of ``DEFLATE_CHUNK``, both fed before either is finished, as
+the worker feeds them: the best wall of ``--repeat`` on the shared
+pool, the time inside zlib summed over the chunks, the bytes out and
+the chunks. The DEFLATE is host code: the device only serves the ticks
 that make the state.
 
     python scripts/ckpt_deflate_levels.py --shape 8192x8192 \\
-        --shape 8192x4915 --shape 6554x8192 --buffers
+        --shape 8192x4915 --shape 6554x8192 --buffers \\
+        --chunk 262144 --chunk 1048576 --chunk 4194304
 """
 
 from __future__ import annotations
@@ -77,6 +83,38 @@ def _reading(frames: list, repeat: int) -> dict:
             "bytes": sum(n for _, n in runs[0]),
         }
     return out
+
+
+def _chunked(frames: list, chunk: int, repeat: int) -> dict:
+    """``frames`` through ``FrameDeflater`` at the checkpoint's level
+    in chunks of ``chunk`` bytes: the best wall of ``repeat``, and of
+    that run the time inside zlib, the bytes out and the chunks."""
+    from protocol_tpu.faults import checkpoint
+    from protocol_tpu.trace import format as tfmt
+
+    saved, tfmt.DEFLATE_CHUNK = tfmt.DEFLATE_CHUNK, chunk
+    try:
+        best = None
+        for _ in range(repeat):
+            t0 = time.perf_counter()
+            deflaters = []
+            for pieces in frames:
+                d = tfmt.FrameDeflater(checkpoint.CKPT_COMPRESSLEVEL)
+                for piece in pieces:
+                    d.feed(piece)
+                deflaters.append(d)
+            bodies = [d.finish()[1] for d in deflaters]
+            run = {
+                "wall_ms": round((time.perf_counter() - t0) * 1e3, 3),
+                "zlib_ms": round(sum(d.take_ms() for d in deflaters), 3),
+                "bytes": sum(len(b) for b in bodies),
+                "chunks": sum(d.chunks for d in deflaters),
+            }
+            if best is None or run["wall_ms"] < best["wall_ms"]:
+                best = run
+        return best
+    finally:
+        tfmt.DEFLATE_CHUNK = saved
 
 
 def capture(n_providers: int, n_tasks: int, warm: int) -> tuple[list, dict]:
@@ -149,6 +187,9 @@ def main() -> int:
     ap.add_argument("--repeat", type=int, default=2)
     ap.add_argument("--buffers", action="store_true",
                     help="every large buffer alone as well")
+    ap.add_argument("--chunk", type=int, action="append", default=[],
+                    help="a chunk size in bytes for the checkpoint's level "
+                    "through FrameDeflater; may repeat")
     args = ap.parse_args()
 
     from protocol_tpu.utils.platform import place_compile_cache
@@ -169,6 +210,11 @@ def main() -> int:
             line["buffers"] = {
                 name: {"raw_bytes": len(b), **_reading([[b]], args.repeat)}
                 for name, b in buffers.items() if len(b) * 100 >= raw
+            }
+        if args.chunk:
+            line["chunked"] = {
+                str(size): _chunked(frames, size, args.repeat)
+                for size in args.chunk
             }
         line.update(
             zlib=zlib.ZLIB_RUNTIME_VERSION, cores=os.cpu_count(),

@@ -8,6 +8,7 @@ and counted. CPU, 256 rows, through the loopback servicer of
 ``test_span_tree.py``."""
 
 import os
+import struct
 import sys
 import threading
 import zlib
@@ -80,15 +81,15 @@ def _raw_frames(data: bytes) -> list:
     return out
 
 
-def _payloads_at(data: bytes, level: int) -> list:
+def _payloads_at(data: bytes, level: int, deflate=zlib.compress) -> list:
     """``(kind, payload)`` of every frame, each DEFLATEd body being
-    what ``zlib.compress`` gives for its payload at ``level``."""
+    what ``deflate`` gives for its payload at ``level``."""
     out = []
     for kind, flags, body in _raw_frames(data):
         payload = body
         if flags & tfmt._FLAG_DEFLATE:
             payload = zlib.decompress(body)
-            assert body == zlib.compress(payload, level), (kind, level)
+            assert body == deflate(payload, level), (kind, level)
         out.append((kind, payload))
     return out
 
@@ -103,6 +104,28 @@ LEVELS = {
 }
 
 
+CHUNK = tfmt.DEFLATE_CHUNK
+# a session whose ARENA payload (~1.5 MB) is more than one chunk
+BIG_ROWS = 1024
+
+
+def _piecewise(payload: bytes, level: int) -> bytes:
+    """The zlib stream a frame's payload DEFLATEs to, built here from
+    zlib alone: ``zlib.compress`` for one chunk or less, else the
+    header, every ``CHUNK`` bytes as raw DEFLATE ended by a sync flush
+    (the last by ``Z_FINISH``), and the payload's Adler-32."""
+    if len(payload) <= CHUNK:
+        return zlib.compress(payload, level)
+    out = [zlib.compress(b"", level)[:2]]
+    for at in range(0, len(payload), CHUNK):
+        z = zlib.compressobj(level, zlib.DEFLATED, -zlib.MAX_WBITS)
+        last = at + CHUNK >= len(payload)
+        out.append(z.compress(payload[at:at + CHUNK]))
+        out.append(z.flush(zlib.Z_FINISH if last else zlib.Z_SYNC_FLUSH))
+    out.append(struct.pack(">I", zlib.adler32(payload)))
+    return b"".join(out)
+
+
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     s = _Served(str(tmp_path_factory.mktemp("ckpt")))
@@ -112,9 +135,45 @@ def served(tmp_path_factory):
         s.close()
 
 
+@pytest.fixture(scope="module")
+def big(tmp_path_factory):
+    s = _Served(str(tmp_path_factory.mktemp("big")), rows=BIG_ROWS)
+    try:
+        yield s
+    finally:
+        s.close()
+
+
 def test_every_acks_journal_is_the_sequential_one_and_continues_the_chain(
     served, tmp_path
 ):
+    _every_ack_is_the_sequential_journal(served, tmp_path)
+
+
+def test_every_acks_journal_in_chunks_is_the_sequential_one(big, tmp_path):
+    """The same at a payload above one chunk: the worker's feeding of
+    the ARENA frame ends inside a chunk the flush then continues, and
+    the journal is still the sequential path's, byte for byte."""
+    journals = _every_ack_is_the_sequential_journal(big, tmp_path)
+    ckpt = big.server.servicer.ckpt
+    # META, SNAPSHOT and OUTCOME one chunk each, ARENA more than one
+    assert ckpt.last_flush["chunks"] > len(WHOLE)
+    arena = _raw_frames(journals[-1])[2]
+    assert arena[0] == tfmt.KIND_ARENA and arena[1] & tfmt._FLAG_DEFLATE
+    payload = zlib.decompress(arena[2])
+    assert len(payload) > CHUNK
+    assert arena[2] == _piecewise(payload, LEVELS["checkpoint"])
+    assert arena[2] != zlib.compress(payload, LEVELS["checkpoint"])
+    # six flushes: the open's and five acks'
+    assert big.seam()["ckpt_chunks_sum"] == ckpt.chunks
+    assert ckpt.chunks > 6 * len(WHOLE)
+
+
+def _every_ack_is_the_sequential_journal(served, tmp_path) -> list:
+    """Five warm acks of a fresh servicer: each one's journal is the
+    one a checkpointer with no prefix writes for the same state, and
+    the second, loaded, continues the chain bit for bit. Returns the
+    journals."""
     ckpt = served.server.servicer.ckpt
     session = _session(served)
     # the cold open's flush: no warm structure to start from
@@ -161,6 +220,47 @@ def test_every_acks_journal_is_the_sequential_one_and_continues_the_chain(
         p4t, _t4p, _price = loaded.solve()
         np.testing.assert_array_equal(p4t, plans[n - 1])
     assert loaded.arena.last_stats["cold"] is False
+    return journals
+
+
+def test_a_journal_in_chunks_restores_and_continues_the_chain(
+    big, tmp_path
+):
+    """A journal whose frames were DEFLATEd in chunks reads through
+    ``read_frames`` and restores through ``load_one``, and so does the
+    one a writer of one zlib stream a frame, as before chunks,
+    wrote for the same state: both serve the chain's next ticks to the
+    served plans, and their payloads are the same frame for frame."""
+    session = _session(big)
+    ours = _journal(big.server.servicer.ckpt, big.sid)
+    payloads = _payloads_at(ours, LEVELS["checkpoint"], _piecewise)
+    assert [kind for kind, _ in payloads] == WHOLE
+    assert _kinds(ours, tmp_path) == WHOLE
+    # the same frames, each body one ``zlib.compress`` stream
+    theirs = bytearray(tfmt.MAGIC)
+    for kind, flags, body in _raw_frames(ours):
+        if flags & tfmt._FLAG_DEFLATE:
+            body = zlib.compress(zlib.decompress(body), LEVELS["checkpoint"])
+        theirs += tfmt._HEADER.pack(kind, flags, len(body), zlib.crc32(body))
+        theirs += body
+    theirs = bytes(theirs)
+    assert theirs != ours
+    assert _payloads_at(theirs, LEVELS["checkpoint"]) == payloads
+    loaded = [
+        _loads(journal, big.sid, tmp_path / f"load-{name}")
+        for name, journal in (("ours", ours), ("theirs", theirs))
+    ]
+    none = np.zeros(0, np.int32)
+    for _ in range(2):
+        big.tick()
+        rows = big.rows
+        p_delta = {k: v[rows] for k, v in big.p_cols.items()}
+        for restored in loaded:
+            restored.apply_delta(rows, p_delta, none, {})
+            p4t, _t4p, _price = restored.solve()
+            assert restored.arena.last_stats["cold"] is False
+            np.testing.assert_array_equal(p4t, big.plan)
+    assert loaded[0].tick == loaded[1].tick == session.tick - 2
 
 
 def _miss(served, before: dict, tmp_path, why: str) -> None:
@@ -297,9 +397,10 @@ def test_a_stream_fed_in_pieces_is_the_stream_of_one_call(level):
     d.feed(head)
     for _name, a in arrays:
         d.feed(tfmt.raw_bytes(a))
-    assert d.bytes_raw == len(whole)
+    assert d.bytes_raw == len(whole) <= CHUNK
     assert d.finish() == (1, zlib.compress(whole, level))
     assert d.finish() is d.finish()
+    assert d.chunks == 1
     assert d.take_ms() > 0 and d.take_ms() == 0
     # a payload DEFLATE cannot shorten is stored as it is, as _frame does
     noise = rng.bytes(4096)
@@ -307,6 +408,89 @@ def test_a_stream_fed_in_pieces_is_the_stream_of_one_call(level):
     d.feed(noise[:100])
     d.feed(noise[100:])
     assert d.finish() == (0, noise)
+
+
+LENGTHS = {
+    "empty": 0, "one-byte": 1, "chunk-less-1": CHUNK - 1, "chunk": CHUNK,
+    "chunk-plus-1": CHUNK + 1, "5.5-chunks": 5 * CHUNK + CHUNK // 2,
+}
+# where a payload's feeds end, by its length
+CUTS = {
+    "one-call": lambda n: [],
+    # every feed ends on a seam between two chunks
+    "at-seams": lambda n: list(range(CHUNK, n, CHUNK)),
+    # every seam falls inside a feed of eight bytes
+    "straddling": lambda n: [
+        x for s in range(CHUNK, n + 1, CHUNK) for x in (s - 3, s + 5)
+        if 0 < x < n
+    ],
+    # a one-byte feed first, then feeds of a third of a chunk or so
+    "ragged": lambda n: list(range(1, n, 333_331)),
+}
+
+
+@pytest.fixture(scope="module")
+def payloads():
+    """Bytes that DEFLATE well, as a journal's int32 columns do."""
+    rng = np.random.default_rng(42)
+    n = max(LENGTHS.values())
+    return rng.integers(0, 300, n // 4, dtype=np.int32).tobytes()
+
+
+@pytest.mark.parametrize("level", LEVELS.values(), ids=LEVELS.keys())
+@pytest.mark.parametrize("cuts", CUTS.values(), ids=CUTS.keys())
+@pytest.mark.parametrize("n", LENGTHS.values(), ids=LENGTHS.keys())
+def test_a_payload_fed_in_any_cuts_is_its_piecewise_stream(
+    n, cuts, level, payloads
+):
+    """Whatever the feeds, a frame's body is the piecewise stream of the
+    joined payload, which any ``zlib.decompress`` reads; at one chunk or
+    less it is ``zlib.compress``'s, so small frames keep their bytes."""
+    payload = payloads[:n]
+    d, at = tfmt.FrameDeflater(level), 0
+    for cut in [*cuts(n), n]:
+        d.feed(memoryview(payload)[at:cut])
+        at = cut
+    assert d.bytes_raw == n
+    flags, body = d.finish()
+    stream = _piecewise(payload, level)
+    assert zlib.decompress(stream) == payload
+    if len(stream) < n:
+        assert (flags, body) == (tfmt._FLAG_DEFLATE, stream)
+        assert zlib.decompress(body) == payload
+    else:  # stored as it is, as a frame always was
+        assert (flags, body) == (0, payload)
+    assert d.chunks == max(1, -(-n // CHUNK))
+    if n <= CHUNK:
+        assert stream == zlib.compress(payload, level)
+    else:
+        assert stream != zlib.compress(payload, level)
+    assert d.take_ms() > 0
+
+
+@pytest.mark.parametrize("n", [CHUNK + 1, 5 * CHUNK + CHUNK // 2])
+def test_the_trailer_is_the_payloads_adler32_from_its_chunks(n, payloads):
+    payload = payloads[:n]
+    d = tfmt.FrameDeflater(LEVELS["checkpoint"])
+    d.feed(payload)
+    flags, body = d.finish()
+    assert flags == tfmt._FLAG_DEFLATE and d.chunks > 1
+    assert body[-4:] == struct.pack(">I", zlib.adler32(payload))
+    # and the combination itself, at every chunk's seam and off them
+    for cut in (0, 1, CHUNK - 1, CHUNK, n - 1, n):
+        head, tail = payload[:cut], payload[cut:]
+        assert tfmt.adler32_combine(
+            zlib.adler32(head), zlib.adler32(tail), len(tail)
+        ) == zlib.adler32(payload), cut
+
+
+def test_a_payload_deflate_cannot_shorten_is_stored_whatever_its_chunks():
+    noise = np.random.default_rng(42).bytes(2 * CHUNK + 4096)
+    d = tfmt.FrameDeflater(LEVELS["checkpoint"])
+    d.feed(noise[:CHUNK + 7])
+    d.feed(noise[CHUNK + 7:])
+    assert d.finish() == (0, noise)
+    assert d.chunks == 3
 
 
 def test_the_levels_are_the_checkpoints_and_the_traces():
@@ -433,6 +617,21 @@ def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):
     from threads of their own under a short switch interval: a flush
     takes its own session's prefix or none (never another's, never
     half of one), so each journal is the sequential one."""
+    _share_one_worker(tmp_path, n_sessions=6, ticks=4, rows=64, top_k=16)
+
+
+def test_sessions_share_one_worker_and_every_journal_in_chunks_is_whole(
+    tmp_path,
+):
+    """The same where an ARENA frame is more than one chunk: the
+    sessions' chunks share the one DEFLATE pool as well."""
+    ckpt = _share_one_worker(
+        tmp_path, n_sessions=3, ticks=3, rows=BIG_ROWS, top_k=64
+    )
+    assert ckpt.chunks > ckpt.flushes * len(WHOLE)
+
+
+def _share_one_worker(tmp_path, n_sessions, ticks, rows, top_k):
     from protocol_tpu.ops.cost import CostWeights
     from protocol_tpu.proto import wire
     from protocol_tpu.services.session_store import (
@@ -441,7 +640,6 @@ def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):
     )
     from protocol_tpu.trace.synth import synth_providers, synth_requirements
 
-    n_sessions, ticks, rows = 6, 4, 64
     ckpt = SessionCheckpointer(str(tmp_path / "shared"))
     refs = SessionCheckpointer(str(tmp_path / "refs"))
     sessions = []
@@ -455,9 +653,9 @@ def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):
         )
         session = SolveSession(
             session_id=f"s{i}@t", fingerprint=f"fp{i}",
-            weights=CostWeights(), kernel="jax", threads=0, top_k=16,
+            weights=CostWeights(), kernel="jax", threads=0, top_k=top_k,
             p_cols=p_cols, r_cols=r_cols, n_providers=rows, n_tasks=rows,
-            arena=make_solve_arena("jax", k=16, threads=0),
+            arena=make_solve_arena("jax", k=top_k, threads=0),
         )
         with session.lock:
             session.last_p4t = session.solve()[0]
@@ -510,3 +708,4 @@ def test_sessions_share_one_worker_and_every_journal_is_whole(tmp_path):
     assert len(outcomes) == n_sessions * ticks
     assert set(outcomes) <= {"hit", "miss"} and "hit" in outcomes
     assert ckpt.flush_failures == 0 and not ckpt._jobs
+    return ckpt

@@ -99,11 +99,11 @@ def time_limit():
 
 class _Served:
     """A loopback servicer with checkpoint-before-ack on and one
-    session of ``ROWS`` rows (``kernel``: jax); ``tick()`` sends the
+    session of ``rows`` rows (``kernel``: jax); ``tick()`` sends the
     next warm delta (1% of providers re-priced, ``self.rows``) under a
     client span and returns that span's trace id."""
 
-    def __init__(self, ckpt_dir: str, kernel: str = "jax"):
+    def __init__(self, ckpt_dir: str, kernel: str = "jax", rows: int = ROWS):
         from protocol_tpu.fleet.fabric import FleetConfig
         from protocol_tpu.ops.cost import CostWeights
         from protocol_tpu.proto import wire
@@ -127,8 +127,9 @@ class _Served:
         )
         self.client = SchedulerBackendClient(address)
         self.rng = np.random.default_rng(26)
-        ep = synth_providers(self.rng, ROWS)
-        er = synth_requirements(self.rng, ROWS)
+        self.n_rows = rows
+        ep = synth_providers(self.rng, rows)
+        er = synth_requirements(self.rng, rows)
         w = CostWeights()
         self.p_cols = wire.canon_columns(ep, wire.P_WIRE_DTYPES)
         r_cols = wire.canon_columns(er, wire.R_WIRE_DTYPES)
@@ -151,7 +152,7 @@ class _Served:
 
         self.n += 1
         rows = self.rows = np.sort(
-            self.rng.choice(ROWS, 3, replace=False)
+            self.rng.choice(self.n_rows, 3, replace=False)
         ).astype(np.int32)
         price = self.p_cols["price"]
         price[rows] = self.rng.uniform(0.5, 9.0, 3).astype(price.dtype)
@@ -755,14 +756,14 @@ _SEAM_BEFORE = {
     "ckpt_deflate_ms_sum": 80.0, "bytes_ckpt": 1000.0,
     "ckpt_overlap_ms_sum": 700.0, "session_ckpt_prefix_hit": 7.0,
     "ckpt_join_ms_sum": 30.0, "ckpt_worker_ms_sum": 100.0,
-    "ckpt_encode_ms_sum": 20.0,
+    "ckpt_encode_ms_sum": 20.0, "ckpt_chunks_sum": 112.0,
 }
 _SEAM_AFTER = {
     "apply_ms_sum": 5.0, "ckpt_flush_ms_sum": 1300.0,
     "ckpt_deflate_ms_sum": 1080.0, "bytes_ckpt": 7001000.0,
     "ckpt_overlap_ms_sum": 1600.0, "session_ckpt_prefix_hit": 9.0,
     "ckpt_join_ms_sum": 54.0, "ckpt_worker_ms_sum": 500.0,
-    "ckpt_encode_ms_sum": 120.0,
+    "ckpt_encode_ms_sum": 120.0, "ckpt_chunks_sum": 144.0,
 }
 # metric -> (layer, unit, source, the key whose absence silences it,
 #            expected value on the canned context[, better])
@@ -858,6 +859,9 @@ METRICS = {
     "ckpt_encode_ms_per_ack": (
         "session, arena bookkeeping and checkpoint", "ms", "program_span",
         "ckpt_encode_ms_sum", 50.0),
+    "ckpt_chunks_per_ack": (
+        "session, arena bookkeeping and checkpoint", "chunks",
+        "program_counter", "ckpt_chunks_sum", 16.0, "higher"),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -880,7 +884,8 @@ CELLS.update(dict.fromkeys(
 CELLS.update(dict.fromkeys(
     ("ckpt_join_ms_per_ack", "scan_rounds_per_ack",
      "solve_open_read_ms_per_ack", "quality_gap_ms_per_ack",
-     "ckpt_worker_ms_per_ack", "ckpt_encode_ms_per_ack"),
+     "ckpt_worker_ms_per_ack", "ckpt_encode_ms_per_ack",
+     "ckpt_chunks_per_ack"),
     ["pool-large.ticks", "pool-slack.ticks", "pool-queued.ticks"],
 ))
 
