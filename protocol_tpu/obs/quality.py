@@ -109,8 +109,20 @@ def duality_gap(
     # same dual point the engine's in-solve certificate pass uses
     cmax = float(cand_c[feas].max()) if feas.any() else 0.0
     price = np.minimum(price, 2.0 * cmax + 10.0)
+    # ... and NONNEGATIVE it has to be: where a provider some task lists
+    # carries a price under 0 (a pool whose floor went below 0, one
+    # whose free providers sit at the least seated price), every price
+    # is raised by as much. A uniform shift is a dual point too, never a
+    # lower bound than the optimum; with no such price it is no shift
+    # at all. A provider no task lists (a row that left) is in no sum.
     safe_p = np.maximum(cand_p, 0)
-    adj = np.where(feas, cand_c.astype(np.float64) + price[safe_p], np.inf)
+    listed = price[safe_p]
+    if price.size and price.min() < 0.0:
+        listed_lo = float(np.where(feas, listed, 0.0).min())
+        if listed_lo < 0.0:
+            price = price - listed_lo
+            listed = listed - listed_lo
+    adj = np.where(feas, cand_c.astype(np.float64) + listed, np.inf)
     best = adj.min(axis=1)
 
     rows = np.flatnonzero(p4t >= 0)

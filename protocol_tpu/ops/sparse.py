@@ -26,6 +26,7 @@ BASELINE.md configs #3-#5.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 
@@ -547,6 +548,12 @@ def _sparse_auction_phase(
             newly_retired = f_ok & (v1 < give_up)
             bidding = f_ok & ~newly_retired & (v1 > _NEG * 0.5)
             if reserve is None:
+                # a task with one candidate has giving up for its
+                # runner-up: it bids that one up to the give-up level,
+                # not by frontier_bids' floor of 1e8, which leaves a
+                # price no float32 carries with eps to spare (the warm
+                # shift then moves every other price by as much)
+                v2 = jnp.where(v2 <= -1e8, jnp.maximum(give_up, -1e8), v2)
                 bid_amt = price[p1] + (v1 - v2) + eps  # [width]
             else:
                 bid_amt = price[p1] + jnp.minimum(
@@ -693,7 +700,8 @@ def _sparse_auction_phase(
 @jax.jit
 @jax.named_scope("auction.unassign_unhappy")
 def _unassign_unhappy(
-    cand_provider, cand_cost, price, owner, p4t, eps_next, reserve=None
+    cand_provider, cand_cost, price, owner, p4t, eps_next, reserve=None,
+    joined=None,
 ):
     """eps-CS repair between phases: holders whose assignment violates the
     tighter eps re-enter the auction; happy holders stay seated (avoids both
@@ -709,24 +717,65 @@ def _unassign_unhappy(
     65k: 33,264/65,524 pairs within 1e-4 of the boundary, none beyond
     eps + 1e-3. Without the tolerance a warm restart at the SAME eps
     evicts that entire boundary population (~32k seeds for 655 churned
-    tasks) and re-solves from scratch."""
+    tasks) and re-solves from scratch.
+
+    ``joined`` [P] marks providers that came back since the prices were
+    made (a row whose ``valid`` went True again): the price such a row
+    carries is the one it left with, from another regime perhaps, and
+    one far below the landscape makes every seated task that lists it
+    unhappy at once (at 256 rows, two returners a tick freed ~55 seats
+    and the queue pass ran 1,900-5,707 rounds for them). Each is priced
+    where the seated task that values it most would just take it (no
+    seat is worse off than before it came), and the prices are returned
+    as a third result. The solves call it through :func:`_repair_seats`,
+    which hands every argument over as an array, so that one program
+    serves every regime: two, a tick with a row coming back and one
+    without (the scatter over ``[T, K]`` costs the chip a few ms, which
+    a tick with nobody coming back does not pay)."""
     cand_valid = cand_provider >= 0
     cand_safe = jnp.where(cand_valid, cand_provider, 0)
     value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)  # [T,K]
-    v1 = jnp.max(value, axis=1)
-    if reserve is not None:
-        v1 = jnp.maximum(v1, reserve)
     held = p4t  # [T]
     vcur = jnp.max(
         jnp.where(cand_safe == jnp.maximum(held, 0)[:, None], value, _NEG), axis=1
     )
+    P = owner.shape[0]
+    if joined is not None:
+        # the most any seated task would pay for each provider, its own
+        # seat's value given up: -cost - (seat value)
+        offer = jnp.where(
+            cand_valid & (held >= 0)[:, None], -cand_cost - vcur[:, None], _NEG
+        )
+        top = jnp.full(P + 1, _NEG).at[
+            jnp.where(cand_valid, cand_provider, P)
+        ].max(offer)[:P]
+        price = jnp.where(joined & (top > _NEG * 0.5), top, price)
+        value = jnp.where(cand_valid, -cand_cost - price[cand_safe], _NEG)
+    v1 = jnp.max(value, axis=1)
+    if reserve is not None:
+        v1 = jnp.maximum(v1, reserve)
     finite_max = jnp.max(jnp.where(cand_valid, cand_cost, 0.0))
     tol = 1e-5 * (1.0 + finite_max + jnp.max(jnp.abs(price)))
     unhappy = (held >= 0) & (vcur < v1 - eps_next - tol)
-    P = owner.shape[0]
     owner = owner.at[jnp.where(unhappy, held, P)].set(-1, mode="drop")
     p4t = jnp.where(unhappy, -1, p4t)
+    if joined is not None:
+        return owner, p4t, price
     return owner, p4t
+
+
+def _repair_seats(cand_provider, cand_cost, price, owner, p4t, eps, reserve,
+                  joined=None):
+    """:func:`_unassign_unhappy` as every solve calls it: (owner, p4t,
+    price). No reserve is a reserve of -inf (a max with it is the value
+    itself)."""
+    args = (
+        cand_provider, cand_cost, price, owner, p4t, jnp.float32(eps),
+        jnp.float32(-jnp.inf if reserve is None else reserve),
+    )
+    if joined is None:
+        return (*_unassign_unhappy(*args), price)
+    return _unassign_unhappy(*args, jnp.asarray(joined, bool))
 
 
 # The reverse pass runs in a pool whose slack (:func:`_stranded`) is at
@@ -774,32 +823,47 @@ def _transpose_candidates(cand_provider, cand_cost, num_providers: int, width: i
 @jax.jit
 @jax.named_scope("auction.reverse")
 def _stranded(cand_provider, price, owner, p4t):
-    """(floor, stranded mask, [stranded, slack, listed providers]).
+    """(listed [P], floor, the least seated price, [stranded, slack,
+    listed providers]).
 
-    Stranded: the providers a converged forward phase left free at a
-    price above the floor, the lowest price any provider carries (what
-    one nobody ever bid for still has); prices within float dust of the
-    floor do not count. Slack: the providers left free that some task
-    lists, less TWICE the tasks left open that list any; positive where
-    most of the free providers are free for want of tasks and not
-    because their tasks were priced out. :func:`_forward_reverse` holds
-    it against the listed providers (``_SLACK_SHARE``)."""
+    Only providers some task lists (``listed``) take part: a row no
+    task lists (one that left the pool, or that nobody can use) is
+    never stranded and sets no floor, whatever price it carries.
+    Stranded: the listed providers a converged forward phase left free
+    at a price above the floor, the lowest price a listed provider
+    carries (what one nobody ever bid for still has); prices within
+    float dust of the floor do not count. The least price a SEATED
+    provider carries is the floor of a pool in the band
+    (:func:`_forward_reverse`'s ``band``): the asymmetric condition
+    asks no more than that no free provider is dearer than a seated
+    one. Slack: the providers left free that some task lists, less
+    TWICE the tasks left open that list any; positive where most of the
+    free providers are free for want of tasks and not because their
+    tasks were priced out. :func:`_forward_reverse` holds it against
+    the listed providers (``_SLACK_SHARE``)."""
     P = price.shape[0]
     listed = cand_provider >= 0
     reach = jnp.zeros(P + 1, jnp.int32).at[
         jnp.where(listed, cand_provider, P).ravel()
     ].add(1)[:P] > 0
-    floor = jnp.min(price)
-    tol = 1e-5 * (1.0 + jnp.max(jnp.abs(price)))
     free = owner < 0
-    stranded = free & (price > floor + tol)
+    floor = jnp.min(jnp.where(reach, price, jnp.inf))
+    floor = jnp.where(jnp.isfinite(floor), floor, 0.0)
+    seated = jnp.min(jnp.where(reach & ~free, price, jnp.inf))
+    seated = jnp.where(jnp.isfinite(seated), seated, floor)
+    stranded = free & reach & (price > floor + _dust(price))
     slack = jnp.sum(free & reach, dtype=jnp.int32) - 2 * jnp.sum(
         (p4t < 0) & jnp.any(listed, axis=1), dtype=jnp.int32
     )
-    return floor, stranded, jnp.stack([
+    return reach, floor, seated, jnp.stack([
         jnp.sum(stranded, dtype=jnp.int32), slack,
         jnp.sum(reach, dtype=jnp.int32),
     ])
+
+
+def _dust(price):
+    """How far above a floor a price may lie and still be at it."""
+    return 1e-5 * (1.0 + jnp.max(jnp.abs(price)))
 
 
 def _task_values(cand_provider, cand_cost, price, p4t):
@@ -817,17 +881,19 @@ def _task_values(cand_provider, cand_cost, price, p4t):
 
 @jax.jit
 @jax.named_scope("auction.reverse")
-def _reverse_seed(cand_provider, cand_cost, price, owner, p4t):
+def _reverse_seed(cand_provider, cand_cost, price, owner, p4t, listed, floor):
     """State of the reverse phase, in :func:`_sparse_auction_phase`'s
     layout with the roles swapped (providers bid, tasks are bid for):
     a task's "price" is its profit, the value of its seat (of its best
     candidate when it has none), so a provider's value for a task,
     ``-cost - profit``, is the price at which that task would just take
-    it. Free providers at the floor do not bid (no seated task prefers
-    one by more than eps, or the forward phase would not have ended)."""
+    it. Only the listed providers stranded above ``floor`` bid
+    (:func:`_stranded`'s ``listed`` and one of its floors): one at the
+    floor does not (no seated task prefers it by more than eps, or the
+    forward phase would not have ended). Returns (state, floor)."""
     value, seat = _task_values(cand_provider, cand_cost, price, p4t)
     profit = jnp.where(p4t >= 0, seat, jnp.max(value, axis=1))
-    floor, stranded, _ = _stranded(cand_provider, price, owner, p4t)
+    stranded = (owner < 0) & listed & (price > floor + _dust(price))
     return (jnp.int32(0), profit, p4t, owner, (owner < 0) & ~stranded), floor
 
 
@@ -867,6 +933,7 @@ def _forward_reverse(
     cand_provider, cand_cost, num_providers: int, state, eps,
     max_iters: int, frontier: int, stall_limit: int,
     stats_out: dict | None, transposed: list, reserve: float | None = None,
+    band: bool = False,
 ):
     """One eps phase to the condition the pool's regime needs: with
     ``reserve`` (a pool with a queue) :func:`_queue_phase`'s; without,
@@ -890,7 +957,17 @@ def _forward_reverse(
     One read of three scalars says whether any provider is stranded and
     whether the pool has slack enough (:func:`_stranded`,
     ``_SLACK_SHARE``); unless both, nothing else runs and the prices
-    come back bit for bit. ``transposed``
+    come back bit for bit. ``band`` (a solve whose pool came into the
+    band between the regimes from either side, ``REGIMES``) lets any
+    slack above 0 do, and the pass then lowers the stranded providers
+    only as far as the least price a seated provider carries, which is
+    all the asymmetric condition asks: from there a chain ends at the
+    first provider no task would take above the seated ones, where from
+    the floor it would have to carry the whole landscape down (a full
+    pool, ``"none"``, keeps the forward phase's plan as it always has:
+    its few free providers are the ones its unseatable tail leaves,
+    and a chain through its pool cost 4.7 s a tick for 0.004 a task at
+    8,192 rows). ``transposed``
     caches the transposed graph for the solve. Each step of the pass is
     an ``auction.reverse`` span. ``stats_out`` gains ``free_repriced``
     (providers the pass lowered), ``reverse_rounds``, ``reverse_ms`` and
@@ -912,22 +989,23 @@ def _forward_reverse(
     rounds = int(it) if stats_out is not None else 0
     t0 = time.perf_counter()
     with _tracer.span("auction.reverse", eps=eps, step="check") as sp:
-        n_stranded, slack, listed = (
-            int(n) for n in np.asarray(
-                _stranded(cand_provider, price, owner, p4t)[2]
-            )
+        reach, floor, seated_floor, counts = _stranded(
+            cand_provider, price, owner, p4t
         )
+        n_stranded, slack, listed = (int(n) for n in np.asarray(counts))
         if sp is not None:
             sp["attrs"].update(stranded=n_stranded, slack=slack)
     lowered = reverse_rounds = reverse_rows = reverse_scans = 0
-    if n_stranded > 0 and slack > 0 and slack * _SLACK_SHARE >= listed:
+    band = band and slack * _SLACK_SHARE < listed
+    if n_stranded > 0 and slack > 0 and (band or slack * _SLACK_SHARE >= listed):
         with _tracer.span("auction.reverse", step="seed", dispatch_only=True):
             if not transposed:
                 transposed.extend(_transpose_candidates(
                     cand_provider, cand_cost, num_providers, _REVERSE_WIDTH
                 ))
             rstate, floor = _reverse_seed(
-                cand_provider, cand_cost, price, owner, p4t
+                cand_provider, cand_cost, price, owner, p4t, reach,
+                seated_floor if band else floor,
             )
         profit0 = rstate[1]
         # every stranded provider may bid at once: the kernel fits each
@@ -958,23 +1036,44 @@ def _forward_reverse(
 
 
 # A pool has a queue where the tasks that list a provider outnumber the
-# providers any task lists by at least one in ``_QUEUE_SHARE`` of those
-# providers. Under that the queue pass does no better than the forward
-# auction's give-up level, which decides there as it always has: with a
-# handful waiting, only the seats within their reach are priced against
-# the reserve, and the chains that would carry it to the rest outlast
-# the stall breaker (1,024 providers on the CPU: 10 waiting, 0.034-0.046
-# a seated task off the optimum with the pass and 0.027-0.041 without;
-# 21 waiting, 0.0002-0.018; 51, under 0.004). A full pool's unseatable
-# tail is no queue by this count at all: the served one lists every
-# provider and every task, 8,192 of each, on every tick.
-_QUEUE_SHARE = _SLACK_SHARE
+# providers any task lists, by however few: its binding phase runs the
+# chains that decide who waits to their end (:func:`_queue_phase`), so a
+# handful waiting is a queue like any other (820 providers on the CPU: 7
+# waiting, 0.0001 a seated task off the optimum, where the forward
+# auction's give-up level left it 0.0345 off). A full pool's unseatable
+# tail is no queue: the served one lists every provider and every task,
+# 8,192 of each, on every tick, and a task nobody can serve lists no
+# provider.
+
+
+# The regimes a solve names (``auction.regime``, the arena's carried
+# ``regime``): a pool with a queue, one with idle nodes (one in
+# ``_SLACK_SHARE`` of the listed providers free, at least), and between
+# them, a few idle nodes or none: ``"none"`` for a full pool, which
+# has always been there (its unseatable tail), ``"band"`` for a pool
+# that came into it from either side and is still there
+# (:func:`assign_auction_sparse_warm`). The band is told from the
+# carried regime and not from the pool alone: a full pool with a few
+# tasks nobody can serve has a few more listed providers than tasks
+# that list one, as the band has, and a reverse pass there moves seats
+# the parent's solve kept (33 of 512 on ``tests/test_pool_slack.py``'s
+# full pool with four unservable tasks) and cost 4.7 s a tick at 8,192
+# rows for 0.004 a task.
+REGIMES = ("none", "slack", "queue", "band")
 
 
 def _queue_reserve(cand_provider, cand_cost, num_providers: int,
-                   reserve0: float | None, stats_out: dict | None):
+                   reserve0: float | None, stats_out: dict | None,
+                   regime_out: dict | None = None,
+                   regime0: str | None = None):
     """The reserve of a pool with a queue, or None where the pool has
-    none. The regime is the candidate graph's, counted on the host
+    none; ``regime_out["regime"]`` gets the regime's name (``REGIMES``):
+    a queue where tasks that list a provider outnumber the providers any
+    task lists, slack where the providers outnumber the tasks by one in
+    ``_SLACK_SHARE``, ``"none"`` between, which is ``"band"`` where the
+    duals were made in another regime (``regime0``, the carried one: a
+    pool that came there from either side and is still there). The regime
+    is the candidate graph's, counted on the host
     (tasks that list a provider against providers some task lists: the
     arena and the matcher hold the lists as NumPy, so no program runs
     and nothing is read back; a device array is copied over first), an
@@ -1006,7 +1105,15 @@ def _queue_reserve(cand_provider, cand_cost, num_providers: int,
             + (time.perf_counter() - t0) * 1e3, 3
         )
         stats_out.setdefault("queue_rounds", 0)
-    if queued <= 0 or queued * _QUEUE_SHARE < reach:
+    queue = queued > 0
+    if regime_out is not None:
+        regime_out["regime"] = (
+            "queue" if queue
+            else "slack" if -queued * _SLACK_SHARE >= reach > 0
+            else "band" if regime0 not in (None, "none")
+            else "none"
+        )
+    if not queue:
         return None
     finite_max = float(np.max(np.asarray(cand_cost), where=listed, initial=0.0))
     if reserve0 is not None and (
@@ -1073,7 +1180,15 @@ def _queue_phase(
        whose best seat is not worth eps more than that waits. Bids evict
        and never vacate, so step 1 need not run again; prices are
        bounded by the reserve, so the phase ends by itself, and the
-       tasks it leaves open are the ones that wait.
+       tasks it leaves open are the ones that wait. So the binding
+       phase (the warm solve's, the ladder's last) is run with no stall
+       breaker (``stall_limit`` 0): a breaker counts seats, and every
+       provider a task lists is seated once step 1 is done, so under
+       it the phase had a fixed budget of 512 rounds, and a queue of a
+       few tasks next to P = T needs the chains that find who waits
+       run to their end (CPU, 820 providers: 7 waiting, 1,180 rounds
+       and 0.0001 a seated task off the optimum, 0.0117-0.0345 cut at
+       512).
 
     Step 1 is ``auction.queue`` spans (``check``, closed at the pass's
     one read of a scalar, then ``seed``, the segments and ``finish``);
@@ -1170,13 +1285,19 @@ def _greedy_cleanup_compacted(cand_provider, cand_cost, owner, p4t, budget: int)
     )
 
 
-def _greedy_cleanup(cand_provider, cand_cost, owner, p4t):
+def _greedy_cleanup(cand_provider, cand_cost, owner, p4t, build: bool = False):
     """Host wrapper: one scalar readback decides whether cleanup is needed;
     the compaction budget is a pow-2 bucket of the open count (of tasks
     that list a provider: a session's padded rows never do, and a pool
-    with fewer tasks than its row bucket would sweep them every tick)."""
+    with fewer tasks than its row bucket would sweep them every tick).
+
+    ``build`` (the ladder's): sweep at the smallest bucket even where
+    nothing is open, so that the program a warm tick of the band needs
+    (a few tasks left open beside as many providers) is built with the
+    session's cold open and not on that tick; where nothing is open the
+    sweep seats nobody."""
     n_open = int(jnp.sum((p4t < 0) & jnp.any(cand_provider >= 0, axis=1)))
-    if n_open == 0:
+    if n_open == 0 and not build:
         return p4t
     budget = 1024
     while budget < n_open:
@@ -1207,6 +1328,8 @@ def assign_auction_sparse_scaled(
     stall_limit: int = 64,
     stats_out: dict | None = None,
     with_state: bool = False,
+    regime_out: dict | None = None,
+    regime0: str | None = None,
 ):
     """eps-scaling auction: geometric eps ladder with warm-started prices
     (Bertsekas' eps-scaling — total bid events O(n log(1/eps)) instead of
@@ -1240,14 +1363,23 @@ def assign_auction_sparse_scaled(
     chain that does not carry the mask re-fights the unfillable tail's
     full stall budget on every solve (measured: 1792 vs 476 rounds at a
     tail-heavy 2048).
+
+    ``regime_out["regime"]`` gets the pool's regime (:func:`_queue_reserve`,
+    which names the band from ``regime0``, the regime the carried duals
+    were made in: a dual refresh passes it, a session's open has none).
+    In the band the final phase runs the reverse pass
+    (:func:`_forward_reverse`), as the warm solve does.
     """
     state = None
     # (lists held on the host go up while the host counts them)
     with _tracer.span("auction.upload", dispatch_only=True):
         lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    regime_out = {} if regime_out is None else regime_out
     reserve = _queue_reserve(
-        cand_provider, cand_cost, num_providers, None, stats_out
+        cand_provider, cand_cost, num_providers, None, stats_out, regime_out,
+        regime0,
     )
+    band = regime_out.get("regime") == "band"
     cand_provider, cand_cost = lists
     if reserve is not None:
         # a pool with a queue opens with every seat priced out of
@@ -1271,14 +1403,23 @@ def assign_auction_sparse_scaled(
             # the FINAL phase's retirement is binding and its eviction
             # chains (closing eps_end-sized price gaps) legitimately
             # make no net progress for long stretches — give it 8x the
-            # circuit-breaker budget of the disposable coarse phases
-            stall_limit=stall_limit * (8 if final else 1),
+            # circuit-breaker budget of the disposable coarse phases,
+            # and none where tasks wait (see _queue_phase), whose
+            # coarse phases get the 8x (cut at 64 rounds, the ladder
+            # that seats a queue of 7 of 827 ended 0.021 a seated task
+            # off the optimum on the CPU, at 512 0.0005)
+            stall_limit=(
+                0 if reserve is not None else stall_limit * 8
+            ) if final else stall_limit * (8 if reserve is not None else 1),
             stats_out=stats_out, transposed=transposed, reserve=reserve,
+            band=band and final,
         )
         # per-phase round count (read back only when asked for)
         rounds_total += rounds
         if final:
-            _report_stall("scaled", stall, stall_limit * 8, stats_out)
+            # (a queue's forward phase runs without the breaker)
+            _report_stall("scaled", stall, 0 if reserve is not None
+                          else stall_limit * 8, stats_out)
             if stats_out is not None:
                 # the platform-independent cost driver: wall = rounds x
                 # per-round kernel cost. Exposed so frontier/eps tuning
@@ -1288,7 +1429,7 @@ def assign_auction_sparse_scaled(
         eps = max(eps * scale, eps_end)
         it, price, owner, p4t, retired = state
         with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
-            owner, p4t = _unassign_unhappy(
+            owner, p4t, _ = _repair_seats(
                 cand_provider, cand_cost, price, owner, p4t, eps, reserve
             )
             # un-retire: coarse-phase retirement was only the circuit
@@ -1299,9 +1440,19 @@ def assign_auction_sparse_scaled(
     _, price, owner, p4t, retired = state
     with _tracer.span("auction.cleanup"):
         # (a pool with a queue has nobody to sweep: a provider its
-        # queue pass leaves free is one no waiting task lists)
+        # queue pass leaves free is one no waiting task lists; the
+        # sweep's smallest program is built all the same, and seats
+        # nobody, since no listed provider is free)
         if reserve is None:
-            p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+            p4t = _greedy_cleanup(
+                cand_provider, cand_cost, owner, p4t, build=True
+            )
+        else:
+            p4t = _greedy_cleanup_compacted(
+                cand_provider, cand_cost, owner, p4t,
+                min(1024, int(cand_cost.shape[0])),
+            )
+    _regime_stats(stats_out)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
         # a retired task the greedy cleanup managed to seat is assigned,
@@ -1310,6 +1461,20 @@ def assign_auction_sparse_scaled(
     if with_prices:
         return res, price
     return res
+
+
+def _regime_stats(stats_out: dict | None) -> None:
+    """The crossing's counters, 0 on a solve that crossed nothing, and
+    ``transposed_rounds``: the reverse and queue passes' rounds of the
+    solve in one counter."""
+    if stats_out is not None:
+        stats_out.setdefault("regime_change", 0)
+        stats_out.setdefault("cross_ms", 0.0)
+        stats_out.setdefault("cross_rounds", 0)
+        stats_out["transposed_rounds"] = int(
+            stats_out.get("reverse_rounds", 0)
+            + stats_out.get("queue_rounds", 0)
+        )
 
 
 def _phase_adaptive(
@@ -1462,6 +1627,9 @@ def assign_auction_sparse_warm(
     retired0: jax.Array | None = None,
     with_state: bool = False,
     reserve0: float | None = None,
+    joined0: np.ndarray | None = None,
+    regime0: str | None = None,
+    regime_out: dict | None = None,
 ) -> tuple[AssignResult, jax.Array]:
     """Incremental (delta-frontier) auction solve: SURVEY §7 hard part 4.
 
@@ -1470,7 +1638,13 @@ def assign_auction_sparse_warm(
     every population change would waste the batched win the same way. This
     warm start carries the auction's dual state across solves:
 
-      ``price0`` [P]  final prices of the previous solve (new providers: 0).
+      ``price0`` [P]  final prices of the previous solve, a row per
+                      provider row, live or not: a row no task lists
+                      (one that left the pool, or that nobody can
+                      use) is in no candidate list, so its price moves
+                      nothing, sets no floor (:func:`_stranded`) and
+                      enters no certificate; a row that comes back is
+                      priced anew (``joined0``).
       ``p4t0``  [T]   previous assignment re-expressed in the new index
                       space (-1 for new/changed tasks). Must be injective
                       over >= 0.
@@ -1498,12 +1672,36 @@ def assign_auction_sparse_warm(
     None). The regime is read off the candidate graph at every solve
     (:func:`_queue_reserve`). A pool that has a queue now and carried
     none (it had no queue a tick ago, or its costs have left the
-    anchor) has duals of another regime: the solve re-grounds them with
-    the cold ladder, once. In a pool with a queue prices are held
-    between 0 and the reserve's level by the bidding itself, so the
-    uniform shift below is left out, and "retired" means "waits at
-    these prices", which every solve decides anew; a mask carried out of
-    a queue is dropped where the pool has none any more.
+    anchor), or that carried one and has none now, has duals of another
+    regime: the solve re-grounds them with the cold ladder, once, and
+    carries neither their prices nor their retirement mask. In a pool
+    with a queue prices are held between 0 and the reserve's level by
+    the bidding itself, so the uniform shift below is left out, and
+    "retired" means "waits at these prices", which every solve decides
+    anew.
+
+    ``regime0`` is the regime the carried duals were made in (a name of
+    ``REGIMES``; None: the one ``reserve0`` implies), and
+    ``regime_out["regime"]`` gets this solve's. A solve whose regime
+    differs is a crossing, counted in ``stats_out`` (``regime_change``;
+    ``cross_ms`` and ``cross_rounds``, the wall and the rounds of the
+    re-ground where one runs, all rounds of its ladder; 0 elsewhere).
+    A pool that LEAVES a queue carries duals made against the reserve,
+    every seat priced where a waiting task would just not take it, and
+    a reverse pass from there has to carry every freed provider down
+    the whole landscape: 20,224 rounds, its budget, at 256 rows (8.2-12
+    certificate), 37 s at 8,192. So the crossing re-grounds either way,
+    as a pool that enters a queue always has, once, in an
+    ``auction.regime`` span (``step="cross"``, ``from``, ``to``). A
+    pool that came into the band from either side (``"band"``) runs its
+    reverse pass there too, to the least seated price, for as long as
+    it stays there, warm and in the ladder of a dual refresh, which is
+    handed the carried regime (:func:`_forward_reverse`'s ``band``; CPU, 820
+    providers: 4 idle beside a full pool, 0.0143 a seated task off the
+    optimum without it; 8,192 on the chip, 83 idle, 0.048).
+
+    ``joined0`` [P]: providers that came back since the carried prices
+    were made (:func:`_unassign_unhappy`).
 
     Returns (AssignResult, final prices [P]), plus the final retirement
     mask [T] and the reserve (None without a queue) when
@@ -1512,20 +1710,41 @@ def assign_auction_sparse_warm(
     # (lists held on the host go up while the host counts them)
     with _tracer.span("auction.upload", dispatch_only=True):
         lists = jnp.asarray(cand_provider), jnp.asarray(cand_cost)
+    regime_out = {} if regime_out is None else regime_out
+    if regime0 is None and reserve0 is not None:
+        regime0 = "queue"
     reserve = _queue_reserve(
-        cand_provider, cand_cost, num_providers, reserve0, stats_out
+        cand_provider, cand_cost, num_providers, reserve0, stats_out,
+        regime_out, regime0,
     )
-    if reserve is not None and reserve != reserve0:
+    regime = regime_out.get("regime")
+    crossed = None not in (regime0, regime) and regime0 != regime
+    band = regime == "band"
+    if (reserve is None) != (reserve0 is None) or (
+        reserve is not None and reserve != reserve0
+    ):
         # (the ladder counts the lists again, on the host: once, at the
-        # tick a pool enters the regime)
-        out = assign_auction_sparse_scaled(
-            cand_provider, cand_cost, num_providers, eps_end=eps,
-            frontier=frontier, stall_limit=stall_limit,
-            stats_out=stats_out, with_state=True,
-        )
+        # tick a pool enters or leaves the regime)
+        t0 = time.perf_counter()
+        with _tracer.span(
+            "auction.regime", step="cross",
+            **{"from": regime0 or "none", "to": regime or "none"},
+        ) if crossed else contextlib.nullcontext():
+            out = assign_auction_sparse_scaled(
+                cand_provider, cand_cost, num_providers, eps_end=eps,
+                frontier=frontier, stall_limit=stall_limit,
+                stats_out=stats_out, with_state=True, regime0=regime0,
+            )
+        if crossed and stats_out is not None:
+            stats_out["regime_change"] = 1
+            stats_out["cross_ms"] = round(
+                (time.perf_counter() - t0) * 1e3, 3
+            )
+            stats_out["cross_rounds"] = int(
+                stats_out.get("rounds_total", 0)
+                + stats_out.get("transposed_rounds", 0)
+            )
         return out if with_state else out[:2]
-    if reserve is None and reserve0 is not None:
-        retired0 = None
     cand_provider, cand_cost = lists
     with _tracer.span("auction.seed", eps=eps, dispatch_only=True):
         # a seed for a task with NO candidates would sail through the eps-CS
@@ -1553,8 +1772,9 @@ def assign_auction_sparse_warm(
             shift = jnp.maximum(jnp.max(price0) - (finite_max + 5.0), 0.0)
             price0 = price0 - shift
         owner0 = _invert(p4t0, num_providers)
-        owner0, p4t0 = _unassign_unhappy(
-            cand_provider, cand_cost, price0, owner0, p4t0, eps, reserve
+        owner0, p4t0, price0 = _repair_seats(
+            cand_provider, cand_cost, price0, owner0, p4t0, eps, reserve,
+            joined0,
         )
         if retired0 is None:
             retired_seed = jnp.zeros(cand_cost.shape[0], bool)
@@ -1573,12 +1793,15 @@ def assign_auction_sparse_warm(
         max_iters=max_iters, frontier=frontier,
         # the warm solve is a binding final phase: same 8x stall budget
         # as the scaled ladder's last phase (see
-        # assign_auction_sparse_scaled); stall_limit=0 opts out (run to
-        # max_iters)
-        stall_limit=stall_limit * 8,
-        stats_out=stats_out, transposed=[], reserve=reserve,
+        # assign_auction_sparse_scaled), none where tasks wait (see
+        # _queue_phase); stall_limit=0 opts out (run to max_iters)
+        stall_limit=0 if reserve is not None else stall_limit * 8,
+        stats_out=stats_out, transposed=[], reserve=reserve, band=band,
     )
-    _report_stall("warm", stall, stall_limit * 8, stats_out)
+    _report_stall("warm", stall, 0 if reserve is not None
+                  else stall_limit * 8, stats_out)
+    if crossed and stats_out is not None:
+        stats_out["regime_change"] = 1
     if stats_out is not None:
         # same cost driver the cold ladder exposes: wall = rounds x
         # per-round kernel cost (see assign_auction_sparse_scaled)
@@ -1589,6 +1812,7 @@ def assign_auction_sparse_warm(
         # queue pass leaves free is one no waiting task lists)
         if reserve is None:
             p4t = _greedy_cleanup(cand_provider, cand_cost, owner, p4t)
+    _regime_stats(stats_out)
     res = AssignResult(p4t, _invert(p4t, num_providers))
     if with_state:
         return res, price, retired & (p4t < 0), reserve

@@ -76,6 +76,7 @@ from protocol_tpu.obs import quality as _quality
 from protocol_tpu.obs.spans import TRACER as _tracer
 from protocol_tpu.ops.encoding import EncodedProviders, EncodedRequirements
 from protocol_tpu.ops.sparse import (
+    REGIMES,
     assign_auction_sparse_scaled,
     assign_auction_sparse_warm,
     candidates_topk_bidir,
@@ -207,6 +208,9 @@ class JaxSolveArena:
         self._dual_age = 0
         self._starve_age: Optional[np.ndarray] = None
         self._reserve: Optional[float] = None
+        # the regime the carried duals were made in (ops/sparse.py:
+        # REGIMES; None before a solve)
+        self._regime: Optional[str] = None
         self._last_quality: dict = {}
         self.last_repair_mask: Optional[np.ndarray] = None
         self._owned_cols: set = set()
@@ -216,7 +220,9 @@ class JaxSolveArena:
     # the exported arrays a solve writes; every other entry of
     # :meth:`export_state` is final for the tick once its
     # ``arena.candidates`` span has closed
-    SOLVE_STATE = ("price", "retired", "p4t", "starve_age", "queue_reserve")
+    SOLVE_STATE = (
+        "price", "retired", "p4t", "starve_age", "queue_reserve", "regime",
+    )
 
     def live_state(self) -> dict:
         """:meth:`export_state`'s entries as the LIVE objects, no
@@ -244,6 +250,12 @@ class JaxSolveArena:
             "queue_reserve": np.array(
                 [np.nan if self._reserve is None else self._reserve],
                 np.float32,
+            ),
+            # the regime the duals were made in: its index in REGIMES,
+            # -1 before a solve
+            "regime": np.array(
+                [-1 if self._regime is None else REGIMES.index(self._regime)],
+                np.int8,
             ),
             "warm_solves": int(self._warm_solves),
             "dual_age": int(self._dual_age),
@@ -353,6 +365,12 @@ class JaxSolveArena:
         self._reserve = (
             None if reserve is None or np.isnan(reserve[0])
             else float(reserve[0])
+        )
+        # a journal from before the regime was carried: the reserve's
+        regime = state.get("regime")
+        self._regime = (
+            REGIMES[int(regime[0])] if regime is not None and regime[0] >= 0
+            else "queue" if self._reserve is not None else None
         )
         self._warm_solves = int(state["warm_solves"])
         self._dual_age = int(state["dual_age"])
@@ -584,14 +602,42 @@ class JaxSolveArena:
                     self._p4t[lost] = -1
         return changed
 
+    def _life(self, p_was, r_was, pf: dict, rf: dict):
+        """What the delta about to be applied did to who is there, from
+        the ``valid`` columns before (``p_was`` / ``r_was``) and after:
+        (stats, the providers that came back, or None). The stats are
+        ``arena_rows_left`` / ``arena_rows_joined``, provider rows whose
+        ``valid`` went False / True (``arena_rows_moved``: both), and
+        ``arena_seats_vacated``, seats of the carried plan the seat
+        guard empties (:meth:`_adopt`) because their provider left or
+        their task ended. The warm solve prices the providers that came
+        back (``assign_auction_sparse_warm``'s ``joined0``)."""
+        was = p_was.astype(bool)
+        now = pf["valid"].astype(bool)
+        left, joined = was & ~now, ~was & now
+        ended = r_was.astype(bool) & ~rf["valid"].astype(bool)
+        vacated = (self._p4t >= 0) & (
+            ended | left[np.maximum(self._p4t, 0)]
+        )
+        n_left, n_joined = int(left.sum()), int(joined.sum())
+        return {
+            "arena_rows_left": n_left,
+            "arena_rows_joined": n_joined,
+            "arena_rows_moved": n_left + n_joined,
+            "arena_seats_vacated": int(vacated.sum()),
+        }, joined if n_joined else None
+
     def _ladder(self, P: int, eng: Optional[dict]):
         """Cold/refresh solve stage: the eps-annealed auction ladder
         from scratch duals over the CURRENT candidate structure."""
+        regime: dict = {}
         res, price, retired, self._reserve = assign_auction_sparse_scaled(
             self._cand_p, self._cand_c,
             num_providers=P, eps_start=self.eps_start,
             eps_end=self.eps_end, stats_out=eng, with_state=True,
+            regime_out=regime, regime0=self._regime,
         )
+        self._regime = regime.get("regime")
         return self._readback(res, price, retired, eng)
 
     @staticmethod
@@ -615,7 +661,7 @@ class JaxSolveArena:
 
     def _warm(
         self, P: int, p4t0: np.ndarray, changed: np.ndarray,
-        eng: Optional[dict],
+        eng: Optional[dict], joined: Optional[np.ndarray] = None,
     ):
         """Warm solve stage: delta-frontier auction from the carried
         duals. Retirement is cleared for exactly the ``changed`` rows
@@ -623,6 +669,7 @@ class JaxSolveArena:
         warm kernel's documented caller contract; the kernel itself
         applies the uniform price downshift that keeps carried prices
         sound."""
+        regime: dict = {}
         res, price, retired, self._reserve = assign_auction_sparse_warm(
             self._cand_p, self._cand_c,
             num_providers=P,
@@ -631,7 +678,9 @@ class JaxSolveArena:
             eps=self.eps_end,
             retired0=jnp.asarray(self._retired & ~changed),
             stats_out=eng, with_state=True, reserve0=self._reserve,
+            joined0=joined, regime0=self._regime, regime_out=regime,
         )
+        self._regime = regime.get("regime")
         return self._readback(res, price, retired, eng)
 
     @staticmethod
@@ -801,6 +850,8 @@ class JaxSolveArena:
                     fields[name][idx] = canon[name][keep]
             return rows[keep].astype(np.int32)
 
+        p_was = self._p_fields["valid"].copy()
+        r_was = self._r_fields["valid"].copy()
         dirty_p = _narrow(
             provider_rows, p_rows, self._p_fields, _P_SPEC, P, "p"
         )
@@ -820,13 +871,16 @@ class JaxSolveArena:
             return self._p4t.copy()
 
         eng: Optional[dict] = {} if obs.enabled() else None
+        life, joined = self._life(
+            p_was, r_was, self._p_fields, self._r_fields
+        )
         changed, rep, sharded, cold_passes = self._maintain(
             self._p_fields, self._r_fields, weights, dirty_p, dirty_t,
             event=True,
         )
         t_gen = time.perf_counter()
         with _tracer.span("arena.engine", engine="jax", cold=False):
-            p4t, price, retired = self._warm(P, self._p4t, changed, eng)
+            p4t, price, retired = self._warm(P, self._p4t, changed, eng, joined)
         t_solve = time.perf_counter()
         self._price, self._retired, self._p4t = price, retired, p4t
         self.last_repair_mask = changed
@@ -840,6 +894,7 @@ class JaxSolveArena:
             # certificate and EventResult count) overrides the repair
             # kernels' forward-scope counter of the same name
             **rep,
+            **life,
             "dirty_providers": n_dp,
             "dirty_tasks": n_dt,
             "changed_rows": int(changed.sum()),
@@ -980,6 +1035,9 @@ class JaxSolveArena:
         eng: Optional[dict] = {} if obs.enabled() else None
         prev_p4t = self._p4t.copy() if obs.enabled() else None
         t_start = time.perf_counter()
+        life, joined = self._life(
+            self._p_fields["valid"], self._r_fields["valid"], pf, rf
+        )
         self._p_fields, self._r_fields = pf, rf
         self._owned_cols = set()
 
@@ -997,7 +1055,7 @@ class JaxSolveArena:
                 self._dual_age = 0
             else:
                 p4t, price, retired = self._warm(
-                    P, self._p4t, changed, eng
+                    P, self._p4t, changed, eng, joined
                 )
                 self._dual_age += 1
         t_solve = time.perf_counter()
@@ -1016,6 +1074,7 @@ class JaxSolveArena:
             **took,
             **rep,
             "dual_refresh": dual_refresh,
+            **life,
             "dirty_providers": n_dp,
             "dirty_tasks": n_dt,
             "changed_rows": int(changed.sum()),

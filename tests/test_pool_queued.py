@@ -304,10 +304,12 @@ def test_the_reserve_is_anchored_once_and_carried_while_the_anchor_holds(where):
     # costs doubled, or fell to a fifth: the anchor no longer holds them
     assert sparse._queue_reserve(*lists(9, 8, 20.0), 8, -30.0, None) == -50.0
     assert sparse._queue_reserve(*lists(9, 8, 2.0), 8, -30.0, None) == -14.0
-    # a margin: 65 tasks over 64 providers is a queue, 129 over 128 is
-    # a full pool's tail
+    # no margin: 65 tasks over 64 providers is a queue, and so is 129
+    # over 128 (the band next to P = T: the queue's forward
+    # phase runs its chains to their end); 128 over 128 is none
     assert sparse._queue_reserve(*lists(65, 64, 1.0), 64, None, None) == -12.0
-    assert sparse._queue_reserve(*lists(129, 128, 1.0), 128, None, None) is None
+    assert sparse._queue_reserve(*lists(129, 128, 1.0), 128, None, None) == -12.0
+    assert sparse._queue_reserve(*lists(128, 128, 1.0), 128, None, None) is None
     # the count is of tasks that list a provider and of providers some
     # task lists: an empty slot is nobody's, a task with an empty list
     # does not queue, and an empty slot's cost anchors nothing

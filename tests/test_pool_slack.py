@@ -136,7 +136,8 @@ def chains():
 
                 def forward_only(cand_p, cand_c, n_providers, state, eps,
                                  max_iters, frontier, stall_limit,
-                                 stats_out, transposed, reserve=None):
+                                 stats_out, transposed, reserve=None,
+                                 band=False):
                     state, stall, _rows, _scans = sparse._phase_adaptive(
                         cand_p, cand_c, n_providers, state, eps=eps,
                         max_iters=max_iters, frontier=frontier, retire=True,

@@ -428,6 +428,73 @@ class TestSpanTree:
             assert stats["eng_reverse_rounds"] == 0
 
 
+    def test_the_regime_span_and_counters_on_a_crossing(self):
+        """A pool that breathes crosses P = T: the solve that enters or
+        leaves a queue re-grounds in one ``auction.regime`` span
+        (``step="cross"``, ``from``, ``to``) under ``arena.engine``,
+        whose wall and rounds ``eng_cross_ms`` / ``eng_cross_rounds``
+        count; every warm tick names its crossing, its transposed
+        rounds, the rows that left or came back and the seats emptied,
+        0 where nothing happened."""
+        from tests.test_pool_breathing import open_breathing
+
+        gen, arena, session = open_breathing()
+        regime, crossings = None, []
+        for tick in range(14):
+            mark = TRACER.mark()
+            with session.lock:
+                if tick:
+                    session.apply_delta(*gen.next_delta())
+                session.solve()
+            spans = TRACER.since(mark)
+            stats = dict(arena.last_stats)
+            by_id = {s["span"]: s for s in spans}
+            cross = [s for s in spans if s["name"] == "auction.regime"]
+            if tick:
+                for key in ("eng_regime_change", "eng_cross_ms",
+                            "eng_cross_rounds", "eng_transposed_rounds",
+                            "arena_rows_left", "arena_rows_joined",
+                            "arena_seats_vacated"):
+                    assert key in stats, (tick, key)
+                assert stats["eng_regime_change"] == int(
+                    arena._regime != regime)
+                assert stats["eng_transposed_rounds"] == (
+                    stats["eng_reverse_rounds"] + stats["eng_queue_rounds"])
+            if cross:
+                (span,) = cross
+                assert by_id[span["parent"]]["name"] == "arena.engine"
+                assert span["attrs"]["step"] == "cross"
+                assert (span["attrs"]["from"], span["attrs"]["to"]) == (
+                    regime, arena._regime)
+                assert "queue" in (regime, arena._regime)
+                assert stats["eng_cross_ms"] >= span["dur_ns"] / 1e6 - 0.5
+                assert stats["eng_cross_ms"] <= stats["solve_ms"]
+                inside = [
+                    s["attrs"] for s in spans
+                    if s["name"] in ("auction.segment", "auction.reverse",
+                                     "auction.queue")
+                    and "rounds" in s["attrs"]
+                ]
+                assert stats["eng_cross_rounds"] == sum(
+                    a["rounds"] for a in inside) > 0
+                crossings.append(tick)
+            else:
+                assert stats.get("eng_cross_ms", 0.0) == 0.0
+                assert stats.get("eng_cross_rounds", 0) == 0
+            regime = arena._regime
+        assert len(crossings) >= 2
+
+    def test_the_regime_span_is_read_by_the_solves_idle_metric(self):
+        import re
+
+        readers_of = [
+            m["name"] for m in _idle_split()["metrics"]
+            if any(re.fullmatch(p, "auction.regime")
+                   for p in m["read"]["spans"])
+        ]
+        assert readers_of == ["idle_in_solve_ms_per_ack"]
+
+
 class TestCounters:
     def test_last_stats_split_the_stage_walls(self, served):
         stats = served.stats()
@@ -641,7 +708,9 @@ class TestScopeNames:
             sparse._transpose_candidates.lower(
                 cp, cc, num_providers=self.P, width=16),
             sparse._stranded.lower(cp, jnp.zeros(self.P), owner, p4t),
-            sparse._reverse_seed.lower(cp, cc, jnp.zeros(self.P), owner, p4t),
+            sparse._reverse_seed.lower(
+                cp, cc, jnp.zeros(self.P), owner, p4t,
+                jnp.ones(self.P, bool), jnp.float32(0.0)),
             sparse._reverse_finish.lower(
                 cp, cc, jnp.zeros(self.P), jnp.zeros(self.T), rstate,
                 jnp.float32(0.0)),
@@ -738,7 +807,10 @@ _ACKS = [
      "rep_readback_bytes": 6000000, "rep_syncs": 4,
      "eng_waiting_tasks": 1638, "eng_queue_rounds": 30,
      "eng_queue_ms": 60.0, "waiting_excess": 4.0, "eng_scan_rounds": 20,
-     "eng_open_read_ms": 30.0, "q_gap_ms": 8.0},
+     "eng_open_read_ms": 30.0, "q_gap_ms": 8.0,
+     "eng_regime_change": 1, "eng_cross_ms": 500.0,
+     "eng_cross_rounds": 3000, "eng_transposed_rounds": 70,
+     "arena_rows_moved": 60, "arena_seats_vacated": 160},
     {"wall_ms": 4200.0, "gen_ms": 520.0, "solve_ms": 3100.0,
      "dirty_ms": 14.0, "diff_ms": 44.0, "rep_enter_ms": 110.0,
      "rep_forward_ms": 210.0, "rep_tiles_ms": 64.0, "rep_merge_ms": 94.0,
@@ -749,7 +821,10 @@ _ACKS = [
      "rep_readback_bytes": 7000000, "rep_syncs": 3,
      "eng_waiting_tasks": 1640, "eng_queue_rounds": 50,
      "eng_queue_ms": 80.0, "waiting_excess": 6.0, "eng_scan_rounds": 40,
-     "eng_open_read_ms": 50.0, "q_gap_ms": 12.0},
+     "eng_open_read_ms": 50.0, "q_gap_ms": 12.0,
+     "eng_regime_change": 0, "eng_cross_ms": 0.0,
+     "eng_cross_rounds": 0, "eng_transposed_rounds": 110,
+     "arena_rows_moved": 70, "arena_seats_vacated": 170},
 ]
 _SEAM_BEFORE = {
     "apply_ms_sum": 1.0, "ckpt_flush_ms_sum": 100.0,
@@ -862,6 +937,23 @@ METRICS = {
     "ckpt_chunks_per_ack": (
         "session, arena bookkeeping and checkpoint", "chunks",
         "program_counter", "ckpt_chunks_sum", 16.0, "higher"),
+    "cross_ms_per_ack": (
+        "auction solve", "ms", "program_span", "eng_cross_ms", 250.0),
+    "cross_rounds_per_ack": (
+        "auction solve", "rounds", "program_counter", "eng_cross_rounds",
+        1500.0),
+    "regime_changes_per_ack": (
+        "auction solve", "changes", "program_counter", "eng_regime_change",
+        0.5),
+    "transposed_rounds_per_ack": (
+        "auction solve", "rounds", "program_counter",
+        "eng_transposed_rounds", 90.0),
+    "rows_moved_per_ack": (
+        "session, arena bookkeeping and checkpoint", "rows",
+        "program_counter", "arena_rows_moved", 65.0),
+    "seats_vacated_per_ack": (
+        "session, arena bookkeeping and checkpoint", "seats",
+        "program_counter", "arena_seats_vacated", 165.0),
 }
 # the cells a metric is declared for, where not ``pool-large.ticks``
 CELLS = {
@@ -880,6 +972,12 @@ CELLS.update(dict.fromkeys(
     ("waiting_tasks_per_ack", "queue_rounds_per_ack", "queue_ms_per_ack",
      "waiting_excess_per_ack", "queued_gap_per_task"),
     ["pool-queued.ticks"],
+))
+CELLS.update(dict.fromkeys(
+    ("cross_ms_per_ack", "cross_rounds_per_ack", "regime_changes_per_ack",
+     "transposed_rounds_per_ack", "rows_moved_per_ack",
+     "seats_vacated_per_ack"),
+    ["pool-breathing.life"],
 ))
 CELLS.update(dict.fromkeys(
     ("ckpt_join_ms_per_ack", "scan_rounds_per_ack",

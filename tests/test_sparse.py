@@ -529,9 +529,15 @@ class TestAdaptiveFrontierLadder:
             "rounds_total", "segments", "wait_ms", "frontier_rows",
             "stall_exit", "stall_rounds", "reverse_rounds", "reverse_ms",
             "free_repriced", "queue_rounds", "queue_ms", "scan_rounds",
-            "open_read_ms",
+            "open_read_ms", "regime_change", "cross_ms", "cross_rounds",
+            "transposed_rounds",
         }
         assert stats["queue_rounds"] == 0  # (no queue in this pool)
+        # nothing carried a regime in, so nothing crossed; the transposed
+        # passes' rounds in one counter
+        assert stats["regime_change"] == stats["cross_rounds"] == 0
+        assert stats["cross_ms"] == 0.0
+        assert stats["transposed_rounds"] == stats["reverse_rounds"]
         # the open counts' reads after full segments: a part of the wait
         assert 0 <= stats["open_read_ms"] <= stats["wait_ms"]
         assert stats["segments"] >= 1 and stats["rounds_total"] >= 1
@@ -700,7 +706,10 @@ def _rung_case(name):
             )
             _, price, owner, p4t, _ = state
         rev_t, rev_c = sparse._transpose_candidates(cp, cc, P, 32)
-        rstate, floor = sparse._reverse_seed(cp, cc, price, owner, p4t)
+        listed, floor, _, _ = sparse._stranded(cp, price, owner, p4t)
+        rstate, floor = sparse._reverse_seed(
+            cp, cc, price, owner, p4t, listed, floor
+        )
         return rev_t, rev_c, T, rstate, dict(
             eps=0.25, max_iters=600, frontier=P, retire=True, stall_limit=0,
             reserve=floor,
