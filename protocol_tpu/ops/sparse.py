@@ -796,27 +796,60 @@ _SLACK_SHARE = 64
 _REVERSE_WIDTH = 256
 
 
+def _windows(x, start, width: int):
+    """``x[start[p] : start[p] + width]`` for every ``p``, [P, width]:
+    the whole 128-lane rows of ``x`` that each window spans, gathered
+    as rows, then each shifted left by where its window starts in its
+    first row, one bit of the shift at a time. Past the end of ``x``
+    the window reads zeros."""
+    lanes = 128
+    n_rows = -(-width // lanes) + 1
+    rows = -(-x.shape[0] // lanes) + n_rows
+    table = jnp.pad(x, (0, rows * lanes - x.shape[0])).reshape(rows, lanes)
+    spans = table[
+        start[:, None] // lanes + jnp.arange(n_rows, dtype=jnp.int32)[None, :]
+    ].reshape(start.shape[0], n_rows * lanes)
+    shift = start % lanes
+    for bit in range(lanes.bit_length() - 1):
+        step = 1 << bit
+        spans = jnp.where(
+            ((shift & step) != 0)[:, None],
+            jnp.concatenate([spans[:, step:], spans[:, :step]], axis=1),
+            spans,
+        )
+    return spans[:, :width]
+
+
 @partial(jax.jit, static_argnames=("num_providers", "width"))
 @jax.named_scope("auction.reverse")
 def _transpose_candidates(cand_provider, cand_cost, num_providers: int, width: int):
     """The candidate graph from the providers' side: for each provider
     the tasks that list it, in task order, as ``rev_task``/``rev_cost``
-    [P, width] with -1 in empty slots — the shape
+    [P, width] with -1 / INFEASIBLE in empty slots — the shape
     :func:`_sparse_auction_phase` takes when providers bid. One stable
-    single-key sort (what the TPU compiler builds fastest: a second key
-    doubles its 20 s), then counts and gathers."""
+    single-key sort of the flattened provider ids, the keys kept (what
+    the TPU compiler builds fastest: a second key doubles its 20 s),
+    with each entry's index and cost carried along; a binary search in
+    the keys finds where each provider's run starts, and the run's first
+    ``width`` entries are cut out as whole rows (:func:`_windows`). On a
+    TPU v5e at 8,192 x 80 -> [8,192, 256] that is ~3.5 ms a call, where
+    gathering the cost after the sort took ~8 and a scatter-add of the
+    counts and two gathers of every [P, width] slot by index ~36 (PERF.md
+    section 5); the cost as a third operand adds ~12 s to the compile."""
     T, K = cand_cost.shape
     P = num_providers
     prov = jnp.where(cand_provider >= 0, cand_provider, P).ravel()
-    order = jnp.argsort(prov, stable=True).astype(jnp.int32)
-    count = jnp.zeros(P + 1, jnp.int32).at[prov].add(1)[:P]
-    start = jnp.cumsum(count) - count
-    slot = jnp.arange(width, dtype=jnp.int32)[None, :]
-    ok = slot < count[:, None]
-    flat = order[jnp.minimum(start[:, None] + slot, T * K - 1)]
+    prov, flat, cost = lax.sort(
+        (prov, jnp.arange(T * K, dtype=jnp.int32), cand_cost.ravel()),
+        num_keys=1, is_stable=True,
+    )
+    start = jnp.searchsorted(
+        prov, jnp.arange(P + 1, dtype=jnp.int32)
+    ).astype(jnp.int32)
+    ok = jnp.arange(width)[None, :] < (start[1:] - start[:P])[:, None]
     return (
-        jnp.where(ok, flat // K, -1),
-        jnp.where(ok, cand_cost.ravel()[flat], INFEASIBLE),
+        jnp.where(ok, _windows(flat // K, start[:P], width), -1),
+        jnp.where(ok, _windows(cost, start[:P], width), INFEASIBLE),
     )
 
 

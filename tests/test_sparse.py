@@ -978,3 +978,111 @@ class TestWarmColdRegression:
         np.testing.assert_allclose(pr[2] - pr[0], 0.5, atol=1e-4)
         assert pr.max() <= 2.0 + 5.0 + 1e-4  # finite_max + 5 guard
         assert (np.asarray(res.provider_for_task) == [0, 1, 2]).all()
+
+
+def _plain_transpose(cand_provider, cand_cost, P, width):
+    """The candidate graph from the providers' side, as a loop: tasks in
+    order, each appending itself to every provider it lists, a
+    provider's list cut at ``width``."""
+    rev_t = np.full((P, width), -1, np.int32)
+    rev_c = np.full((P, width), INFEASIBLE, np.float32)
+    n = np.zeros(P, np.int64)
+    for t, (row_p, row_c) in enumerate(zip(cand_provider, cand_cost)):
+        for p, c in zip(row_p, row_c):
+            if p >= 0 and n[p] < width:
+                rev_t[p, n[p]] = t
+                rev_c[p, n[p]] = c
+                n[p] += 1
+    return rev_t, rev_c
+
+
+@partial(jax.jit, static_argnames=("num_providers", "width"))
+def _argsort_transpose(cand_provider, cand_cost, num_providers, width):
+    """The transposition as one stable argsort of the flattened provider
+    ids, a scatter-add of the counts and two gathers of every [P, width]
+    slot (``_transpose_candidates`` before it kept the sorted keys),
+    kept here as the second reference."""
+    T, K = cand_cost.shape
+    P = num_providers
+    prov = jnp.where(cand_provider >= 0, cand_provider, P).ravel()
+    order = jnp.argsort(prov, stable=True).astype(jnp.int32)
+    count = jnp.zeros(P + 1, jnp.int32).at[prov].add(1)[:P]
+    start = jnp.cumsum(count) - count
+    slot = jnp.arange(width, dtype=jnp.int32)[None, :]
+    ok = slot < count[:, None]
+    flat = order[jnp.minimum(start[:, None] + slot, T * K - 1)]
+    return (
+        jnp.where(ok, flat // K, -1),
+        jnp.where(ok, cand_cost.ravel()[flat], INFEASIBLE),
+    )
+
+
+def _transpose_case(T, P, K, seed, dead=0.0, listed=None, hot=False):
+    """Lists of K providers a task drawn from the first ``listed`` of P
+    (duplicates allowed), a ``dead`` share of rows all -1, and with
+    ``hot`` provider 0 listed by every live task."""
+    rng = np.random.default_rng(seed)
+    cp = rng.integers(0, listed or P, (T, K)).astype(np.int32)
+    cc = rng.uniform(0.0, 12.0, (T, K)).astype(np.float32)
+    if hot:
+        cp[:, 0] = 0
+    gone = rng.random(T) < dead
+    cp[gone] = -1
+    cc[gone] = INFEASIBLE
+    # a row that lists fewer than K: its tail is empty
+    cp[1, K // 2:] = -1
+    cc[1, K // 2:] = INFEASIBLE
+    return cp, cc
+
+
+class TestTransposeCandidates:
+    """``_transpose_candidates`` gives each provider's takers in task
+    order, the first ``width`` kept, -1 / INFEASIBLE in the empty slots,
+    the same arrays bit for bit as a plain loop and as the argsort
+    formulation it replaced."""
+
+    @staticmethod
+    def _same(got, want):
+        assert np.array_equal(np.asarray(got[0]), want[0])
+        assert np.array_equal(
+            np.asarray(got[1]).view(np.int32),
+            np.asarray(want[1], np.float32).view(np.int32),
+        )
+
+    @pytest.mark.parametrize("T,P,K,width,kw", [
+        # slack (T < P), P not a power of two, dead rows, providers past
+        # 470 listed by none
+        (300, 500, 12, 16, dict(dead=0.3, listed=470)),
+        # a queue (T > P): most providers listed by more than ``width``
+        (600, 250, 10, 16, dict(dead=0.1)),
+        # the served width, one provider listed by every live task
+        (700, 300, 40, 256, dict(dead=0.2, hot=True)),
+        # a queue at the served width, P not a power of two
+        (900, 131, 30, 256, dict(listed=100, hot=True)),
+        # every row dead: nothing is listed
+        (64, 96, 8, 16, dict(dead=1.0)),
+    ])
+    def test_matches_plain_loop(self, T, P, K, width, kw):
+        from protocol_tpu.ops import sparse
+
+        cp, cc = _transpose_case(T, P, K, seed=T + P, **kw)
+        got = sparse._transpose_candidates(
+            jnp.asarray(cp), jnp.asarray(cc), num_providers=P, width=width
+        )
+        want = _plain_transpose(cp, cc, P, width)
+        self._same(got, want)
+        self._same(_argsort_transpose(
+            jnp.asarray(cp), jnp.asarray(cc), num_providers=P, width=width
+        ), want)
+
+    def test_matches_argsort_at_served_shape(self):
+        """8,192 tasks x 80 -> [8,192, 256], 40% of the rows dead, as a
+        pool with slack sends them."""
+        from protocol_tpu.ops import sparse
+
+        cp, cc = _transpose_case(8192, 8192, 80, seed=44, dead=0.4)
+        cp, cc = jnp.asarray(cp), jnp.asarray(cc)
+        got = sparse._transpose_candidates(cp, cc, num_providers=8192,
+                                           width=256)
+        want = _argsort_transpose(cp, cc, num_providers=8192, width=256)
+        self._same(got, [np.asarray(a) for a in want])
